@@ -24,6 +24,7 @@ exploration smoke sweep.
 """
 import argparse
 import json
+import os
 import time
 from typing import Any, Dict, List
 
@@ -33,6 +34,7 @@ import numpy as np
 
 from repro import api, obs
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RESULTS: List[Dict[str, Any]] = []
 # --profile: compile with stripe_jit(..., profile=True) in the cache and
 # serving benches (measured per-unit latencies + cost-model residual rows)
@@ -267,7 +269,7 @@ def bench_fusion() -> None:
     emit("fusion_relu2_fused_groups", t_f2, n_f2)
     emit("fusion_speedup", 0.0, f"{t_u2 / t_f2:.2f}x")
 
-    pallas = api.stripe_jit(semantic, api.get_config("tpu_v5e"), backend="pallas", interpret=True)
+    pallas = api.stripe_jit(semantic, api.get_config("tpu_v5e"), backend="pallas")
     emit("fusion_pallas_kernels", 0.0,
          f"\"{n_u}->{pallas.record.n_kernels} "
          f"(backend={pallas.record.backend})\"")
@@ -419,7 +421,7 @@ def bench_conv() -> None:
                         (api.explore.workloads.fig5_conv_f32, "fig5")):
         prog = build()
         src = copy.deepcopy(prog)
-        c = api.stripe_jit(prog, hw, backend="pallas", interpret=True, use_disk=False)
+        c = api.stripe_jit(prog, hw, backend="pallas", use_disk=False)
         assert c.record.backend == "pallas", c.record.fallback_reasons()
         assert c.record.n_kernels >= 1
         ins = {}
@@ -443,8 +445,7 @@ def bench_conv() -> None:
         "O[x, y, k] += I[x + i - 1, y + j - 1, c] * F[i, j, c, k]",
         {"I": ((x, y, ci), "float32"), "F": ((3, 3, ci, co), "float32"),
          "O": ((x, y, co), "float32")}, out="O", name="conv_serving")
-    pal = api.stripe_jit(copy.deepcopy(prog), hw, backend="pallas",
-                     interpret=True, use_disk=False)
+    pal = api.stripe_jit(copy.deepcopy(prog), hw, backend="pallas", use_disk=False)
     assert pal.record.backend == "pallas", pal.record.fallback_reasons()
     ref = api.stripe_jit(copy.deepcopy(prog), hw, backend="jnp", use_disk=False)
     ins = {"I": jnp.asarray(rng.randn(x, y, ci), jnp.float32),
@@ -481,7 +482,7 @@ def bench_conv() -> None:
     tp.op("M[x, y] max= C[x, y, k]", name="headmax")  # no Pallas path
     mixed = tp.build()
     src = copy.deepcopy(mixed)
-    hy = api.stripe_jit(mixed, hw, backend="pallas", interpret=True, use_disk=False)
+    hy = api.stripe_jit(mixed, hw, backend="pallas", use_disk=False)
     rec = hy.record
     assert rec.backend == "pallas"
     assert rec.block_backends.get("headmax") == "jnp"
@@ -506,7 +507,7 @@ def bench_stripe_matmul() -> None:
     x = jnp.asarray(rng.randn(256, 512), jnp.float32)
     w = jnp.asarray(rng.randn(512, 384), jnp.float32)
     t_ref = _timeit(jax.jit(lambda a, b: api.matmul_ref(a, b)), x, w)
-    got = api.matmul(x, w, interpret=True)
+    got = api.matmul(x, w)
     err = float(jnp.max(jnp.abs(got - api.matmul_ref(x, w))))
     emit("stripe_matmul_ref_xla", t_ref, 1)
     emit("stripe_matmul_pallas_interpret_maxerr", 0.0, f"{err:.2e}")
@@ -833,7 +834,7 @@ def bench_autotune() -> None:
         tuned_recs = {}
         for w in workloads:
             c = api.stripe_jit(w.build(), hw, backend="pallas",
-                               interpret=True, cache=cache2, tune=db)
+                               cache=cache2, tune=db)
             tuned_recs[w.name] = c
         dt_replay = (time.perf_counter() - t0) * 1e6 / len(workloads)
         n_tuned = sum(1 for c in tuned_recs.values()
@@ -910,135 +911,103 @@ def bench_autotune() -> None:
 
 
 def bench_distributed() -> None:
-    """Multi-device smoke on 8 emulated host devices (subprocess — this
-    process's jax is already initialized single-device): the acceptance
-    FFN through ``stripe_jit(mesh=8)`` vs the *replicated* placement on
-    the same mesh (every device computes the full program — the
-    no-partitioning baseline; emulated devices share the host cores, so
-    the wall-clock ratio measures the partition's per-device work
-    reduction, not physical parallelism), plus the predicted-vs-emitted
-    collective loop on a reduction-split matmul (psum count and modelled
-    bytes asserted in the child).  A plain single-device row is emitted
-    as the absolute reference."""
-    import os
-    import subprocess
-    import sys
-    import textwrap
+    """Multi-device smoke on the devices present (8 emulated host devices
+    on the CPU, see ``main``): the acceptance FFN through
+    ``stripe_jit(mesh=n)`` vs the *replicated* placement on the same mesh
+    (every device computes the full program — the no-partitioning
+    baseline; emulated devices share the host cores, so there the
+    wall-clock ratio measures the partition's per-device work reduction,
+    not physical parallelism), plus the predicted-vs-emitted collective
+    loop on a reduction-split matmul (psum count and modelled bytes
+    asserted).  A plain single-device row is emitted as the absolute
+    reference.  Runs in this process: one process holds the chips."""
+    from jax.sharding import Mesh, PartitionSpec as P
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(repo, "src")
-    script = textwrap.dedent("""
-        import json, time
-        import jax
-        import numpy as np
-        if jax.device_count() < 8:
-            print(json.dumps({"skip": f"only {jax.device_count()} device(s)"}))
-            raise SystemExit(0)
-        from repro import api
-        from repro.core import mesh_lower
-        from repro.core.cost import collective_seconds
-        from repro.core.frontend import TileProgram
-        from repro.core.hwconfig import CPU_TEST
+    from repro.core import mesh_lower
+    from repro.core.cost import collective_seconds
 
-        def ffn(m, k, n):
-            tp = TileProgram("ffn")
-            tp.input("X", (m, k), "float32")
-            tp.input("W", (k, n), "float32")
-            tp.input("B", (n,), "float32")
-            tp.output("O", (m, n), "float32")
-            tp.temp("T", (m, n), "float32")
-            tp.temp("U", (m, n), "float32")
-            tp.op("T[i, j] += X[i, c] * W[c, j]", name="mm")
-            tp.op("U[i, j] = T[i, j] + B[j]", name="bias")
-            tp.op("O[i, j] = gelu(U[i, j])", name="act")
-            return tp.build()
-
-        m, k, n = 2048, 512, 512
-        rng = np.random.default_rng(0)
-        arrays = {"X": rng.normal(size=(m, k)).astype("float32"),
-                  "W": rng.normal(size=(k, n)).astype("float32"),
-                  "B": rng.normal(size=(n,)).astype("float32")}
-        single = api.jit(ffn(m, k, n), CPU_TEST, backend="jnp")
-        sh = api.jit(ffn(m, k, n), CPU_TEST, backend="jnp", mesh=8)
-
-        # replicated placement on the same mesh: every device runs the
-        # full single-device program (in_specs/out_specs all P())
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
-        jmesh = Mesh(np.array(jax.devices()[:8]), ("x",))
-        inner = api.jit(ffn(m, k, n), CPU_TEST, backend="jnp", jit=False)
-        in_order = ["X", "W", "B"]
-        rep_body = shard_map(
-            lambda X, W, B: inner({"X": X, "W": W, "B": B})["O"],
-            mesh=jmesh, in_specs=(P(), P(), P()), out_specs=P(),
-            check_rep=False)
-        rep_jit = jax.jit(rep_body)
-        rep = lambda a: {"O": rep_jit(*[a[k] for k in in_order])}
-
-        r0, s0, g0 = rep(arrays), sh(arrays), single(arrays)
-        np.testing.assert_allclose(np.asarray(s0["O"]), np.asarray(g0["O"]),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(r0["O"]), np.asarray(g0["O"]),
-                                   rtol=1e-4, atol=1e-4)
-
-        def best_us(fn, rounds=5):
-            best = float("inf")
-            for _ in range(rounds):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(arrays)["O"])
-                best = min(best, time.perf_counter() - t0)
-            return best * 1e6
-
-        t_single, t_rep, t_sh = best_us(single), best_us(rep), best_us(sh)
-
-        # predicted-vs-emitted collective loop: reduction-split matmul
-        tp = TileProgram("kred")
-        tp.input("X", (12, 4096), "float32")
-        tp.input("W", (4096, 20), "float32")
-        tp.output("O", (12, 20), "float32")
-        tp.op("O[i, j] += X[i, c] * W[c, j]", name="mm")
-        kr = api.jit(tp.build(), CPU_TEST, backend="jnp", mesh=8)
-        karr = {"X": rng.normal(size=(12, 4096)).astype("float32"),
-                "W": rng.normal(size=(4096, 20)).astype("float32")}
-        counts = mesh_lower.count_collectives(kr._fn, karr)
-        assert counts.get("psum") == 1, counts
-        pred = kr.record.mesh["collective_bytes"]
-        want = collective_seconds("psum", 12 * 20 * 4, 8, 1.0)
-        assert abs(pred - want) < 1e-6, (pred, want)
-        np.testing.assert_allclose(
-            np.asarray(kr(karr)["O"]),
-            np.asarray(karr["X"] @ karr["W"]), rtol=1e-3, atol=1e-3)
-
-        print(json.dumps({
-            "devices": jax.device_count(),
-            "single_us": t_single,
-            "replicated_us": t_rep, "sharded_us": t_sh,
-            "speedup": t_rep / t_sh,
-            "ffn_collective_bytes": sh.record.mesh["collective_bytes"],
-            "kred_psum_count": counts["psum"],
-            "kred_collective_bytes": pred,
-        }))
-    """)
-    out = subprocess.run([sys.executable, "-c", script], env=env,
-                         capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, f"distributed bench failed:\n{out.stdout}\n{out.stderr}"
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    if "skip" in res:
-        emit("distributed_skipped", 0.0, f"\"{res['skip']}\"")
+    n = min(jax.device_count(), 8)
+    if n < 2:
+        emit("distributed_skipped", 0.0, f"\"only {n} device(s)\"")
         return
-    emit("distributed_devices", 0.0, res["devices"])
-    emit("distributed_ffn_single_device", res["single_us"], "")
-    emit("distributed_ffn_replicated_mesh8", res["replicated_us"], "")
-    emit("distributed_ffn_sharded_mesh8", res["sharded_us"],
-         f"{res['speedup']:.2f}x")
-    assert res["speedup"] > 1.0, \
-        f"sharded must beat the replicated placement ({res['speedup']:.2f}x)"
+    hw = api.get_config("cpu_test")
+
+    def ffn(m, k, n_out):
+        tp = api.TileProgram("ffn")
+        tp.input("X", (m, k), "float32")
+        tp.input("W", (k, n_out), "float32")
+        tp.input("B", (n_out,), "float32")
+        tp.output("O", (m, n_out), "float32")
+        tp.temp("T", (m, n_out), "float32")
+        tp.temp("U", (m, n_out), "float32")
+        tp.op("T[i, j] += X[i, c] * W[c, j]", name="mm")
+        tp.op("U[i, j] = T[i, j] + B[j]", name="bias")
+        tp.op("O[i, j] = gelu(U[i, j])", name="act")
+        return tp.build()
+
+    m, k, n_out = 2048, 512, 512
+    rng = np.random.default_rng(0)
+    arrays = {"X": rng.normal(size=(m, k)).astype("float32"),
+              "W": rng.normal(size=(k, n_out)).astype("float32"),
+              "B": rng.normal(size=(n_out,)).astype("float32")}
+    single = api.jit(ffn(m, k, n_out), hw, backend="jnp")
+    sh = api.jit(ffn(m, k, n_out), hw, backend="jnp", mesh=n)
+
+    # replicated placement on the same mesh: every device runs the full
+    # single-device program (in_specs/out_specs all P())
+    jmesh = Mesh(np.array(jax.devices()[:n]), ("x",))
+    inner = api.jit(ffn(m, k, n_out), hw, backend="jnp", jit=False)
+    in_order = ["X", "W", "B"]
+    rep_jit = jax.jit(jax.shard_map(
+        lambda X, W, B: inner({"X": X, "W": W, "B": B})["O"],
+        mesh=jmesh, in_specs=(P(), P(), P()), out_specs=P(), check_vma=False))
+    rep = lambda a: {"O": rep_jit(*[a[name] for name in in_order])}  # noqa: E731
+
+    r0, s0, g0 = rep(arrays), sh(arrays), single(arrays)
+    np.testing.assert_allclose(np.asarray(s0["O"]), np.asarray(g0["O"]),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(r0["O"]), np.asarray(g0["O"]),
+                               rtol=1e-4, atol=1e-4)
+
+    def best_us(fn, rounds=5):
+        best = float("inf")
+        for _ in range(rounds):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(arrays)["O"])
+            best = min(best, time.perf_counter() - t0)
+        return best * 1e6
+
+    t_single, t_rep, t_sh = best_us(single), best_us(rep), best_us(sh)
+
+    # predicted-vs-emitted collective loop: reduction-split matmul
+    tp = api.TileProgram("kred")
+    tp.input("X", (12, 4096), "float32")
+    tp.input("W", (4096, 20), "float32")
+    tp.output("O", (12, 20), "float32")
+    tp.op("O[i, j] += X[i, c] * W[c, j]", name="mm")
+    kr = api.jit(tp.build(), hw, backend="jnp", mesh=n)
+    karr = {"X": rng.normal(size=(12, 4096)).astype("float32"),
+            "W": rng.normal(size=(4096, 20)).astype("float32")}
+    counts = mesh_lower.count_collectives(kr._fn, karr)
+    assert counts.get("psum") == 1, counts
+    pred = kr.record.mesh["collective_bytes"]
+    want = collective_seconds("psum", 12 * 20 * 4, n, 1.0)
+    assert abs(pred - want) < 1e-6, (pred, want)
+    np.testing.assert_allclose(np.asarray(kr(karr)["O"]),
+                               np.asarray(karr["X"] @ karr["W"]),
+                               rtol=1e-3, atol=1e-3)
+
+    speedup = t_rep / t_sh
+    emit("distributed_devices", 0.0, n)
+    emit("distributed_ffn_single_device", t_single, "")
+    emit(f"distributed_ffn_replicated_mesh{n}", t_rep, "")
+    emit(f"distributed_ffn_sharded_mesh{n}", t_sh, f"{speedup:.2f}x")
+    assert speedup > 1.0, \
+        f"sharded must beat the replicated placement ({speedup:.2f}x)"
     emit("distributed_ffn_collective_bytes", 0.0,
-         int(res["ffn_collective_bytes"]))
+         int(sh.record.mesh["collective_bytes"]))
     emit("distributed_kred_psum_emitted_vs_predicted", 0.0,
-         f"\"psum={res['kred_psum_count']} bytes={int(res['kred_collective_bytes'])}\"")
+         f"\"psum={counts['psum']} bytes={int(pred)}\"")
 
 
 BENCHES = {
@@ -1078,6 +1047,12 @@ def main(argv=None) -> None:
                          "latencies + residual log) in the cache/serving "
                          "benches")
     args = ap.parse_args(argv)
+    if "device_count" not in os.environ.get("XLA_FLAGS", ""):
+        # the distributed leg's mesh on the CPU: 8 emulated host devices,
+        # set before the first JAX call initializes the backend
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                                   " --xla_force_host_platform_device_count=8")
+    api.enable_compilation_cache(REPO)
     global PROFILE
     PROFILE = args.profile
     if args.trace:

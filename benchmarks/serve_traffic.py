@@ -37,6 +37,7 @@ import json
 import tempfile
 import threading
 import time
+from pathlib import Path
 from typing import Any, Dict, List
 
 import jax
@@ -317,4 +318,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    api.enable_compilation_cache(Path(__file__).resolve().parents[1])
     main()

@@ -4,6 +4,8 @@ interpret mode.
 
     PYTHONPATH=src python examples/compile_op_with_stripe.py
 """
+from pathlib import Path
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -52,7 +54,7 @@ def run_generated_kernel():
     x = jnp.asarray(rng.randn(256, 512), jnp.float32)
     w = jnp.asarray(rng.randn(512, 384), jnp.float32)
     b = jnp.asarray(rng.randn(384), jnp.float32)
-    got = api.matmul(x, w, b, act="relu", interpret=True)
+    got = api.matmul(x, w, b, act="relu")
     want = api.matmul_ref(x, w, b, act="relu")
     print("max |err| vs oracle:", float(jnp.max(jnp.abs(got - want))))
 
@@ -84,6 +86,7 @@ def jit_with_cache():
 
 
 if __name__ == "__main__":
+    api.enable_compilation_cache(Path(__file__).resolve().parents[1])
     fig5_rewrite()
     pass_by_pass()
     run_generated_kernel()

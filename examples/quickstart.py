@@ -4,6 +4,8 @@ both backends.
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from pathlib import Path
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -44,4 +46,5 @@ def main():
 
 
 if __name__ == "__main__":
+    api.enable_compilation_cache(Path(__file__).resolve().parents[1])
     main()

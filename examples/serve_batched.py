@@ -5,6 +5,7 @@ greedy sampling), then stream a couple of requests token-by-token.
     PYTHONPATH=src python examples/serve_batched.py --arch qwen3-4b
 """
 import argparse
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -52,4 +53,5 @@ def main():
 
 
 if __name__ == "__main__":
+    api.enable_compilation_cache(Path(__file__).resolve().parents[1])
     main()

@@ -8,6 +8,7 @@ xLSTM config (TPU-scale; on CPU it is slow but correct).
     PYTHONPATH=src python examples/train_tiny_lm.py --steps 200
 """
 import argparse
+from pathlib import Path
 
 import jax
 
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    api.enable_compilation_cache(Path(__file__).resolve().parents[1])
     main()

@@ -19,7 +19,8 @@ Compile:
 Hardware & model configs:
     ``get_config`` (hardware registry), ``HW_REGISTRY``,
     ``HardwareConfig``, ``configs`` (architecture registry:
-    ``configs.get(name)``), ``build_model``, ``make_batch``
+    ``configs.get(name)``), ``build_model``, ``make_batch``,
+    ``enable_compilation_cache`` (for entry points)
 Caching:
     ``CompilationCache``, ``get_default_cache``, ``set_default_cache``
 Serving:
@@ -65,6 +66,7 @@ from .core.hwconfig import REGISTRY as HW_REGISTRY
 from .core.hwconfig import HardwareConfig, get_config
 from .core.passes import compile_program, get_pass
 from .core.passes.autotile import choose_tiling
+from .core.platform import enable_compilation_cache
 from .core.tiling import split_block
 from .data.pipeline import DataConfig
 from .explore import dominating_baseline, get_space, pareto_front, run_sweep
@@ -99,6 +101,7 @@ __all__ = [
     "split_block", "choose_tiling", "evaluate_tiling", "score_pass_trace",
     # configs
     "get_config", "HW_REGISTRY", "HardwareConfig", "configs", "Mesh",
+    "enable_compilation_cache",
     "build_model", "make_batch",
     # caching
     "CompilationCache", "get_default_cache", "set_default_cache",

@@ -1,5 +1,5 @@
 """qwen3-4b [dense]: 36L d_model=2560 32H (GQA kv=8) d_ff=9728
-vocab=151936 — qk_norm, GQA [hf:Qwen/Qwen3-8B; hf]."""
+vocab=151936 — qk_norm, GQA, tied embeddings [hf:Qwen/Qwen3-4B; hf]."""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -16,5 +16,6 @@ CONFIG = ArchConfig(
     qk_norm=True,
     rope="full",
     rope_theta=1_000_000.0,
-    source="[hf:Qwen/Qwen3-8B; hf]",
+    tie_embeddings=True,
+    source="[hf:Qwen/Qwen3-4B; hf]",
 )

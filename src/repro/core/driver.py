@@ -34,6 +34,7 @@ from .interp import execute_reference
 from .ir import Block, Program, ir_fingerprint
 from .lower_jnp import lower_program_jnp
 from .passes import PassManager, TilingOracle
+from .platform import resolve_interpret
 
 DRIVER_VERSION = 1
 
@@ -262,7 +263,8 @@ def _lower(opt: Program, backend: str, interpret: bool, jit: bool,
                     fn = lower_program_hybrid(
                         opt, interpret=interpret,
                         pipeline_depth=hw.pipeline_depth if hw is not None else 2,
-                        profile=profile, force_jnp_units=force_jnp_units)
+                        profile=profile, force_jnp_units=force_jnp_units,
+                        vmem_cap=hw.inner_mem().size_bytes if hw is not None else None)
             except UnsupportedPallas as e:
                 # legality fallback: deterministic and known, no quarantine
                 backend, fallback = "jnp", str(e)
@@ -446,7 +448,7 @@ def stripe_jit(fn_or_contraction: Union[Program, TileProgram, str, Callable],
                ranges: Optional[Mapping[str, int]] = None,
                cache: Optional[_cache.CompilationCache] = None,
                workers: Optional[int] = None,
-               interpret: bool = True,
+               interpret: Optional[bool] = None,
                jit: bool = True,
                use_disk: bool = True,
                profile: bool = False,
@@ -455,8 +457,9 @@ def stripe_jit(fn_or_contraction: Union[Program, TileProgram, str, Callable],
     """Compile a tensor op end-to-end through the cached Stripe pipeline.
 
     ``workers`` enables the parallel autotune search on cold compiles;
-    ``interpret`` selects Pallas interpret mode (CPU validation) for the
-    pallas backend; ``cache`` defaults to the process-wide cache.
+    ``interpret`` selects Pallas interpret mode for the pallas backend
+    (default: compiled kernels on a TPU, interpret mode elsewhere);
+    ``cache`` defaults to the process-wide cache.
     ``profile=True`` wall-times each lowered unit on dispatch: the record
     carries per-unit measured latencies next to the cost model's
     predictions, and the first dispatch appends (predicted, measured)
@@ -480,6 +483,7 @@ def stripe_jit(fn_or_contraction: Union[Program, TileProgram, str, Callable],
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected one of {BACKENDS}")
+    interpret = resolve_interpret(interpret)
     if cache is None:
         cache = _cache.get_default_cache()
     if mesh is None and getattr(hw, "mesh_devices", lambda: 1)() > 1:
@@ -614,7 +618,7 @@ def _single_device_hw(hw: HardwareConfig) -> HardwareConfig:
 def _stripe_jit_mesh(fn_or_contraction, hw: HardwareConfig, backend: str,
                      resolved, *, tensors=None, out=None, ranges=None,
                      cache: Optional[_cache.CompilationCache] = None,
-                     workers: Optional[int] = None, interpret: bool = True,
+                     workers: Optional[int] = None, interpret: bool = False,
                      jit: bool = True, use_disk: bool = True,
                      profile: bool = False,
                      tune: Union[None, bool, Any] = None) -> CompiledProgram:
@@ -771,7 +775,7 @@ def compile_with_tilings(fn_or_contraction: Union[Program, TileProgram, str, Cal
                          tensors: Optional[Mapping[str, Tuple]] = None,
                          out: Optional[str] = None,
                          ranges: Optional[Mapping[str, int]] = None,
-                         interpret: bool = True,
+                         interpret: Optional[bool] = None,
                          jit: bool = True,
                          profile: bool = False) -> CompiledProgram:
     """Compile with a **fixed tiling assignment** — no cache, no search.
@@ -784,6 +788,7 @@ def compile_with_tilings(fn_or_contraction: Union[Program, TileProgram, str, Cal
     candidates is the tiling (and the backend), never the model."""
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected one of {BACKENDS}")
+    interpret = resolve_interpret(interpret)
     t0 = time.perf_counter()
     prog = _as_program(fn_or_contraction, tensors=tensors, out=out, ranges=ranges)
     ir_fp = ir_fingerprint(prog)

@@ -174,8 +174,11 @@ TPU_V5E = HardwareConfig(
     name="tpu_v5e",
     mem_units=(
         MemoryUnit("HBM", 16 * 2**30, 819e9, cache_line_elems=128),
-        # VMEM: ~128 MiB; budget half for double-buffering headroom
-        MemoryUnit("VMEM", 128 * 2**20, 2.7e12, cache_line_elems=128),
+        # VMEM: 128 MiB on the chip, of which a kernel gets the scoped
+        # limit it asks Mosaic for.  The unit is the most the emitter asks
+        # for one kernel (its vmem_limit_bytes cap; half the chip, the rest
+        # left to Mosaic), so the planner budgets only what a kernel gets
+        MemoryUnit("VMEM", 64 * 2**20, 2.7e12, cache_line_elems=128),
         MemoryUnit("VREG", 32 * 2**10, 1e14, cache_line_elems=8),
     ),
     stencils=(
@@ -192,8 +195,12 @@ TPU_V5E = HardwareConfig(
         ("autotile", {
             "cost": "roofline",
             "search": "pow2",
-            "mem_cap_frac": 0.45,   # of VMEM; leaves room for double buffering
+            # of VMEM for the planned tile (pipeline slots + accumulator);
+            # the rest is Mosaic's own scratch
+            "mem_cap_frac": 0.45,
             "count_untiled": True,
+            # (sublane, lane): Mosaic's block alignment of the two minor dims
+            "tile_align": (8, 128),
         }),
         ("stencil", {"stencil": "mxu", "min_dim": 16}),
         ("boundary", {"mode": "remainder"}),
@@ -244,6 +251,11 @@ CPU_TEST = HardwareConfig(
 
 REGISTRY: Dict[str, HardwareConfig] = {
     c.name: c for c in (TPU_V5E, PAPER_FIG4, CPU_TEST)
+}
+
+# ``jax.Device.device_kind`` -> registry name of the chip's config
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
 }
 
 

@@ -65,6 +65,13 @@ from .lower_jnp import _J_BINARY, _J_UNARY, _acc_dtype
 
 MAX_WINDOW_STEPS = 512           # unrolled kernel steps per grid point
 MAX_HALO_BYTES = 256 * 2**20     # materialized (gathered) operand budget
+# scoped VMEM Mosaic gives a kernel that asks for no more; a kernel is
+# never asked to make do with less
+MOSAIC_DEFAULT_VMEM = 16 * 2**20
+# Mosaic's own scratch on top of the planned arena (intermediates of the
+# kernel body, the output's second pipeline buffer): a quarter of the
+# arena plus this much
+VMEM_SLACK = 2 * 2**20
 
 
 class UnsupportedPallas(Exception):
@@ -264,6 +271,12 @@ class ContractionPlan:
     scale: float
     lhs_contract: Tuple[int, ...]
     rhs_contract: Tuple[int, ...]
+    # operand tile shapes the kernel contracts: unit dims ahead of the
+    # batch dims dropped, the batch dims (shared with the output, leading
+    # in both operands) merged into the one batch dim the TPU matmul takes
+    lhs_shape: Tuple[int, ...]
+    rhs_shape: Tuple[int, ...]
+    n_batch: int
     epilogue: List[object]
     acc_scalar: Optional[str]
 
@@ -332,7 +345,8 @@ def _ensure_grid(outer: Block) -> Block:
 
 
 def _collect(outer: Block):
-    """Common scaffolding: grid refs, local allocs, leaf stmts, epilogue."""
+    """Common scaffolding: grid refs, local allocs, the leaf block and its
+    statements, epilogue."""
     grid_ranges = {i.name: i.range for i in outer.idxs if not i.is_passthrough()}
     ins: List[GridRef] = []
     out: Optional[GridRef] = None
@@ -360,7 +374,6 @@ def _collect(outer: Block):
         # sub-block are the (pure elementwise) fused epilogue, which lifts
         # soundly from per-point to per-tile granularity.
         cur: Block = outer
-        leaf_stmts: List = []
         while True:
             msubs = cur.sub_blocks()
             trailing = []
@@ -372,21 +385,46 @@ def _collect(outer: Block):
                     trailing.append(s)
             if msubs and trailing:
                 epilogue = trailing
-                leaf_stmts = list(_leaf_of(msubs[0]).stmts)
+                leaf = _leaf_of(msubs[0])
                 break
             if not msubs:
-                leaf_stmts = list(cur.stmts)
+                leaf = cur
                 break
             if len(msubs) != 1:
                 raise UnsupportedPallas("multiple inner blocks")
             cur = msubs[0]
     else:
-        leaf_stmts = list(outer.stmts)
-    return grid_ranges, ins, out, local_alloc, leaf_stmts, epilogue
+        leaf = outer
+    return grid_ranges, ins, out, local_alloc, leaf, epilogue
+
+
+def _dim_names(ref: Refinement, tag: str) -> List[str]:
+    """Name each dim of a leaf refinement by the index variable that
+    addresses it; a constant offset (a unit dim) gets a name of its own."""
+    names: List[str] = []
+    for d, e in enumerate(ref.offsets):
+        if e.is_const():
+            names.append(f"{tag}#{d}")
+        elif len(e.terms) == 1:
+            names.append(e.terms[0][0])
+        else:
+            raise UnsupportedPallas(f"multi-index tile access {e}")
+    return names
+
+
+def _leaf_ref(leaf: Block, buf: str) -> Refinement:
+    for r in leaf.refs:
+        if r.from_buf == buf or r.into == buf:
+            return r
+    raise UnsupportedPallas(f"leaf block does not address {buf}")
 
 
 def extract_contraction(outer: Block) -> ContractionPlan:
-    grid_ranges, ins, out, local_alloc, leaf_stmts, epilogue = _collect(outer)
+    """Plan one MXU contraction.  Operand and output dims are identified by
+    the leaf block's index variables: a variable on both operands and not
+    on the output is contracted, one on both operands and the output is a
+    batch dim, one on a single operand and the output is free."""
+    grid_ranges, ins, out, local_alloc, leaf, epilogue = _collect(outer)
     if (out.ref.agg or "assign") not in ("add", "assign"):
         # dot_general + the scratch accumulation only realize a SUM
         raise UnsupportedPallas(
@@ -395,7 +433,7 @@ def extract_contraction(outer: Block) -> ContractionPlan:
     red_vars = [v for v in grid_ranges if v not in out_vars]
     grid_order = [v for v in grid_ranges if v in out_vars] + red_vars
 
-    root = _leaf_root(leaf_stmts)
+    root = _leaf_root(leaf.stmts)
     sig_of = {g.ref.into: (g.dim_vars, g.block_shape) for g in ins}
     lhs, rhs, scale = _split_contraction(root, sig_of)
 
@@ -414,45 +452,67 @@ def extract_contraction(outer: Block) -> ContractionPlan:
     lhs_bufs, rhs_bufs = side_bufs(lhs), side_bufs(rhs)
     lhs_gr = next(g for g in ins if g.ref.into == lhs_bufs[0])
     rhs_gr = next(g for g in ins if g.ref.into == rhs_bufs[0])
+    leaf_out = next((r for r in leaf.refs
+                     if r.dir in (RefDir.OUT, RefDir.INOUT)), None)
+    if leaf_out is None:
+        raise UnsupportedPallas("leaf block has no output")
+    ln = _dim_names(_leaf_ref(leaf, lhs_bufs[0]), "lhs")
+    rn = _dim_names(_leaf_ref(leaf, rhs_bufs[0]), "rhs")
+    on = _dim_names(leaf_out, "out")
+    if len(on) != len(out.block_shape):
+        raise UnsupportedPallas("output tile rank differs from its leaf view")
 
-    def contract_axes(gr: GridRef) -> List[int]:
-        axes = []
-        for d in range(gr.ref.rank):
-            v = gr.dim_vars[d]
-            if v is not None and v in out_vars:
-                continue
-            axes.append(d)
-        return axes
-
-    lhs_c, rhs_c = contract_axes(lhs_gr), contract_axes(rhs_gr)
-    lhs_final, rhs_final, used = [], [], set()
-    for a in lhs_c:
-        for b in rhs_c:
-            bv, av = rhs_gr.dim_vars[b], lhs_gr.dim_vars[a]
-            if b in used or lhs_gr.block_shape[a] != rhs_gr.block_shape[b]:
-                continue
-            if av is not None and bv is not None and av != bv:
-                continue  # distinct reduction vars never pair
-            lhs_final.append(a)
-            rhs_final.append(b)
-            used.add(b)
-            break
-    if not lhs_final:
+    contract = [n for n in ln if n in rn and n not in on]
+    batch = [n for n in ln if n in rn and n in on]
+    for names, gr in ((ln, lhs_gr), (rn, rhs_gr)):
+        for n, size in zip(names, gr.block_shape):
+            if n not in contract and n not in on and "#" not in n:
+                raise UnsupportedPallas(f"one-sided reduction over {n}")
+    if not contract:
         raise UnsupportedPallas("no contraction dims found")
+    nb = len(batch)
+    lshape, rshape = tuple(lhs_gr.block_shape), tuple(rhs_gr.block_shape)
+    if nb:
+        def lead(names, shape):
+            k = 0
+            while k < len(names) and "#" in names[k] and shape[k] == 1:
+                k += 1
+            if names[k:k + nb] != batch:
+                raise UnsupportedPallas(
+                    f"batch dims {batch} are not leading in {names}")
+            if len(names) - k - nb < 2:
+                raise UnsupportedPallas("batch dims reach the minor tile dims")
+            batch_elems = 1
+            for d in shape[k:k + nb]:
+                batch_elems *= d
+            return names[k + nb:], (batch_elems,) + shape[k + nb:]
 
+        ln, lshape = lead(ln, lshape)
+        rn, rshape = lead(rn, rshape)
+        on = lead(on, tuple(out.block_shape))[0]
+    real = lambda names: [n for n in names if "#" not in n]  # noqa: E731
+    result = real([n for n in ln if n not in contract]
+                  + [n for n in rn if n not in contract])
+    if result != real(on):
+        raise UnsupportedPallas(
+            f"dot result dims {result} are not the output tile's {real(on)}")
+    off = 1 if nb else 0
     return ContractionPlan(
         grid_order=grid_order, grid_sizes=grid_ranges, in_refs=ins, out_ref=out,
         red_vars=red_vars, lhs=lhs, rhs=rhs, lhs_bufs=lhs_bufs, rhs_bufs=rhs_bufs,
-        scale=scale, lhs_contract=tuple(lhs_final), rhs_contract=tuple(rhs_final),
+        scale=scale,
+        lhs_contract=tuple(off + ln.index(n) for n in contract),
+        rhs_contract=tuple(off + rn.index(n) for n in contract),
+        lhs_shape=lshape, rhs_shape=rshape, n_batch=nb,
         epilogue=epilogue, acc_scalar=acc_scalar,
     )
 
 
 def extract_elementwise(outer: Block) -> ElementwisePlan:
-    grid_ranges, ins, out, _local, leaf_stmts, epilogue = _collect(outer)
+    grid_ranges, ins, out, _local, leaf, epilogue = _collect(outer)
     if epilogue:
         raise UnsupportedPallas("elementwise block with trailing epilogue")
-    root = _leaf_root(leaf_stmts)
+    root = _leaf_root(leaf.stmts)
     # broadcast legality: each input's addressed dims must line up with the
     # trailing dims of the output tile (numpy broadcasting in the kernel)
     out_dv = list(out.dim_vars)
@@ -656,16 +716,49 @@ def _apply_epilogue(plan: ContractionPlan, acc, tile_args: Dict[str, jnp.ndarray
     return result
 
 
-def _dimension_semantics(grid_order: List[str], red_vars) -> Optional[object]:
-    """Mark parallel (output) grid axes for Mosaic; reduction axes are
-    'arbitrary' because the scratch accumulation carries state across
-    their steps."""
+def _compiler_params(grid_order: List[str], red_vars,
+                     mp: Optional[memplan.BlockPlan],
+                     vmem_cap: Optional[int],
+                     blocks: Sequence[Tuple[Sequence[int], Optional[Sequence[int]],
+                                            Refinement]],
+                     buffers: Optional[Mapping[str, TensorDecl]]
+                     ) -> "pltpu.CompilerParams":
+    """Mosaic parameters of one kernel, after checking that every block
+    ``(block shape, array shape, refinement)`` is aligned to the TPU
+    tiling; an array shape of None is the declared buffer's.  Parallel (output-streaming) grid axes may be reordered or
+    split across cores; reduction axes are 'arbitrary' because the scratch
+    accumulation carries state across their steps.  The scoped VMEM limit
+    is what the memory plan needs, so the planner and the compiler agree;
+    a kernel needing more than the chip grants (``vmem_cap``) is refused
+    here, at lowering time."""
+    for shape, full, ref in blocks:
+        if full is None and buffers is not None and ref.from_buf in buffers:
+            full = buffers[ref.from_buf].shape
+        if full is not None:
+            _check_tpu_block(shape, full, ref.dtype, ref.from_buf)
     red = set(red_vars)
     sem = tuple("arbitrary" if v in red else "parallel" for v in grid_order)
-    try:
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)
-    except Exception:  # pragma: no cover - API drift across jax versions
-        return None
+    if mp is None:
+        return pltpu.CompilerParams(dimension_semantics=sem)
+    need = mp.peak_bytes + mp.peak_bytes // 4 + VMEM_SLACK
+    if vmem_cap is not None and need > vmem_cap:
+        raise UnsupportedPallas(
+            f"kernel needs {need}B of VMEM (planned arena {mp.peak_bytes}B); "
+            f"a kernel is granted {vmem_cap}B")
+    return pltpu.CompilerParams(dimension_semantics=sem,
+                                vmem_limit_bytes=max(need, MOSAIC_DEFAULT_VMEM))
+
+
+def _check_tpu_block(block: Sequence[int], full: Sequence[int], dtype,
+                     what: str) -> None:
+    """Mosaic tiles the two minor dims of every block by (sublane, 128
+    lanes): each must be a multiple of the tile or span the whole array."""
+    sub = 8 * max(1, 4 // np.dtype(dtype).itemsize)
+    for k, tile in ((1, 128), (2, sub)):
+        if len(block) >= k and block[-k] % tile and block[-k] != full[-k]:
+            raise UnsupportedPallas(
+                f"{what}: block {tuple(block)} of {tuple(full)} is not aligned "
+                f"to the ({sub}, 128) TPU tiling")
 
 
 def _index_map_for(gr: GridRef, gpos: Mapping[str, int]):
@@ -811,7 +904,8 @@ def _contract_sides(sides_vals: List[Tuple[jnp.ndarray, List[str]]],
 
 def _emit_windowed(plan: WindowedPlan, interpret: bool,
                    mp: Optional[memplan.BlockPlan] = None,
-                   buffers: Optional[Mapping[str, TensorDecl]] = None) -> Callable:
+                   buffers: Optional[Mapping[str, TensorDecl]] = None,
+                   vmem_cap: Optional[int] = None) -> Callable:
     grid = tuple(plan.grid_sizes[v] for v in plan.grid_order)
     gpos = {v: i for i, v in enumerate(plan.grid_order)}
     out_block = plan.out_ref.block_shape
@@ -946,10 +1040,14 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
 
     kwargs = {}
     if not interpret:
-        cp = _dimension_semantics(plan.grid_order,
-                                  mp.red_vars if mp is not None else plan.red_vars)
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+        # a materialized halo operand is the kernel's own array: only the
+        # views taken straight from a buffer are checked against it
+        blocks = [(bshape, None, gr.ref) for (prep, bshape), gr
+                  in zip(preps, plan.in_refs) if prep is None]
+        kwargs["compiler_params"] = _compiler_params(
+            plan.grid_order, mp.red_vars if mp is not None else plan.red_vars,
+            mp, vmem_cap, blocks + [(out_block, out_full_shape, plan.out_ref.ref)],
+            buffers)
     scratch = [pltpu.VMEM(out_block, acc_dtype)] if has_red else []
     call = pl.pallas_call(
         kernel,
@@ -977,7 +1075,9 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
 
 
 def _emit_contraction(plan: ContractionPlan, interpret: bool,
-                      mp: Optional[memplan.BlockPlan] = None) -> Callable:
+                      mp: Optional[memplan.BlockPlan] = None,
+                      buffers: Optional[Mapping[str, TensorDecl]] = None,
+                      vmem_cap: Optional[int] = None) -> Callable:
     grid = tuple(plan.grid_sizes[v] for v in plan.grid_order)
     gpos = {v: i for i, v in enumerate(plan.grid_order)}
 
@@ -986,7 +1086,8 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
     extra = [g for g in plan.in_refs if g.ref.into not in side]
     order = operand_grs + extra
 
-    dnums = ((plan.lhs_contract, plan.rhs_contract), ((), ()))
+    batch = ((0,), (0,)) if plan.n_batch else ((), ())
+    dnums = ((plan.lhs_contract, plan.rhs_contract), batch)
     out_dtype = np.dtype(plan.out_ref.ref.dtype)
     acc_dtype = _acc_dtype(plan.out_ref.ref.dtype)
     cast_ints = np.dtype(out_dtype).kind in "iu"
@@ -1019,6 +1120,9 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
             tiles = {k: v.astype(acc_dtype) for k, v in tiles.items()}
         lhs = _eval_tnode(plan.lhs, tiles)
         rhs = _eval_tnode(plan.rhs, tiles)
+        if plan.n_batch:
+            lhs = lhs.reshape(plan.lhs_shape)
+            rhs = rhs.reshape(plan.rhs_shape)
         part = jax.lax.dot_general(lhs, rhs, dnums, preferred_element_type=acc_dtype)
         part = part.reshape(out_block)
         if plan.scale != 1.0:
@@ -1063,10 +1167,10 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
         # planned slots gate the semantics: grid axes that stream the
         # output may be reordered/parallelized by Mosaic; axes that
         # revisit the planned accumulator carry state and stay arbitrary
-        cp = _dimension_semantics(plan.grid_order,
-                                  mp.red_vars if mp is not None else plan.red_vars)
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+        kwargs["compiler_params"] = _compiler_params(
+            plan.grid_order, mp.red_vars if mp is not None else plan.red_vars,
+            mp, vmem_cap, [(g.block_shape, None, g.ref) for g in order]
+            + [(out_block, out_full_shape, plan.out_ref.ref)], buffers)
     scratch = []
     if has_red:
         # sized by the memory plan when available (acc_bytes == f32 out
@@ -1094,11 +1198,16 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
     return fn
 
 
-def _emit_elementwise(plan: ElementwisePlan, interpret: bool) -> Callable:
+def _emit_elementwise(plan: ElementwisePlan, interpret: bool,
+                      mp: Optional[memplan.BlockPlan] = None,
+                      buffers: Optional[Mapping[str, TensorDecl]] = None,
+                      vmem_cap: Optional[int] = None) -> Callable:
     grid = tuple(plan.grid_sizes[v] for v in plan.grid_order)
     gpos = {v: i for i, v in enumerate(plan.grid_order)}
     out_block = plan.out_ref.block_shape
     out_dtype = np.dtype(plan.out_ref.ref.dtype)
+    out_full_shape = tuple(s * (plan.grid_sizes[v] if v else 1)
+                           for s, v in zip(out_block, plan.out_ref.dim_vars))
 
     def kernel(*refs):
         *ins, out_ref = refs
@@ -1108,20 +1217,17 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool) -> Callable:
 
     kwargs = {}
     if not interpret:
-        cp = _dimension_semantics(plan.grid_order, ())
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+        kwargs["compiler_params"] = _compiler_params(
+            plan.grid_order, (), mp, vmem_cap,
+            [(g.block_shape, None, g.ref) for g in plan.in_refs]
+            + [(out_block, out_full_shape, plan.out_ref.ref)], buffers)
     call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec(g.block_shape, _index_map_for(g, gpos))
                   for g in plan.in_refs],
         out_specs=pl.BlockSpec(out_block, _index_map_for(plan.out_ref, gpos)),
-        out_shape=jax.ShapeDtypeStruct(
-            tuple(s * (plan.grid_sizes[v] if v else 1)
-                  for s, v in zip(out_block, plan.out_ref.dim_vars)),
-            out_dtype,
-        ),
+        out_shape=jax.ShapeDtypeStruct(out_full_shape, out_dtype),
         interpret=interpret,
         **kwargs,
     )
@@ -1130,8 +1236,7 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool) -> Callable:
         args = [jnp.asarray(arrays[g.ref.from_buf]) for g in plan.in_refs]
         return call(*args)
 
-    fn.out_shape = tuple(s * (plan.grid_sizes[v] if v else 1)
-                         for s, v in zip(out_block, plan.out_ref.dim_vars))
+    fn.out_shape = out_full_shape
     fn.out_dtype = out_dtype
     fn.out_base = plan.out_ref.base
     fn.in_bufs = [g.ref.from_buf for g in plan.in_refs]
@@ -1140,13 +1245,15 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool) -> Callable:
 
 def lower_op_pallas(outer: Block, interpret: bool = False,
                     pipeline_depth: int = 2,
-                    buffers: Optional[Mapping[str, TensorDecl]] = None) -> Callable:
+                    buffers: Optional[Mapping[str, TensorDecl]] = None,
+                    vmem_cap: Optional[int] = None) -> Callable:
     """Returns fn(arrays: dict) -> output array for one optimized op block
     or fusion group (a single ``pallas_call``).  ``pipeline_depth`` is the
     hardware's DMA-pipeline depth (``HardwareConfig.pipeline_depth``),
     threaded into the memory plan so its slot figures match the schedule's;
     ``buffers`` (the program's declarations) sizes the padded operand of
-    halo views.
+    halo views and checks block alignment; ``vmem_cap`` is the scoped VMEM
+    one kernel may be granted (the hardware config's inner memory).
 
     Emission paths are tried in order — dense contraction / elementwise
     for constraint-free aligned blocks, then the windowed (halo + masked
@@ -1179,23 +1286,27 @@ def lower_op_pallas(outer: Block, interpret: bool = False,
     if not constrained:
         if agg == "assign" and not outer.sub_blocks():
             attempt("elementwise",
-                    lambda: _emit_elementwise(extract_elementwise(outer), interpret))
+                    lambda: _emit_elementwise(extract_elementwise(outer), interpret,
+                                              mp, buffers, vmem_cap))
         elif agg == "assign":
             # a fused group's outer agg is on its local accumulator; decide
             # by whether a reduction sub-structure exists — both reasons
             # are recorded when neither path fits
             attempt("contraction",
-                    lambda: _emit_contraction(extract_contraction(outer), interpret, mp=mp))
+                    lambda: _emit_contraction(extract_contraction(outer), interpret,
+                                              mp, buffers, vmem_cap))
             attempt("elementwise",
-                    lambda: _emit_elementwise(extract_elementwise(outer), interpret))
+                    lambda: _emit_elementwise(extract_elementwise(outer), interpret,
+                                              mp, buffers, vmem_cap))
         else:
             attempt("contraction",
-                    lambda: _emit_contraction(extract_contraction(outer), interpret, mp=mp))
+                    lambda: _emit_contraction(extract_contraction(outer), interpret,
+                                              mp, buffers, vmem_cap))
     # the general halo/masked path: constraint-carrying blocks (boundary
     # remainders, conv halos) and halo views of constraint-free interiors
     attempt("windowed",
             lambda: _emit_windowed(extract_windowed(outer), interpret,
-                                   mp=mp, buffers=buffers))
+                                   mp, buffers, vmem_cap))
     if fn is None:
         raise UnsupportedPallas("; ".join(errors))
     fn.out_buf = out_ref.from_buf
@@ -1283,7 +1394,8 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
                          pipeline_depth: int = 2,
                          strict: bool = False,
                          profile: bool = False,
-                         force_jnp_units: Optional[set] = None) -> Callable:
+                         force_jnp_units: Optional[set] = None,
+                         vmem_cap: Optional[int] = None) -> Callable:
     """Lower every op block / fusion group to one Pallas kernel and
     compose the units in wavefront order; intermediates between groups
     live in outer memory (HBM).
@@ -1327,7 +1439,7 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
             for b in u.blocks:
                 fn = lower_op_pallas(b, interpret=interpret,
                                      pipeline_depth=pipeline_depth,
-                                     buffers=prog.buffers)
+                                     buffers=prog.buffers, vmem_cap=vmem_cap)
                 decl = prog.buffers.get(fn.out_buf)
                 if decl is None:
                     raise UnsupportedPallas(

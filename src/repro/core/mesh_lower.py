@@ -92,7 +92,6 @@ def emit(prog, plan: ShardPlan, segments: List[Segment], compiled: List,
     global (unsharded) arrays keyed like the single-device driver."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = plan.n
@@ -142,9 +141,9 @@ def emit(prog, plan: ShardPlan, segments: List[Segment], compiled: List,
                 raise ValueError(f"unknown plan step {step!r}")
         return tuple(env[o] for o in out_order)
 
-    sharded = shard_map(body, mesh=jmesh, in_specs=tuple(in_specs),
-                        out_specs=tuple(P() for _ in out_order),
-                        check_rep=False)
+    sharded = jax.shard_map(body, mesh=jmesh, in_specs=tuple(in_specs),
+                            out_specs=tuple(P() for _ in out_order),
+                            check_vma=False)
     if jit:
         sharded = jax.jit(sharded)
 

@@ -24,7 +24,7 @@ from typing import Dict, List, Mapping, Optional, Tuple
 
 from ..cost import TileCost, evaluate_tiling
 from ..hwconfig import HardwareConfig
-from ..ir import Block, Program
+from ..ir import Block, Program, dtype_bytes
 from ..poly import factors
 from ..tiling import split_block
 from . import register
@@ -48,6 +48,25 @@ def _candidates(r: int, search: str) -> List[int]:
         t *= 2
     out.append(r)
     return out
+
+
+def _minor_dim_steps(block: Block, align) -> Dict[str, int]:
+    """Tile-size step each index must keep where it alone addresses one of
+    the two minor dims of a view: the TPU lays a block's minor dim over
+    ``lane`` lanes and the one before over ``sublane`` 32-bit rows (twice
+    as many 16-bit ones).  An index's full range is always legal."""
+    sublane, lane = align
+    steps: Dict[str, int] = {}
+    for r in block.refs:
+        packing = max(1, 4 // dtype_bytes(r.dtype))
+        for d, step in ((r.rank - 1, lane), (r.rank - 2, sublane * packing)):
+            if d < 0:
+                continue
+            e = r.offsets[d]
+            if len(e.terms) == 1 and e.terms[0][1] == 1:
+                v = e.terms[0][0]
+                steps[v] = max(steps.get(v, 1), step)
+    return steps
 
 
 def _resolve_workers(params: Mapping) -> int:
@@ -104,6 +123,8 @@ def _search_parallel(block, hw, params, names, cands, workers):
     import concurrent.futures
     import multiprocessing
 
+    from ..platform import pin_worker_to_cpu
+
     combos = list(itertools.product(*(cands[v] for v in names)))
     # strip private injected state (oracles etc.) before shipping to workers
     clean = {k: v for k, v in params.items() if not k.startswith("_")}
@@ -122,7 +143,9 @@ def _search_parallel(block, hw, params, names, cands, workers):
             ctx = multiprocessing.get_context("forkserver")
         except ValueError:
             ctx = multiprocessing.get_context("fork")
-        with concurrent.futures.ProcessPoolExecutor(max_workers=workers, mp_context=ctx) as ex:
+        with concurrent.futures.ProcessPoolExecutor(
+                max_workers=workers, mp_context=ctx,
+                initializer=pin_worker_to_cpu) as ex:
             futs = [
                 ex.submit(_search_chunk, block, hw, clean, names,
                           combos[i:i + chunk], i, macs_exact)
@@ -149,6 +172,11 @@ def choose_tiling(block: Block, hw: HardwareConfig, params: Mapping) -> Tuple[Di
     search = params.get("search", "pow2")
     names = sorted(free)
     cands = {v: _candidates(free[v], search) for v in names}
+    if params.get("tile_align"):
+        for v, step in _minor_dim_steps(block, params["tile_align"]).items():
+            if v in cands:
+                cands[v] = [c for c in cands[v]
+                            if c % step == 0 or c == free[v]] or [free[v]]
     # multiples of an existing stencil (tags like "stencil:v=8")
     for t in block.tags:
         if t.startswith("stencil:"):

@@ -31,6 +31,7 @@ from ..core.cost import ProgramScore, score_pass_trace
 from ..obs import trace as obs_trace
 from ..core.driver import compile_cached, compile_with_tilings, stripe_jit
 from ..core.hwconfig import HardwareConfig
+from ..core.platform import pin_worker_to_cpu, resolve_interpret
 from ..tune.measure import DEFAULT_CALLS, DEFAULT_ROUNDS, measure_interleaved
 from .space import SearchSpace
 from .workloads import Workload, get_workloads
@@ -119,8 +120,9 @@ def _run_points_parallel(space: SearchSpace, jobs: List[Tuple[int, Dict]],
             ctx = multiprocessing.get_context("forkserver")
         except ValueError:
             ctx = multiprocessing.get_context("fork")
-        with concurrent.futures.ProcessPoolExecutor(max_workers=parallel,
-                                                    mp_context=ctx) as ex:
+        with concurrent.futures.ProcessPoolExecutor(
+                max_workers=parallel, mp_context=ctx,
+                initializer=pin_worker_to_cpu) as ex:
             futs = [ex.submit(_score_point_task, space, point, idx,
                               workload_spec, cache_dir)
                     for idx, point in jobs]
@@ -469,11 +471,10 @@ def measure_candidates(sweep: SweepResult, *, db, backend: str = "pallas",
     tuning DB (``db``); the measured winner becomes the entry's best,
     which later ``stripe_jit(..., tune=...)`` compiles replay.
 
-    Candidates run on ``backend`` under ``interpret=True`` (tile sizes
-    change the pallas grid, so interpreted wall time carries real tiling
-    signal; the jnp lowering is tiling-independent).  A real-hardware
-    timer drops in via ``measure_interleaved``'s ``timer`` hook — the
-    estimator and DB schema don't change.  The analytic choice is always
+    Candidates run on ``backend``, compiled on a TPU and in Pallas
+    interpret mode elsewhere (tile sizes change the pallas grid, so even
+    interpreted wall time carries tiling signal; the jnp lowering is
+    tiling-independent).  The analytic choice is always
     candidate 0, so the summary's ``improved`` flag is measured-winner
     vs analytic on identical harnesses.
 
@@ -485,7 +486,8 @@ def measure_candidates(sweep: SweepResult, *, db, backend: str = "pallas",
     interleaved rounds on a certain loser."""
     base_hw = sweep.space.base_config()
     workloads = get_workloads(sweep.workload_spec)
-    summary: Dict[str, Any] = {"backend": backend, "interpret": True,
+    interpret = resolve_interpret(None)
+    summary: Dict[str, Any] = {"backend": backend, "interpret": interpret,
                                "rounds": rounds, "calls": calls,
                                "workloads": {}}
     for w in workloads:
@@ -506,7 +508,7 @@ def measure_candidates(sweep: SweepResult, *, db, backend: str = "pallas",
                 try:
                     compiled = compile_with_tilings(
                         w.build(), base_hw, cand, backend=backend,
-                        interpret=True)
+                        interpret=interpret)
                     arrays = _random_arrays(compiled.program.source
                                             or compiled.program)
                     thunk = _timed_thunk(compiled, arrays)

@@ -230,7 +230,7 @@ def tpu_sweep() -> SearchSpace:
             Axis("pipeline", ("default", "no-fuse"), default="default"),
             Axis("mem.HBM.bandwidth", (819e9, 1.2e12, 1.64e12), default=819e9),
             Axis("mem.VMEM.size_bytes",
-                 (64 * 2**20, 128 * 2**20, 256 * 2**20), default=128 * 2**20),
+                 (32 * 2**20, 64 * 2**20, 128 * 2**20), default=64 * 2**20),
             Axis("pipeline_depth", (2, 1, 3), default=2),
             Axis("autotile.mem_cap_frac", (0.3, 0.45, 0.6, 0.9), default=0.45),
             Axis("fuse.prefer", ("epilogue", "prologue"), default="epilogue"),

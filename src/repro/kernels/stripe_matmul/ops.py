@@ -7,6 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ...core.platform import resolve_interpret
 from .kernel import build_matmul_kernel
 from .ref import matmul_ref
 
@@ -20,13 +21,13 @@ def _run(x, w, bias, act, interpret):
 
 
 def matmul(x: jnp.ndarray, w: jnp.ndarray, bias: Optional[jnp.ndarray] = None,
-           act: Optional[str] = None, interpret: bool = True) -> jnp.ndarray:
+           act: Optional[str] = None, interpret: Optional[bool] = None) -> jnp.ndarray:
     """act(x @ w + bias) via the Stripe-compiled Pallas kernel.
 
-    ``interpret=True`` executes the kernel body on CPU (validation mode);
-    on a real TPU pass ``interpret=False``.
+    ``interpret`` defaults to the platform: the kernel runs compiled on a
+    TPU and in Pallas interpret mode (validation) everywhere else.
     """
-    return _run(x, w, bias, act, interpret)
+    return _run(x, w, bias, act, resolve_interpret(interpret))
 
 
 __all__ = ["matmul", "matmul_ref"]

@@ -11,6 +11,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
 from ..core import oplib
 
@@ -127,7 +128,13 @@ def embed_init(key, vocab: int, d: int, dtype) -> jnp.ndarray:
 
 
 def embed_lookup(table: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
-    return jnp.take(table, tokens, axis=0)
+    sharding = jax.typeof(tokens).sharding
+    if AxisType.Explicit not in sharding.mesh.axis_types:
+        return jnp.take(table, tokens, axis=0)
+    # under explicit-axis sharding the gather's output sharding is
+    # ambiguous (table rows vs ids): the looked-up rows follow the ids
+    return table.at[tokens].get(out_sharding=NamedSharding(
+        sharding.mesh, PartitionSpec(*sharding.spec, None)))
 
 
 def unembed(x: jnp.ndarray, table_or_w: jnp.ndarray, tied: bool) -> jnp.ndarray:

@@ -30,25 +30,16 @@ def axis_size(name: str, mesh=None) -> int:
     """Static size of the named mesh axis, inside *or* outside shard_map.
 
     Resolution order: an explicitly passed ``mesh``; the bound axis of
-    the enclosing shard_map/pmap trace (``jax.lax.axis_size`` on newer
-    jax, ``jax.core.axis_frame`` on 0.4.x); finally the ambient mesh of
-    a ``with mesh:`` context, so helpers like the collective-matmul
-    kernels and ZeRO-1 sharding arithmetic work when called at trace
-    level too."""
+    the enclosing shard_map/pmap trace (``jax.lax.axis_size``); finally
+    the ambient mesh of a ``with mesh:`` context, so helpers like the
+    collective-matmul kernels and ZeRO-1 sharding arithmetic work when
+    called at trace level too."""
     if mesh is not None and name in getattr(mesh, "shape", {}):
         return int(dict(mesh.shape)[name])
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        try:
-            return int(fn(name))
-        except Exception:
-            pass
-    else:
-        try:
-            frame = jax.core.axis_frame(name)
-            return int(getattr(frame, "size", frame))
-        except Exception:
-            pass
+    try:
+        return int(jax.lax.axis_size(name))
+    except NameError:
+        pass
     size = _static_mesh_size(name)
     if size is not None:
         return size

@@ -441,6 +441,24 @@ class ServingEngine:
                 self._get_prefill(b, params, warm=True)
         self._event("warm_start", buckets=buckets)
 
+    def _warm_decode(self, params) -> None:
+        """Compile the decode step before serving, outside the device-step
+        failure handler: a compiler refusal raises here instead of
+        becoming slot requeues.  The warm-up call points every slot at its
+        garbage page and discards the result."""
+        if self._decode_warm:
+            return
+        t0 = time.perf_counter()
+        table = np.tile(self._garbage[:, None], (1, self._pps))
+        zeros = jnp.zeros(self.slots, jnp.int32)
+        jax.block_until_ready(self._decode_fn(
+            params, self._pk, self._pv, jnp.asarray(table), zeros, zeros))
+        self._decode_warm = True
+        self._compile_log.append({
+            "kind": "decode", "slots": self.slots,
+            "kv_window": self._kv_window,
+            "first_call_s": time.perf_counter() - t0})
+
     # ----------------------------------------------------------- admission
     def submit(self, req: Request) -> bool:
         """Enqueue a request.  Validation is synchronous (raises here);
@@ -806,6 +824,7 @@ class ServingEngine:
             raise ValueError("no params: pass params= to run()/generate() "
                              "or construct the engine with params=")
         self._warm_start(params)
+        self._warm_decode(params)
         steps = 0
         stall = 0
         while steps < max_steps:
@@ -852,12 +871,6 @@ class ServingEngine:
             steps += 1
             self._steps += 1
             self._live_steps += len(live)
-            if not self._decode_warm:
-                self._decode_warm = True
-                self._compile_log.append({
-                    "kind": "decode", "slots": self.slots,
-                    "kv_window": self._kv_window,
-                    "first_call_s": time.perf_counter() - t0})
             for s in live:
                 r = self._slot_req[s]
                 tok = int(nxt[s])
@@ -922,6 +935,12 @@ class ServingEngine:
         groups, kernel counts, per-block backends and fallbacks), keyed
         ``decode/<block>`` and ``prefill_L<bucket>/<block>``."""
         return dict(self._records)
+
+    def decode_programs(self):
+        """The decode step's Stripe-compiled block programs
+        (:class:`~repro.serving.stripe_decode.DecodePrograms`), or None
+        when ``use_stripe_decode`` is off."""
+        return self._decode_progs
 
     def events(self) -> List[Dict[str, Any]]:
         """Admission/eviction/fault-recovery event log (used by tests and

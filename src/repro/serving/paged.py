@@ -172,21 +172,23 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
                 k[:, 0].astype(pk.dtype))
             flat_v = pv.reshape(n_phys * ps, kv, hd).at[write_rows].set(
                 v[:, 0].astype(pv.dtype))
-            ck = flat_k[gather_rows].astype(jnp.float32)  # (S, T, KV, hd)
-            cv = flat_v[gather_rows].astype(jnp.float32)
+            # head-major window (S, KV, T, hd): slot and head lead, as the
+            # batch dims of the score/value contractions
+            ck = flat_k[gather_rows].astype(jnp.float32).transpose(0, 2, 1, 3)
+            cv = flat_v[gather_rows].astype(jnp.float32).transpose(0, 2, 1, 3)
 
             qg = q[:, 0].reshape(s, kv, g, hd).astype(jnp.float32)
             if progs is not None and progs.scores is not None:
                 scores = progs.scores({"Q": qg, "K": ck})["S"]
             else:
-                scores = jnp.einsum("bkgd,btkd->bkgt", qg, ck)
+                scores = jnp.einsum("bkgd,bktd->bkgt", qg, ck)
             scores = scores * sm_scale
             scores = jnp.where(mask, scores, NEG_INF)
             probs = jax.nn.softmax(scores, axis=-1)
             if progs is not None and progs.values is not None:
                 o = progs.values({"P": probs, "V": cv})["O"]
             else:
-                o = jnp.einsum("bkgt,btkd->bkgd", probs, cv)
+                o = jnp.einsum("bkgt,bktd->bkgd", probs, cv)
             a2 = o.reshape(s, h * hd)
             if progs is not None:
                 x1 = run_attn_out(progs, a2, x[:, 0], ap["wo"])

@@ -67,7 +67,10 @@ class EngineConfig:
       remaining job first among the prepared requests).
     * ``backend`` / ``hw`` / ``interpret`` — the ``stripe_jit`` backend,
       hardware config name, and Pallas interpret flag used to compile the
-      decode-time attention/MLP blocks.
+      decode-time attention/MLP blocks.  Left ``None``, the platform
+      decides: on a TPU, compiled Pallas kernels for the attached chip's
+      config (an unknown chip is an error); elsewhere the ``jnp`` backend
+      and the ``tpu_v5e`` config, with Pallas in interpret mode.
     * ``use_stripe_decode`` — route decode blocks through ``stripe_jit``
       (the default); ``False`` uses plain jnp ops (same math, no compile
       records) for A/B measurement.
@@ -104,9 +107,9 @@ class EngineConfig:
     page_size: int = 16
     pages: Optional[int] = None
     admission: str = "fcfs"
-    backend: str = "jnp"
-    hw: str = "tpu_v5e"
-    interpret: bool = True
+    backend: Optional[str] = None
+    hw: Optional[str] = None
+    interpret: Optional[bool] = None
     use_stripe_decode: bool = True
     use_disk_cache: bool = False
     max_queue: Optional[int] = None
@@ -116,6 +119,15 @@ class EngineConfig:
     event_log_size: int = 10_000
     profile: bool = False
     tune: bool = False
+
+    def __post_init__(self) -> None:
+        from ..core import platform
+
+        if self.backend is None:
+            self.backend = platform.default_backend()
+        if self.hw is None:
+            self.hw = platform.default_hw_name()
+        self.interpret = platform.resolve_interpret(self.interpret)
 
     def validate(self) -> None:
         if self.slots < 1:

@@ -17,6 +17,10 @@ length for prefill):
   gathered paged KV (decode only; softmax stays outside — it is not a
   contraction);
 * ``values`` — the GQA value contraction ``O[b,k,g,d] += P·V``;
+
+  both take the KV window head-major, ``(b, k, t, d)``, so the slot and
+  KV-head dims lead every operand and become the one batch dim of the
+  TPU matmul;
 * ``attn_out`` — output projection fused with the residual add;
 * ``mlp``    — the FFN with its activation chain fused between the
   matmuls when the activation is exactly representable as Stripe
@@ -61,7 +65,7 @@ class EngineLikeConfig:
 
     hw: HardwareConfig
     backend: str = "jnp"
-    interpret: bool = True
+    interpret: Optional[bool] = None  # None: compiled on a TPU only
     use_disk: bool = True
     cache: Optional[_cache.CompilationCache] = None
     profile: bool = False
@@ -210,9 +214,9 @@ def build_scores_program(cfg, m: int, t: int, jc: EngineLikeConfig) -> CompiledP
     g = cfg.n_heads // kv
     tp = TileProgram(f"serve_scores_m{m}_t{t}")
     tp.input("Q", (m, kv, g, hd))
-    tp.input("K", (m, t, kv, hd))
+    tp.input("K", (m, kv, t, hd))
     tp.output("S", (m, kv, g, t))
-    tp.op("S[b, k, g, t] += Q[b, k, g, d] * K[b, t, k, d]", name="scores")
+    tp.op("S[b, k, g, t] += Q[b, k, g, d] * K[b, k, t, d]", name="scores")
     return stripe_jit(tp.build(), jc.hw, **_jit_opts(jc))
 
 
@@ -221,9 +225,9 @@ def build_values_program(cfg, m: int, t: int, jc: EngineLikeConfig) -> CompiledP
     g = cfg.n_heads // kv
     tp = TileProgram(f"serve_values_m{m}_t{t}")
     tp.input("P", (m, kv, g, t))
-    tp.input("V", (m, t, kv, hd))
+    tp.input("V", (m, kv, t, hd))
     tp.output("O", (m, kv, g, hd))
-    tp.op("O[b, k, g, d] += P[b, k, g, t] * V[b, t, k, d]", name="values")
+    tp.op("O[b, k, g, d] += P[b, k, g, t] * V[b, k, t, d]", name="values")
     return stripe_jit(tp.build(), jc.hw, **_jit_opts(jc))
 
 

@@ -58,7 +58,10 @@ def test_dp_tp_train_step_matches_single_device():
 
         ref = float(jax.jit(loss_of)(params, batch))
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        # the sharding rules are GSPMD annotations: auto axes, which
+        # jax.make_mesh no longer defaults to
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         sizes = dict(mesh.shape)
         pspecs = shd.param_specs(params, sizes)
         bspecs = shd.batch_specs(batch, ('data',), sizes)
@@ -74,12 +77,34 @@ def test_dp_tp_train_step_matches_single_device():
     assert "OK" in out
 
 
+def test_embed_lookup_under_explicit_sharding():
+    """Under explicit mesh axes the embedding gather names its output
+    sharding: the looked-up rows follow the ids, not the table."""
+    out = run_driver("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.nn.core import embed_lookup
+
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Explicit,) * 2)
+        table = np.arange(64 * 8, dtype=np.float32).reshape(64, 8)
+        ids = np.random.default_rng(0).integers(0, 64, (8, 5)).astype(np.int32)
+        with jax.set_mesh(mesh):
+            t_sh = jax.device_put(table, NamedSharding(mesh, P('model', None)))
+            i_sh = jax.device_put(ids, NamedSharding(mesh, P('data', None)))
+            got = jax.jit(embed_lookup)(t_sh, i_sh)
+        assert got.sharding.spec == P('data', None, None), got.sharding
+        np.testing.assert_array_equal(np.asarray(got), table[ids])
+        print('OK')
+    """)
+    assert "OK" in out
+
+
 def test_zero1_matches_adamw():
     out = run_driver("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim import adamw, zero1
 
         cfg = adamw.AdamWConfig(lr=1e-2, warmup_steps=0, weight_decay=0.01)
@@ -92,12 +117,12 @@ def test_zero1_matches_adamw():
 
         mesh = jax.make_mesh((8,), ('data',))
         z_state = zero1.zero1_init_state(params, 8)
-        upd = shard_map(
+        upd = jax.shard_map(
             partial(zero1.zero1_update, cfg=cfg, axis='data'),
             mesh=mesh,
             in_specs=(P(), P(), {'m': P('data'), 'v': P('data'), 'step': P()}),
             out_specs=(P(), {'m': P('data'), 'v': P('data'), 'step': P()}, P()),
-            check_rep=False)
+            check_vma=False)
         new_p, new_s, info = jax.jit(upd)(params, grads, z_state)
         for k in params:
             np.testing.assert_allclose(np.asarray(new_p[k]), np.asarray(ref_p[k]), rtol=1e-5, atol=1e-6)
@@ -111,7 +136,6 @@ def test_collective_matmul_matches_baseline():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.parallel.collective_matmul import (
             ring_allgather_matmul, ring_matmul_reduce_scatter)
 
@@ -121,16 +145,16 @@ def test_collective_matmul_matches_baseline():
         w = jnp.asarray(rng.randn(32, 48), jnp.float32)
 
         # all-gather overlap: x rows sharded, w columns sharded
-        ag = shard_map(partial(ring_allgather_matmul, axis='model'), mesh=mesh,
+        ag = jax.shard_map(partial(ring_allgather_matmul, axis='model'), mesh=mesh,
                        in_specs=(P('model', None), P(None, 'model')),
-                       out_specs=P(None, 'model'), check_rep=False)
+                       out_specs=P(None, 'model'), check_vma=False)
         got = jax.jit(ag)(x, w)
         np.testing.assert_allclose(np.asarray(got), np.asarray(x @ w), rtol=1e-4, atol=1e-4)
 
         # reduce-scatter overlap: x sharded on K, w rows sharded
-        rs = shard_map(partial(ring_matmul_reduce_scatter, axis='model'), mesh=mesh,
+        rs = jax.shard_map(partial(ring_matmul_reduce_scatter, axis='model'), mesh=mesh,
                        in_specs=(P(None, 'model'), P('model', None)),
-                       out_specs=P(None, 'model'), check_rep=False)
+                       out_specs=P(None, 'model'), check_vma=False)
         got2 = jax.jit(rs)(x, w)
         np.testing.assert_allclose(np.asarray(got2), np.asarray(x @ w), rtol=1e-4, atol=1e-4)
         print('OK collective matmul')
@@ -143,7 +167,6 @@ def test_sp_decode_attention_matches_full():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.parallel.sp_attention import sp_decode_attention, full_decode_attention_ref
 
         mesh = jax.make_mesh((8,), ('data',))
@@ -161,9 +184,9 @@ def test_sp_decode_attention_matches_full():
             vl = jnp.clip(valid - start, 0, s_loc)
             return sp_decode_attention(q, k, v, vl, scale, axis='data')
 
-        fn = shard_map(sharded, mesh=mesh,
+        fn = jax.shard_map(sharded, mesh=mesh,
                        in_specs=(P(), P(None, 'data'), P(None, 'data'), P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         got = jax.jit(fn)(q, k, v, valid)
         want = full_decode_attention_ref(q, k, v, valid, scale)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
@@ -177,7 +200,6 @@ def test_pipeline_parallel_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.parallel.pipeline import pipeline_apply, bubble_fraction
 
         S, M, mb, d = 8, 4, 2, 16   # 8 stages, 4 microbatches
@@ -192,7 +214,7 @@ def test_pipeline_parallel_matches_sequential():
         def run(ws_shard, micro):
             return pipeline_apply(stage, ws_shard[0], micro, axis='pod')
 
-        fn = shard_map(run, mesh=mesh, in_specs=(P('pod'), P()), out_specs=P(), check_rep=False)
+        fn = jax.shard_map(run, mesh=mesh, in_specs=(P('pod'), P()), out_specs=P(), check_vma=False)
         outs = jax.jit(fn)(ws, x)
 
         want = x
@@ -210,7 +232,6 @@ def test_compressed_psum_error_feedback():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim.compress import compressed_psum, compression_ratio
 
         mesh = jax.make_mesh((8,), ('data',))
@@ -220,8 +241,8 @@ def test_compressed_psum_error_feedback():
         def step(g_shard, res):
             return compressed_psum(g_shard, 'data', res)
 
-        fn = shard_map(step, mesh=mesh, in_specs=(P('data'), P('data')),
-                       out_specs=(P('data'), P('data')), check_rep=False)
+        fn = jax.shard_map(step, mesh=mesh, in_specs=(P('data'), P('data')),
+                       out_specs=(P('data'), P('data')), check_vma=False)
         res = jnp.zeros_like(g)
         out1, res = jax.jit(fn)(g, res)
         want = jnp.broadcast_to(jnp.sum(g, 0, keepdims=True), g.shape)

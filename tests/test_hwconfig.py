@@ -66,7 +66,7 @@ def test_fingerprint_is_stable_and_distinct_across_configs():
 
 
 @pytest.mark.parametrize("mutate", [
-    lambda hw: hw.with_mem("VMEM", size_bytes=64 * 2**20),
+    lambda hw: hw.with_mem("VMEM", size_bytes=32 * 2**20),
     lambda hw: hw.with_mem("HBM", bandwidth=1.2e12),
     lambda hw: hw.with_mem("HBM", cache_line_elems=64),
     lambda hw: hw.with_stencil("mxu", dims=(256, 256, 128)),

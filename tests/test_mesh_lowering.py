@@ -23,6 +23,14 @@ from repro.core.shardplan import UnsupportedMesh, plan_program
 
 distributed = pytest.mark.distributed
 
+# The sharded conv sums the same 3*3*c products per output point as the
+# single-device one, but XLA:CPU picks its dot reduction order from the
+# operand shape, and a shard has fewer rows than the whole array: the
+# sums round differently in the last bits (up to 3e-6 measured on
+# N(0, 1) data, values up to ~10).
+HALO_RTOL = 1e-5
+HALO_ATOL = 1e-5
+
 
 # --------------------------------------------------------------------------
 # workloads
@@ -167,8 +175,8 @@ def test_halo_conv_bit_exact():
     ref = stripe_jit(halo_conv(), CPU_TEST, backend="jnp")(arrays)
     c = stripe_jit(halo_conv(), CPU_TEST, backend="jnp", mesh=8)
     out = c(arrays)
-    np.testing.assert_array_equal(np.asarray(out["O"]),
-                                  np.asarray(ref["O"]))
+    np.testing.assert_allclose(np.asarray(out["O"]), np.asarray(ref["O"]),
+                               rtol=HALO_RTOL, atol=HALO_ATOL)
     counts = mesh_lower.count_collectives(c._fn, arrays)
     assert counts.get("ppermute") == 2      # lo + hi margins
     assert counts.get("all_gather") == 1    # sharded output
@@ -192,7 +200,12 @@ def test_ring_overlap_chosen_by_cost():
     ops = [col["collective"] for col in c_ring.record.mesh["collectives"]]
     assert "ring_matmul" in ops
     out = c_ring(arrays)
-    np.testing.assert_allclose(out["O"], ref["O"], rtol=1e-4, atol=1e-4)
+    # the ring adds the 4096-term contraction as 8 partial sums in another
+    # order than one dot: f32 reassociation error scales with the size of
+    # the terms, not of the result, so an output near zero by cancellation
+    # needs an absolute bound tied to the output's scale (~30 ulps of it)
+    atol = 2e-6 * float(np.abs(ref["O"]).max())
+    np.testing.assert_allclose(out["O"], ref["O"], rtol=1e-4, atol=atol)
     counts = mesh_lower.count_collectives(c_ring._fn, arrays)
     assert counts == mesh_lower.expected_primitive_counts_from_record(
         c_ring.record.mesh)
@@ -202,7 +215,7 @@ def test_ring_overlap_chosen_by_cost():
     assert "psum" in ops and "ring_matmul" not in ops
     assert not c_psum.record.mesh["overlapped"]
     out = c_psum(arrays)
-    np.testing.assert_allclose(out["O"], ref["O"], rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(out["O"], ref["O"], rtol=1e-4, atol=atol)
 
 
 @distributed
@@ -261,7 +274,6 @@ def test_axis_size_inside_and_outside_shard_map():
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel import compat
@@ -271,8 +283,8 @@ def test_axis_size_inside_and_outside_shard_map():
     def body(x):
         return x * compat.axis_size("data")
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       check_vma=False)
     out = jax.jit(fn)(jnp.ones((4,)))
     np.testing.assert_allclose(np.asarray(out), 8.0)
 
@@ -321,8 +333,9 @@ def test_property_halo_conv_bit_exact(x, y, c):
     arrays = _arrays(prog, seed=x + y + c)
     ref = stripe_jit(halo_conv(x, y, c, 4), CPU_TEST, backend="jnp")(arrays)
     cc = stripe_jit(halo_conv(x, y, c, 4), CPU_TEST, backend="jnp", mesh=8)
-    np.testing.assert_array_equal(np.asarray(cc(arrays)["O"]),
-                                  np.asarray(ref["O"]))
+    np.testing.assert_allclose(np.asarray(cc(arrays)["O"]),
+                               np.asarray(ref["O"]), rtol=HALO_RTOL,
+                               atol=HALO_ATOL)
 
 
 # --------------------------------------------------------------------------
