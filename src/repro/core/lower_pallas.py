@@ -49,6 +49,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+import re
 import time
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -905,7 +906,8 @@ def _contract_sides(sides_vals: List[Tuple[jnp.ndarray, List[str]]],
 def _emit_windowed(plan: WindowedPlan, interpret: bool,
                    mp: Optional[memplan.BlockPlan] = None,
                    buffers: Optional[Mapping[str, TensorDecl]] = None,
-                   vmem_cap: Optional[int] = None) -> Callable:
+                   vmem_cap: Optional[int] = None,
+                   name: Optional[str] = None) -> Callable:
     grid = tuple(plan.grid_sizes[v] for v in plan.grid_order)
     gpos = {v: i for i, v in enumerate(plan.grid_order)}
     out_block = plan.out_ref.block_shape
@@ -1057,6 +1059,7 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
         out_shape=jax.ShapeDtypeStruct(out_full_shape, out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name=name,
         **kwargs,
     )
 
@@ -1077,7 +1080,8 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
 def _emit_contraction(plan: ContractionPlan, interpret: bool,
                       mp: Optional[memplan.BlockPlan] = None,
                       buffers: Optional[Mapping[str, TensorDecl]] = None,
-                      vmem_cap: Optional[int] = None) -> Callable:
+                      vmem_cap: Optional[int] = None,
+                      name: Optional[str] = None) -> Callable:
     grid = tuple(plan.grid_sizes[v] for v in plan.grid_order)
     gpos = {v: i for i, v in enumerate(plan.grid_order)}
 
@@ -1184,6 +1188,7 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
         out_shape=jax.ShapeDtypeStruct(out_full_shape, out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name=name,
         **kwargs,
     )
 
@@ -1201,7 +1206,8 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
 def _emit_elementwise(plan: ElementwisePlan, interpret: bool,
                       mp: Optional[memplan.BlockPlan] = None,
                       buffers: Optional[Mapping[str, TensorDecl]] = None,
-                      vmem_cap: Optional[int] = None) -> Callable:
+                      vmem_cap: Optional[int] = None,
+                      name: Optional[str] = None) -> Callable:
     grid = tuple(plan.grid_sizes[v] for v in plan.grid_order)
     gpos = {v: i for i, v in enumerate(plan.grid_order)}
     out_block = plan.out_ref.block_shape
@@ -1229,6 +1235,7 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool,
         out_specs=pl.BlockSpec(out_block, _index_map_for(plan.out_ref, gpos)),
         out_shape=jax.ShapeDtypeStruct(out_full_shape, out_dtype),
         interpret=interpret,
+        name=name,
         **kwargs,
     )
 
@@ -1246,14 +1253,17 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool,
 def lower_op_pallas(outer: Block, interpret: bool = False,
                     pipeline_depth: int = 2,
                     buffers: Optional[Mapping[str, TensorDecl]] = None,
-                    vmem_cap: Optional[int] = None) -> Callable:
+                    vmem_cap: Optional[int] = None,
+                    name: Optional[str] = None) -> Callable:
     """Returns fn(arrays: dict) -> output array for one optimized op block
     or fusion group (a single ``pallas_call``).  ``pipeline_depth`` is the
     hardware's DMA-pipeline depth (``HardwareConfig.pipeline_depth``),
     threaded into the memory plan so its slot figures match the schedule's;
     ``buffers`` (the program's declarations) sizes the padded operand of
     halo views and checks block alignment; ``vmem_cap`` is the scoped VMEM
-    one kernel may be granted (the hardware config's inner memory).
+    one kernel may be granted (the hardware config's inner memory);
+    ``name`` is the kernel's name (``pallas_call(name=)``), which the TPU
+    compiler gives the kernel's operation and a profile shows.
 
     Emission paths are tried in order — dense contraction / elementwise
     for constraint-free aligned blocks, then the windowed (halo + masked
@@ -1287,26 +1297,26 @@ def lower_op_pallas(outer: Block, interpret: bool = False,
         if agg == "assign" and not outer.sub_blocks():
             attempt("elementwise",
                     lambda: _emit_elementwise(extract_elementwise(outer), interpret,
-                                              mp, buffers, vmem_cap))
+                                              mp, buffers, vmem_cap, name))
         elif agg == "assign":
             # a fused group's outer agg is on its local accumulator; decide
             # by whether a reduction sub-structure exists — both reasons
             # are recorded when neither path fits
             attempt("contraction",
                     lambda: _emit_contraction(extract_contraction(outer), interpret,
-                                              mp, buffers, vmem_cap))
+                                              mp, buffers, vmem_cap, name))
             attempt("elementwise",
                     lambda: _emit_elementwise(extract_elementwise(outer), interpret,
-                                              mp, buffers, vmem_cap))
+                                              mp, buffers, vmem_cap, name))
         else:
             attempt("contraction",
                     lambda: _emit_contraction(extract_contraction(outer), interpret,
-                                              mp, buffers, vmem_cap))
+                                              mp, buffers, vmem_cap, name))
     # the general halo/masked path: constraint-carrying blocks (boundary
     # remainders, conv halos) and halo views of constraint-free interiors
     attempt("windowed",
             lambda: _emit_windowed(extract_windowed(outer), interpret,
-                                   mp, buffers, vmem_cap))
+                                   mp, buffers, vmem_cap, name))
     if fn is None:
         raise UnsupportedPallas("; ".join(errors))
     fn.out_buf = out_ref.from_buf
@@ -1330,6 +1340,17 @@ class _Unit:
     @property
     def name(self) -> str:
         return "+".join(self.members)
+
+
+def kernel_name(prog: Program, unit: _Unit, piece: int = 0) -> str:
+    """The stable name of one unit's kernel: the program's name and the
+    unit's member ops (``serve_mlp_m16.mm_gate``), with ``_p<i>`` for the
+    i-th boundary piece after the first, reduced to ``[A-Za-z0-9_.]``.
+    It depends on nothing but names, so it stays the same across runs
+    and across edits elsewhere."""
+    suffix = f"_p{piece}" if piece else ""
+    return re.sub(r"[^A-Za-z0-9_.]", "_",
+                  f"{prog.entry.name}.{'_'.join(unit.members)}{suffix}")
 
 
 def _units_of(prog: Program) -> List[_Unit]:
@@ -1417,7 +1438,10 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
     the Pallas attempt for those units — the tuning DB's replay of a
     measured per-unit backend choice (a unit that *measured* faster on
     the jnp path is not re-lowered to Pallas just because it legally
-    could be)."""
+    could be).
+
+    Each kernel is named by :func:`kernel_name`; the returned callable's
+    ``kernel_names`` lists them in lowering order."""
     blocks = [s for s in prog.entry.stmts if isinstance(s, Block)]
     if not blocks:
         raise UnsupportedPallas("no op blocks")
@@ -1430,16 +1454,19 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
     written_regions: Dict[str, List[Tuple[Tuple[int, ...], Tuple[int, ...]]]] = {}
     written: set = set()
     n_pallas = 0
+    kernel_names: List[str] = []
     for u in units:
         try:
             if force_jnp_units and u.name in force_jnp_units:
                 raise UnsupportedPallas("tuned: measured faster on jnp")
             kernels = []
             regions = []
-            for b in u.blocks:
+            names = [kernel_name(prog, u, piece) for piece in range(len(u.blocks))]
+            for b, name in zip(u.blocks, names):
                 fn = lower_op_pallas(b, interpret=interpret,
                                      pipeline_depth=pipeline_depth,
-                                     buffers=prog.buffers, vmem_cap=vmem_cap)
+                                     buffers=prog.buffers, vmem_cap=vmem_cap,
+                                     name=name)
                 decl = prog.buffers.get(fn.out_buf)
                 if decl is None:
                     raise UnsupportedPallas(
@@ -1462,6 +1489,7 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
             steps.append((u, "pallas", kernels))
             backends[u.name] = "pallas"
             n_pallas += len(kernels)
+            kernel_names += names
         except _ProgramFallback:
             raise
         except UnsupportedPallas as e:
@@ -1529,6 +1557,7 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
 
     run.n_kernels = n_pallas + sum(1 for _, kind, _ in steps if kind == "jnp")
     run.n_pallas = n_pallas
+    run.kernel_names = kernel_names
     run.block_backends = backends
     run.block_reasons = reasons
     run.unit_times = unit_times

@@ -22,11 +22,20 @@ Usage::
         ...
     obs.export_chrome_trace("trace.json")
 
+Profiler clock: while tracing is enabled, each live ``span()`` also
+enters a ``jax.profiler.TraceAnnotation`` of the same name, so a
+``jax.profiler.trace`` taken meanwhile shows the program's spans on the
+host threads beside the device's operations.  The annotation carries only
+the scalar attributes named in :data:`ANNOTATED_ATTRS`; JAX is imported
+on the first annotated span, so importing ``repro.obs`` stays light.
+
 Cross-thread intervals that cannot be expressed as a ``with`` block on
 one thread (e.g. a request's queue wait, stamped at submit on the feeder
 thread and closed at admission on the serving thread) are recorded
 retroactively with :func:`span_at`, passing explicit
-``time.perf_counter()`` endpoints.
+``time.perf_counter()`` endpoints.  Such spans, and :func:`instant`
+markers, stay in this tracer only: a profiler annotation is a live scope
+and cannot be stamped after the fact.
 
 Enable at import time with ``STRIPE_TRACE=1`` in the environment.
 """
@@ -44,6 +53,19 @@ ENV_TRACE = "STRIPE_TRACE"
 #: default ring-buffer capacity (finished spans retained); beyond it the
 #: oldest spans are dropped and counted in ``Tracer.dropped``
 DEFAULT_CAPACITY = 200_000
+
+#: span attributes copied onto the span's profiler annotation (scalars
+#: only); every other attribute stays in this tracer's record
+ANNOTATED_ATTRS = ("step", "uid", "bucket")
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """The profiler annotation of a live span, entered by the caller."""
+    from jax.profiler import TraceAnnotation
+
+    kw = {k: attrs[k] for k in ANNOTATED_ATTRS
+          if isinstance(attrs.get(k), (int, float, str))}
+    return TraceAnnotation(name, **kw)
 
 
 class SpanRecord:
@@ -98,29 +120,42 @@ _NULL = _NullSpan()
 
 class _Span:
     """A live span (context manager).  ``set(**attrs)`` attaches
-    attributes discovered mid-span (e.g. which cache level hit)."""
+    attributes discovered mid-span (e.g. which cache level hit).  After
+    the block, ``dur`` holds its seconds.  A span made with
+    ``record=False`` (see :meth:`Tracer.timed`) only measures."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_parent", "_depth")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_parent", "_depth",
+                 "_record", "_ann", "dur")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
+                 record: bool = True):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._record = record
+        self._ann = None
+        self.dur = 0.0
 
     def set(self, **attrs) -> "_Span":
         self.attrs.update(attrs)
         return self
 
     def __enter__(self) -> "_Span":
-        stack = self._tracer._stack()
-        self._parent = stack[-1] if stack else ""
-        self._depth = len(stack)
-        stack.append(self.name)
+        if self._record:
+            stack = self._tracer._stack()
+            self._parent = stack[-1] if stack else ""
+            self._depth = len(stack)
+            stack.append(self.name)
+            self._ann = _annotation(self.name, self.attrs)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        dur = time.perf_counter() - self._t0
+        self.dur = dur = time.perf_counter() - self._t0
+        if not self._record:
+            return False
+        self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.name:
             stack.pop()
@@ -180,6 +215,13 @@ class Tracer:
         if not self.enabled:
             return _NULL
         return _Span(self, name, attrs)
+
+    def timed(self, name: str, **attrs) -> _Span:
+        """Like :meth:`span`, but the returned span always measures its
+        block (``dur``, seconds); it is recorded and annotated only while
+        tracing is enabled.  For rare events whose duration the caller
+        keeps too, so that one measurement serves both."""
+        return _Span(self, name, attrs, record=self.enabled)
 
     def span_at(self, name: str, start_s: float, end_s: float, **attrs) -> None:
         """Record a span with explicit ``time.perf_counter`` endpoints —
@@ -277,6 +319,10 @@ def set_tracer(tracer: Tracer) -> None:
 
 def span(name: str, **attrs):
     return _default.span(name, **attrs)
+
+
+def timed(name: str, **attrs):
+    return _default.timed(name, **attrs)
 
 
 def span_at(name: str, start_s: float, end_s: float, **attrs) -> None:

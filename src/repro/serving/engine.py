@@ -55,6 +55,13 @@ Architecture (one PR-sized tour; DESIGN.md §9 has the long form):
     output), while healthy slots keep decoding;
   - *page-allocation failures* — a failed allocation defers the
     admission (``alloc_failed`` event) instead of crashing the engine.
+* **Phase spans.**  Each phase of the loop is a ``serve.*`` span
+  (:mod:`repro.obs.trace`): ``serve.admit`` (``serve.prep_wait``,
+  ``serve.setup.prefill``, ``serve.prefill``), ``serve.decode_step``
+  (``serve.decode.dispatch``, ``serve.decode.sync``), ``serve.emit``, and
+  the ``serve.setup.*`` build and warm-up steps.  With tracing on they
+  are profiler annotations too, so a JAX profile names what the serving
+  thread did while the device was idle.
 
 Public contract
 ---------------
@@ -289,19 +296,20 @@ class ServingEngine:
             # tuned replays lower different tilings, so a tuned bucket
             # never aliases an untuned one in a shared live cache
             self.config.tune)
-        hit = self._compile_cache.get_memory(key)
-        if hit is None:
-            t0 = time.perf_counter()
-            progs = (build_programs(self.cfg, self.slots, self._jc,
-                                    kv_window=self._kv_window)
-                     if self.config.use_stripe_decode else None)
-            fn = jax.jit(make_decode_step(self.cfg, progs, self._ps))
-            hit = (fn, progs)
-            self._compile_cache.put_memory(key, hit)
+        with obs_trace.timed("serve.setup.decode") as sp:
+            hit = self._compile_cache.get_memory(key)
+            built = hit is None
+            if built:
+                progs = (build_programs(self.cfg, self.slots, self._jc,
+                                        kv_window=self._kv_window)
+                         if self.config.use_stripe_decode else None)
+                fn = jax.jit(make_decode_step(self.cfg, progs, self._ps))
+                hit = (fn, progs)
+                self._compile_cache.put_memory(key, hit)
+        if built:
             self._compile_log.append({
                 "kind": "decode_programs", "slots": self.slots,
-                "kv_window": self._kv_window,
-                "first_call_s": time.perf_counter() - t0})
+                "kv_window": self._kv_window, "first_call_s": sp.dur})
             if progs is not None:
                 self._note_tuned("decode", progs.records)
         self._decode_fn, self._decode_progs = hit
@@ -340,13 +348,29 @@ class ServingEngine:
         Every admission routes through this lookup, so bucket traffic is
         counted by the compilation cache for real (``cache_stats()``), and
         every new bucket is added to the on-disk manifest for the next
-        boot's warm start.
+        boot's warm start.  Each lookup is one ``serve.setup.prefill``
+        span (attributes ``bucket`` and ``cache`` = ``hit``/``miss``);
+        a compile's ``compile_log()`` entry takes its ``first_call_s``
+        from that span.
 
         A bucket whose compile *crashes* is quarantined (negative-cached
         with exponential backoff) and served through the plain-jnp prefill
         fallback — same math, same tokens — on the very step the compile
         failed; when the embargo lapses the next admission re-attempts the
         real compile."""
+        with obs_trace.timed("serve.setup.prefill", bucket=bucket) as sp:
+            fn, log = self._fetch_prefill(bucket, params)
+            sp.set(cache="hit" if log is None else "miss")
+        if log is not None:
+            log["first_call_s"] = sp.dur
+            if log["kind"] == "prefill":
+                log["warm_start"] = warm
+            self._compile_log.append(log)
+        return fn
+
+    def _fetch_prefill(self, bucket: int, params):
+        """The prefill step for ``bucket`` and, where this call compiled
+        one, its ``compile_log()`` entry (without its timing)."""
         key = self._prefill_key(bucket)
         entry = self._quarantine.get(key)
         was_expired = entry.expired if entry is not None else None
@@ -358,8 +382,7 @@ class ServingEngine:
                         fail_count=entry.fail_count)
         fn = self._compile_cache.get_memory(key)
         if fn is not None:
-            return fn
-        t0 = time.perf_counter()
+            return fn, None
         try:
             faults.check("serve.prefill_compile", bucket=bucket)
             progs = (build_programs(self.cfg, bucket, self._jc)
@@ -387,33 +410,28 @@ class ServingEngine:
             self._quarantine.clear(key)
             self._event("quarantine_clear", bucket=bucket)
         self._compile_cache.put_memory(key, fn)
-        self._compile_log.append({
-            "kind": "prefill", "bucket": bucket, "slots": 1, "plen": bucket,
-            "first_call_s": time.perf_counter() - t0, "warm_start": warm})
         self._touch_manifest(bucket)
-        return fn
+        return fn, {"kind": "prefill", "bucket": bucket, "slots": 1,
+                    "plen": bucket}
 
     def _prefill_fallback(self, bucket: int, params):
         """Degraded prefill for a quarantined bucket: plain jnp, no stripe
         programs, cached under its own key.  Produces the same tokens as
         the stripe path (both are bit-exact vs the dense reference), so a
-        quarantined bucket degrades in *throughput*, never in output."""
+        quarantined bucket degrades in *throughput*, never in output.
+        Returns the step and, where it was compiled here, its log entry."""
         fkey = stripe_cache.content_key(
             "serve_prefill_fallback", self._model_fp, self._ps, self._pps, bucket)
         fn = self._compile_cache.get_memory(fkey)
         if fn is not None:
-            return fn
-        t0 = time.perf_counter()
+            return fn, None
         fn = jax.jit(make_prefill_step(self.cfg, None, self._ps, bucket))
         row = np.full(self._pps, self._garbage[0], np.int32)
         out = fn(params, jnp.zeros((1, bucket), jnp.int32), jnp.int32(1),
                  jnp.asarray(row), self._pk, self._pv)
         jax.block_until_ready(out)
         self._compile_cache.put_memory(fkey, fn)
-        self._compile_log.append({
-            "kind": "prefill_fallback", "bucket": bucket,
-            "first_call_s": time.perf_counter() - t0})
-        return fn
+        return fn, {"kind": "prefill_fallback", "bucket": bucket}
 
     def _touch_manifest(self, bucket: int) -> None:
         if self._compile_cache.disk_dir is None:
@@ -432,13 +450,14 @@ class ServingEngine:
         self._warmed = True
         if self._compile_cache.disk_dir is None:
             return
-        payload = self._compile_cache.get_disk(self._manifest_key)
-        if not payload:
-            return
-        buckets = [int(b) for b in payload.get("buckets", [])]
-        for b in buckets:
-            if b <= self.max_len:
-                self._get_prefill(b, params, warm=True)
+        with obs_trace.span("serve.setup.warm_start"):
+            payload = self._compile_cache.get_disk(self._manifest_key)
+            if not payload:
+                return
+            buckets = [int(b) for b in payload.get("buckets", [])]
+            for b in buckets:
+                if b <= self.max_len:
+                    self._get_prefill(b, params, warm=True)
         self._event("warm_start", buckets=buckets)
 
     def _warm_decode(self, params) -> None:
@@ -448,16 +467,15 @@ class ServingEngine:
         garbage page and discards the result."""
         if self._decode_warm:
             return
-        t0 = time.perf_counter()
-        table = np.tile(self._garbage[:, None], (1, self._pps))
-        zeros = jnp.zeros(self.slots, jnp.int32)
-        jax.block_until_ready(self._decode_fn(
-            params, self._pk, self._pv, jnp.asarray(table), zeros, zeros))
+        with obs_trace.timed("serve.setup.warm_decode") as sp:
+            table = np.tile(self._garbage[:, None], (1, self._pps))
+            zeros = jnp.zeros(self.slots, jnp.int32)
+            jax.block_until_ready(self._decode_fn(
+                params, self._pk, self._pv, jnp.asarray(table), zeros, zeros))
         self._decode_warm = True
         self._compile_log.append({
             "kind": "decode", "slots": self.slots,
-            "kv_window": self._kv_window,
-            "first_call_s": time.perf_counter() - t0})
+            "kv_window": self._kv_window, "first_call_s": sp.dur})
 
     # ----------------------------------------------------------- admission
     def submit(self, req: Request) -> bool:
@@ -573,37 +591,40 @@ class ServingEngine:
         ``max_retries`` (exhaustion fails the request).  A worker found
         dead *without* a handoff is a fail-fast error."""
         with self._cond:
-            while self._n_prepared < self._n_submitted:
-                if self._prep_exc is not None:
-                    item, exc = self._prep_exc
-                    self._prep_exc = None
-                    self._prep_restarts += 1
-                    ev = {"restarts": self._prep_restarts,
-                          "error": repr(exc)[:200]}
-                    if item is not None:
-                        item.retries += 1
-                        self._retries_total += 1
-                        if item.retries > self.config.max_retries:
-                            self._n_prepared += 1
-                            self._fail_prep(item, exc)
-                            ev["failed_uid"] = item.uid
-                        else:
-                            # nothing happened to the request yet: retry it
-                            # through the restarted worker
-                            self._raw.put(item)
-                            ev["requeued_uid"] = item.uid
-                    self._event("prep_thread_restart", **ev)
-                    self._prep_thread = None
-                    self._ensure_prep_thread()
-                    continue
-                if not self._cond.wait(timeout=0.25):
+            if self._n_prepared >= self._n_submitted:
+                return
+            with obs_trace.span("serve.prep_wait"):
+                while self._n_prepared < self._n_submitted:
                     if self._prep_exc is not None:
+                        item, exc = self._prep_exc
+                        self._prep_exc = None
+                        self._prep_restarts += 1
+                        ev = {"restarts": self._prep_restarts,
+                              "error": repr(exc)[:200]}
+                        if item is not None:
+                            item.retries += 1
+                            self._retries_total += 1
+                            if item.retries > self.config.max_retries:
+                                self._n_prepared += 1
+                                self._fail_prep(item, exc)
+                                ev["failed_uid"] = item.uid
+                            else:
+                                # nothing happened to the request yet: retry it
+                                # through the restarted worker
+                                self._raw.put(item)
+                                ev["requeued_uid"] = item.uid
+                        self._event("prep_thread_restart", **ev)
+                        self._prep_thread = None
+                        self._ensure_prep_thread()
                         continue
-                    if self._prep_thread is None or not self._prep_thread.is_alive():
-                        raise RuntimeError(
-                            "serving prep thread died without handing back its "
-                            f"work ({self._n_submitted - self._n_prepared} "
-                            "request(s) pending)")
+                    if not self._cond.wait(timeout=0.25):
+                        if self._prep_exc is not None:
+                            continue
+                        if self._prep_thread is None or not self._prep_thread.is_alive():
+                            raise RuntimeError(
+                                "serving prep thread died without handing back its "
+                                f"work ({self._n_submitted - self._n_prepared} "
+                                "request(s) pending)")
 
     def close(self) -> None:
         """Stop the prep thread (idempotent; the engine stays usable —
@@ -683,80 +704,83 @@ class ServingEngine:
         """Fill free slots from the prepared queue; returns the
         (uid, first_token) pairs emitted by the prefills (a retried
         request's replayed first token is verified, not re-emitted)."""
-        emitted: List[Tuple[int, int]] = []
-        self._drain_prep()
-        self._expire_queued()
-        self._surface_cache_errors()
-        while self._free_slots:
-            with self._cond:
-                idx = self._pick_candidate()
-                if idx is None:
-                    break
-                prep = self._ready[idx]
-                del self._ready[idx]
-            pages = self._pool.alloc(prep.n_pages)
-            if pages is None:
-                # allocation failed after can_alloc said yes (injected fault
-                # or a raced pool): defer, don't crash — the request goes
-                # back to the queue head and retries next admission phase
+        with obs_trace.span("serve.admit"):
+            emitted: List[Tuple[int, int]] = []
+            self._drain_prep()
+            self._expire_queued()
+            self._surface_cache_errors()
+            while self._free_slots:
                 with self._cond:
-                    self._ready.appendleft(prep)
-                self._event("alloc_failed", uid=prep.req.uid,
-                            pages=prep.n_pages,
-                            free_pages=self._pool.free_pages)
-                break
-            slot = self._free_slots.pop(0)
-            r = prep.req
-            r.slot = slot
-            # queue wait closes at admission: stamped retroactively from
-            # the submit-side timestamp (submit and admission run on
-            # different threads, so this cannot be a ``with`` block)
-            now = time.perf_counter()
-            self._h_queue.observe(now - r.submit_time)
-            obs_trace.span_at("serve.queue", r.submit_time, now, uid=r.uid)
-            row = np.full(self._pps, self._garbage[slot], np.int32)
-            row[: len(pages)] = pages
-            self._page_table[slot] = row
-            self._slot_pages[slot] = pages
-            self._slot_req[slot] = r
-            self._slot_eff[slot] = prep.eff_new
-            with obs_trace.span("serve.prefill", uid=r.uid,
-                                bucket=prep.bucket, slot=slot):
-                t_pf = time.perf_counter()
+                    idx = self._pick_candidate()
+                    if idx is None:
+                        break
+                    prep = self._ready[idx]
+                    del self._ready[idx]
+                pages = self._pool.alloc(prep.n_pages)
+                if pages is None:
+                    # allocation failed after can_alloc said yes (injected fault
+                    # or a raced pool): defer, don't crash — the request goes
+                    # back to the queue head and retries next admission phase
+                    with self._cond:
+                        self._ready.appendleft(prep)
+                    self._event("alloc_failed", uid=prep.req.uid,
+                                pages=prep.n_pages,
+                                free_pages=self._pool.free_pages)
+                    break
+                slot = self._free_slots.pop(0)
+                r = prep.req
+                r.slot = slot
+                # queue wait closes at admission: stamped retroactively from
+                # the submit-side timestamp (submit and admission run on
+                # different threads, so this cannot be a ``with`` block)
+                now = time.perf_counter()
+                self._h_queue.observe(now - r.submit_time)
+                obs_trace.span_at("serve.queue", r.submit_time, now, uid=r.uid)
+                row = np.full(self._pps, self._garbage[slot], np.int32)
+                row[: len(pages)] = pages
+                self._page_table[slot] = row
+                self._slot_pages[slot] = pages
+                self._slot_req[slot] = r
+                self._slot_eff[slot] = prep.eff_new
+                # fetched (or, off the warm path, compiled) outside the
+                # prefill's own span and timer: a compile is set-up time
                 fn = self._get_prefill(prep.bucket, params)
-                tok, self._pk, self._pv = fn(
-                    params, jnp.asarray(prep.tokens), jnp.int32(prep.plen),
-                    jnp.asarray(row), self._pk, self._pv)
-                first = int(tok)
-            self._h_prefill.observe(time.perf_counter() - t_pf)
-            self._pos[slot] = prep.plen
-            self._last[slot] = first
-            replay = r.replay_len
-            if replay > 0:
-                # retried incarnation: the prefill token was already emitted
-                # before the failure — verify, don't re-emit (exactly-once)
-                if first != r.out_tokens[0]:
-                    raise RuntimeError(
-                        f"exactly-once violated on retry of request {r.uid}: "
-                        f"replayed prefill token {first} != recorded "
-                        f"{r.out_tokens[0]}")
-                self._slot_emitted[slot] = 1
-                self._slot_replay[slot] = replay
-                self._event("admit", uid=r.uid, slot=slot, bucket=prep.bucket,
-                            retry=r.retries, replay=replay,
-                            queue_depth=len(self._ready))
-            else:
-                r.first_token_time = time.perf_counter()
-                r.out_tokens.append(first)
-                self._tokens_out += 1
-                self._slot_emitted[slot] = 1
-                self._slot_replay[slot] = 0
-                self._event("admit", uid=r.uid, slot=slot, bucket=prep.bucket,
-                            queue_depth=len(self._ready))
-                emitted.append((r.uid, first))
-                if first == r.sampling.eos_id or len(r.out_tokens) >= prep.eff_new:
-                    self._evict(slot)
-        return emitted
+                with obs_trace.span("serve.prefill", uid=r.uid,
+                                    bucket=prep.bucket, slot=slot):
+                    t_pf = time.perf_counter()
+                    tok, self._pk, self._pv = fn(
+                        params, jnp.asarray(prep.tokens), jnp.int32(prep.plen),
+                        jnp.asarray(row), self._pk, self._pv)
+                    first = int(tok)
+                self._h_prefill.observe(time.perf_counter() - t_pf)
+                self._pos[slot] = prep.plen
+                self._last[slot] = first
+                replay = r.replay_len
+                if replay > 0:
+                    # retried incarnation: the prefill token was already emitted
+                    # before the failure — verify, don't re-emit (exactly-once)
+                    if first != r.out_tokens[0]:
+                        raise RuntimeError(
+                            f"exactly-once violated on retry of request {r.uid}: "
+                            f"replayed prefill token {first} != recorded "
+                            f"{r.out_tokens[0]}")
+                    self._slot_emitted[slot] = 1
+                    self._slot_replay[slot] = replay
+                    self._event("admit", uid=r.uid, slot=slot, bucket=prep.bucket,
+                                retry=r.retries, replay=replay,
+                                queue_depth=len(self._ready))
+                else:
+                    r.first_token_time = time.perf_counter()
+                    r.out_tokens.append(first)
+                    self._tokens_out += 1
+                    self._slot_emitted[slot] = 1
+                    self._slot_replay[slot] = 0
+                    self._event("admit", uid=r.uid, slot=slot, bucket=prep.bucket,
+                                queue_depth=len(self._ready))
+                    emitted.append((r.uid, first))
+                    if first == r.sampling.eos_id or len(r.out_tokens) >= prep.eff_new:
+                        self._evict(slot)
+            return emitted
 
     def _release_slot(self, slot: int) -> None:
         """Return a slot's pages to the pool and reset its decode state;
@@ -828,8 +852,11 @@ class ServingEngine:
         steps = 0
         stall = 0
         while steps < max_steps:
-            for out in self._admit(params):
-                yield out
+            admitted = self._admit(params)
+            if admitted:
+                with obs_trace.span("serve.emit", step=self._steps):
+                    for out in admitted:
+                        yield out
             self._expire_slots()
             live = [s for s in range(self.slots) if self._slot_req[s] is not None]
             if not live:
@@ -856,11 +883,13 @@ class ServingEngine:
                              step=self._steps, n_live=len(live))
                 with obs_trace.span("serve.decode_step", step=self._steps,
                                     n_live=len(live)):
-                    nxt, pk, pv = self._decode_fn(
-                        params, self._pk, self._pv,
-                        jnp.asarray(self._page_table), jnp.asarray(self._pos),
-                        jnp.asarray(self._last))
-                    nxt = np.asarray(nxt)
+                    with obs_trace.span("serve.decode.dispatch", step=self._steps):
+                        nxt, pk, pv = self._decode_fn(
+                            params, self._pk, self._pv,
+                            jnp.asarray(self._page_table), jnp.asarray(self._pos),
+                            jnp.asarray(self._last))
+                    with obs_trace.span("serve.decode.sync", step=self._steps):
+                        nxt = np.asarray(nxt)
             except Exception as e:  # noqa: BLE001 — device-step crash:
                 # nothing was committed (pages/pos/output update below, only
                 # on success); recover the affected slots and carry on
@@ -871,28 +900,29 @@ class ServingEngine:
             steps += 1
             self._steps += 1
             self._live_steps += len(live)
-            for s in live:
-                r = self._slot_req[s]
-                tok = int(nxt[s])
-                self._pos[s] += 1
-                self._last[s] = tok
-                idx = int(self._slot_emitted[s])
-                self._slot_emitted[s] = idx + 1
-                if idx < self._slot_replay[s]:
-                    # replaying pre-failure output on a retried request:
-                    # greedy decode is deterministic, so the regenerated
-                    # token must equal the recorded one — verify, suppress
-                    if tok != r.out_tokens[idx]:
-                        raise RuntimeError(
-                            f"exactly-once violated on retry of request "
-                            f"{r.uid}: replayed token {tok} at index {idx} "
-                            f"!= recorded {r.out_tokens[idx]}")
-                    continue
-                r.out_tokens.append(tok)
-                self._tokens_out += 1
-                yield (r.uid, tok)
-                if tok == r.sampling.eos_id or len(r.out_tokens) >= self._slot_eff[s]:
-                    self._evict(s)
+            with obs_trace.span("serve.emit", step=self._steps - 1):
+                for s in live:
+                    r = self._slot_req[s]
+                    tok = int(nxt[s])
+                    self._pos[s] += 1
+                    self._last[s] = tok
+                    idx = int(self._slot_emitted[s])
+                    self._slot_emitted[s] = idx + 1
+                    if idx < self._slot_replay[s]:
+                        # replaying pre-failure output on a retried request:
+                        # greedy decode is deterministic, so the regenerated
+                        # token must equal the recorded one — verify, suppress
+                        if tok != r.out_tokens[idx]:
+                            raise RuntimeError(
+                                f"exactly-once violated on retry of request "
+                                f"{r.uid}: replayed token {tok} at index {idx} "
+                                f"!= recorded {r.out_tokens[idx]}")
+                        continue
+                    r.out_tokens.append(tok)
+                    self._tokens_out += 1
+                    yield (r.uid, tok)
+                    if tok == r.sampling.eos_id or len(r.out_tokens) >= self._slot_eff[s]:
+                        self._evict(s)
 
     def run(self, params=None, max_steps: int = 256) -> List[Request]:
         """Serve until the queue drains (or ``max_steps`` decode steps);

@@ -71,3 +71,26 @@ def test_served_program_compiles_for_v5e(name, one_chip, jc):
               for n in prog.program.inputs}
     compiled = jax.jit(lambda arrays: prog(arrays)).lower(shapes).compile()
     assert compiled.as_text().count("tpu_custom_call") == rec.n_kernels
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_served_kernels_carry_block_names(name, one_chip, jc):
+    """Each kernel's operation in the compiled program is named after its
+    block (``<program>.<member ops>``, plus the compiler's ``.<n>``), so a
+    profile of the chip shows it under that name."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    prog = PROGRAMS[name](jc)
+    shapes = {n: jax.ShapeDtypeStruct(prog.program.buffers[n].shape, jnp.float32,
+                                      sharding=one_chip)
+              for n in prog.program.inputs}
+    text = jax.jit(lambda arrays: prog(arrays)).lower(shapes).compile().as_text()
+    ops = [re.match(r"\s*(?:ROOT )?%?(\S+) = ", line).group(1)
+           for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    entry = prog.program.entry.name
+    want = {f"{entry}.{unit.replace('+', '_')}" for unit in prog.record.block_backends}
+    assert {re.sub(r"\.\d+$", "", op) for op in ops} == want
+    assert len(ops) == prog.record.n_kernels

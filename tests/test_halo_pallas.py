@@ -157,6 +157,28 @@ def test_masked_remainder_non_dividing_tile():
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
+def test_kernel_names_are_stable_across_lowerings():
+    """Kernel names come from the program and its units alone: two
+    lowerings of one program name their kernels alike, and the boundary
+    piece of a unit is told apart from its interior."""
+    def lowered():
+        tp = TileProgram("mmrem")
+        tp.input("A", (12, 8))
+        tp.input("B", (8, 16))
+        tp.output("O", (12, 16))
+        tp.op("O[m, n] += A[m, c] * B[c, n]", name="mm")
+        prog = tp.build()
+        src = copy.deepcopy(prog)
+        prog.entry.stmts = list(split_boundary(split_block(prog.entry.stmts[0],
+                                                           {"m": 8})))
+        prog.source = src
+        return lower_program_hybrid(prog, interpret=True)
+
+    first, second = lowered(), lowered()
+    assert first.kernel_names == ["mmrem.mm", "mmrem.mm_p1"]
+    assert second.kernel_names == first.kernel_names
+
+
 @settings(max_examples=6, deadline=None)
 @given(st.integers(4, 8), st.integers(4, 8), st.integers(1, 3),
        st.integers(1, 3), st.sampled_from([2, 3]))

@@ -323,3 +323,175 @@ def test_engine_event_ring_buffer():
     # the registry still counted every event, drops notwithstanding
     snap = eng.metrics_snapshot()
     assert snap["counters"]["serve.events{event=finish}"] == 4
+
+
+# ------------------------------------------------- the profiler's clock
+@pytest.fixture()
+def annotations(monkeypatch):
+    """Every ``jax.profiler.TraceAnnotation`` made, as (name, kwargs)."""
+    import jax.profiler
+
+    made = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            made.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    return made
+
+
+def test_disabled_tracing_makes_no_annotation(annotations):
+    t = obs_trace.Tracer(enabled=False)
+    for i in range(100):
+        with t.span("serve.decode_step", step=i, n_live=3):
+            pass
+    with t.timed("serve.setup.decode") as sp:
+        time.sleep(0.002)
+    assert annotations == []
+    assert t.spans() == []
+    assert t.span("serve.emit", step=1) is obs_trace._NULL
+    assert sp.dur >= 0.002  # a timed span measures even when off
+
+
+def test_enabled_span_is_annotated_with_its_ids(annotations, tracer):
+    with obs_trace.span("serve.prefill", uid=7, bucket=64, slot=2, step=[1]):
+        with obs_trace.timed("serve.setup.prefill", bucket=64) as sp:
+            sp.set(cache="miss")
+    obs_trace.span_at("serve.queue", 0.0, 1.0, uid=7)
+    obs_trace.instant("serve.admit", uid=7)
+    # only live spans are annotated, and only with scalar step/uid/bucket
+    assert annotations == [("serve.prefill", {"uid": 7, "bucket": 64}),
+                           ("serve.setup.prefill", {"bucket": 64})]
+    recs = {r.name: r for r in tracer.spans() if r.phase == "X"}
+    assert recs["serve.setup.prefill"].dur == sp.dur
+    assert recs["serve.setup.prefill"].attrs == {"bucket": 64, "cache": "miss"}
+    assert recs["serve.setup.prefill"].parent == "serve.prefill"
+
+
+def test_spans_reach_a_profiler_trace(tracer, tmp_path):
+    """A span taken while the profiler runs is a host event of the same
+    name, on the thread that ran it, with its ids as stats."""
+    import glob
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("serve.decode_step", step=5, n_live=3):
+            with obs_trace.span("serve.decode.sync", step=5):
+                jax.block_until_ready(jnp.ones(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    got = {}
+    for plane in pd.planes:
+        for i, ln in enumerate(plane.lines):
+            for e in ln.events:
+                if e.name.startswith("serve."):
+                    got[e.name] = (plane.name, i, dict(e.stats),
+                                   e.start_ns, e.start_ns + e.duration_ns)
+    assert set(got) == {"serve.decode_step", "serve.decode.sync"}
+    step, sync = got["serve.decode_step"], got["serve.decode.sync"]
+    assert step[:2] == sync[:2]  # one thread's line
+    assert {int(v) for v in step[2].values()} == {5} and "n_live" not in step[2]
+    assert step[3] <= sync[3] and sync[4] <= step[4]
+
+
+# every serve.* span of the loop, with the parent it must have
+SPAN_PARENTS = {
+    "serve.admit": {""},
+    "serve.prep_wait": {"serve.admit"},
+    "serve.prefill": {"serve.admit"},
+    "serve.decode_step": {""},
+    "serve.decode.dispatch": {"serve.decode_step"},
+    "serve.decode.sync": {"serve.decode_step"},
+    "serve.emit": {""},
+    "serve.setup.decode": {""},
+    "serve.setup.prefill": {"serve.admit", "serve.setup.warm_start"},
+    "serve.setup.warm_decode": {""},
+    "serve.setup.warm_start": {""},
+}
+
+
+def test_engine_span_tree(tracer, tmp_path):
+    """A tiny engine, booted twice over one disk cache (the second boot
+    replays the bucket manifest), records every phase of its loop under
+    the parent the loop gives it; each compile's log entry is timed by
+    its setup span."""
+    import jax
+
+    cfg, model = _tiny_model()
+    params = model.init(jax.random.PRNGKey(0))
+
+    def boot():
+        cache = stripe_cache.CompilationCache(capacity=64, disk_dir=tmp_path,
+                                              use_disk=True)
+        eng = ServingEngine(model, EngineConfig(slots=2, max_len=32, page_size=8),
+                            compile_cache=cache)
+        real = eng._prepare
+
+        def slow(req):  # a warm engine's admission waits for the prep thread
+            time.sleep(0.05)
+            return real(req)
+
+        eng._prepare = slow
+        return eng
+
+    first = boot()
+    assert len(_run_requests(first, cfg, params)) == 4
+    assert len(_run_requests(first, cfg, params, n=1, base_uid=4)) == 1
+    second = boot()
+    assert len(_run_requests(second, cfg, params, base_uid=5)) == 4
+
+    spans = [r for r in tracer.spans()
+             if r.phase == "X" and r.name in SPAN_PARENTS]
+    parents = {}
+    for r in spans:
+        parents.setdefault(r.name, set()).add(r.parent)
+    assert set(parents) == set(SPAN_PARENTS)
+    for name, got in parents.items():
+        assert got <= SPAN_PARENTS[name], (name, got)
+    caches = {r.attrs["cache"] for r in spans if r.name == "serve.setup.prefill"}
+    assert caches == {"hit", "miss"}
+    steps = [r for r in spans if r.name == "serve.decode_step"]
+    assert len(steps) == first.metrics()["decode_steps"] + \
+        second.metrics()["decode_steps"]
+
+    # one measurement per compile: the log's time is the span's duration
+    misses = sorted(r.dur for r in spans
+                    if r.name == "serve.setup.prefill" and r.attrs["cache"] == "miss")
+    logged = sorted(e["first_call_s"] for eng in (first, second)
+                    for e in eng.compile_log() if e["kind"] == "prefill")
+    assert logged == misses
+    warm = [r.dur for r in spans if r.name == "serve.setup.warm_decode"]
+    assert sorted(warm) == sorted(e["first_call_s"] for eng in (first, second)
+                                  for e in eng.compile_log() if e["kind"] == "decode")
+
+
+def test_prefill_histogram_excludes_the_compile(tracer):
+    """A bucket compiled on admission is set-up time: the prefill's own
+    span and ``serve.prefill_s`` start after the program is fetched."""
+    import jax
+
+    cfg, model = _tiny_model()
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(model, EngineConfig(slots=2, max_len=32, page_size=8))
+    _run_requests(eng, cfg, params, n=1)
+    (miss,) = [r for r in tracer.spans() if r.name == "serve.setup.prefill"]
+    (prefill,) = [r for r in tracer.spans()
+                  if r.name == "serve.prefill" and r.phase == "X"]
+    assert miss.attrs["cache"] == "miss"
+    assert miss.ts + miss.dur <= prefill.ts
+    hist = eng.metrics_snapshot()["histograms"]["serve.prefill_s"]
+    assert hist["count"] == 1 and hist["sum"] < miss.dur
