@@ -10,9 +10,13 @@ One chip: ``repro.api.build_model`` -> ``ServingEngine`` -> ``stripe_jit``
 programs with the engine's platform defaults (compiled Pallas kernels on
 a TPU).  Qwen3-4B at its published widths with random bf16 weights from
 ``--seed``; two rounds of 8 greedy requests (prompts of 97-128 and
-400-512 tokens, two prefill buckets), 32 tokens each.  Then each of the
-five decode-step programs is checked on the chip against ``jnp.einsum``
-in f32 at ``HIGHEST`` precision on seeded inputs at the served widths.
+400-512 tokens, two prefill buckets), 32 tokens each.  Every served
+matmul weight must show in its program's ``CompileRecord.stored_reads``
+as read in place (and, for a bf16 configuration, as narrow).  Then each
+of the five decode-step programs is checked on the chip against
+``jnp.einsum`` in f32 at ``HIGHEST`` precision on seeded inputs at the
+served widths, each weight handed as the engine hands it: stacked in the
+stored dtype, with the index of the slice to read.
 
 Four chips: the multi-device compile path alone, i.e. an output-split
 SiLU-GLU FFN at Qwen3-4B MLP widths (all_gather) and a reduction-split
@@ -35,9 +39,16 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(HERE, "src")
 
 SLOTS, PAGE_SIZE, MAX_LEN, NEW_TOKENS = 8, 16, 1024, 32
+# layers stacked under each weight in the program check; the last is read
+STACK = 3
+# the matmul weights of each served block program, by record name
+SERVED_WEIGHTS = {"qkv": ("WQ", "WK", "WV"), "attn_out": ("WO",),
+                  "mlp": ("Wg", "Wu", "Wd"), "mlp_up": ("Wg", "Wu"),
+                  "mlp_down": ("Wd",)}
 PROMPT_BUCKETS = ((97, 128), (400, 512))
 # Largest error allowed for a compiled program, relative to the largest
-# magnitude of the reference output.  The operands are f32; where the MXU
+# magnitude of the reference output.  The weights are stored in bf16 and
+# promoted to f32 in the kernel, the other operands are f32; where the MXU
 # evaluates an f32 product as bf16 passes, each product carries a relative
 # error of at most 2**-8, which over the 128- to 9728-term sums here stays
 # near 0.5% of the output's scale.  A wrong tile, index map or accumulation
@@ -92,19 +103,33 @@ def _serve_round(api, engine, params, cfg, rng, uid0: int, problems):
 
 def _decode_references(progs, cfg, rng):
     """(name, program, inputs, reference outputs) for the five decode
-    programs, on seeded f32 inputs at the served widths."""
+    programs, on seeded inputs at the served widths: each weight stacked
+    ``(STACK, ...)`` in its declared (stored) dtype and handed with the
+    index of its last slice, everything else f32.  The reference reads
+    the same slice, cast to f32."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.core.lower_jnp import Stacked
+    from repro.serving.stripe_decode import WEIGHT_INPUTS
+
     hp = jax.lax.Precision.HIGHEST
-    ein = lambda spec, a, b: jnp.einsum(spec, a, b, precision=hp)  # noqa: E731
+
+    def ein(spec, a, b):
+        a, b = (v.select() if isinstance(v, Stacked) else v for v in (a, b))
+        return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32),
+                          precision=hp)
 
     def inputs(prog):
         out = {}
         for name in prog.program.inputs:
-            shape = prog.program.buffers[name].shape
-            a = rng.standard_normal(shape, dtype=np.float32)
+            decl = prog.program.buffers[name]
+            if name in WEIGHT_INPUTS:
+                a = rng.standard_normal((STACK, *decl.shape), dtype=np.float32)
+                out[name] = Stacked(jnp.asarray(a, decl.dtype), STACK - 1)
+                continue
+            a = rng.standard_normal(decl.shape, dtype=np.float32)
             if name == "P":  # attention probabilities over the window
                 a = np.asarray(jax.nn.softmax(jnp.asarray(a), axis=-1))
             out[name] = jnp.asarray(a)
@@ -171,9 +196,16 @@ def one_chip(args, api, jax, on_tpu: bool, problems) -> None:
     stats = jax.devices()[0].memory_stats() or {}
     print(f"device peak_bytes_in_use: {stats.get('peak_bytes_in_use', 'not reported')}")
 
+    narrow = np.dtype(cfg.dtype).itemsize < 4
     for name, rec in sorted(engine.compile_records().items()):
         print(f"record {name}: backend {rec.backend}, n_kernels {rec.n_kernels}, "
               f"block_backends {rec.block_backends}")
+        print(f"  stored_reads {rec.stored_reads}")
+        for w in SERVED_WEIGHTS.get(name.split("/")[-1], ()):
+            read = rec.stored_reads.get(w, {})
+            if not read.get("in_place") or read.get("narrow") != narrow:
+                problems.append(f"record {name}: weight {w} not read as stored "
+                                f"in place: {read}")
         for blk, why in rec.block_fallbacks.items():
             print(f"  legality fallback {blk}: {why}")
         if rec.quarantined or "compile crashed" in rec.fallback_reason:

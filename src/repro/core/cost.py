@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 from typing import Dict, List, Mapping, Optional, Tuple
 
 from .affine import Affine
@@ -205,7 +206,8 @@ def evaluate_tiling(block: Block, tiles: Mapping[str, int], hw: HardwareConfig, 
 
     # ---- planner-exact footprint of one tile -------------------------------
     # (memplan's slot model: streamed views get pipeline_depth slots, grid-
-    # invariant views one, a revisited output one slot + f32 scratch)
+    # invariant views one, a revisited output one slot + f32 scratch, a
+    # narrower float input one promoted copy)
     from . import memplan
 
     depth = hw.pipeline_depth
@@ -222,6 +224,9 @@ def evaluate_tiling(block: Block, tiles: Mapping[str, int], hw: HardwareConfig, 
         entries.append((elems * dtype_bytes(r.dtype), kind, slots))
         if revisited:
             entries.append((elems * 4, "scratch", 1))  # f32 partial sums
+    ins = [(r.into, r.dtype, math.prod(shape)) for r, shape, _u, _a in views
+           if r.dir == RefDir.IN]
+    entries += [(b, "promote", 1) for _, b in memplan.promoted_views(ins)]
     plan_bytes = memplan.tile_footprint_bytes(entries)
 
     cap_e = params.get("mem_cap_elems")
@@ -480,6 +485,9 @@ def fusion_vmem_pressure(refs, ranges: Mapping[str, int], hw: HardwareConfig,
         if revisited:
             elems = nbytes // max(dtype_bytes(r.dtype), 1)
             entries.append((elems * 4, "scratch", 1))
+    ins = [(r.into, r.dtype, tile_view_bytes(r, ranges, tile) // dtype_bytes(r.dtype))
+           for r in refs if r.dir == RefDir.IN]
+    entries += [(b, "promote", 1) for _, b in memplan.promoted_views(ins)]
     pressure = memplan.tile_footprint_bytes(entries)
     return pressure, cap, pressure <= cap
 

@@ -24,6 +24,9 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple, Union
 
+import jax.numpy as jnp
+import numpy as np
+
 from ..obs import profile as obs_profile
 from ..obs import trace as obs_trace
 from ..reliability import faults
@@ -31,8 +34,8 @@ from . import cache as _cache
 from .frontend import TileProgram, single_op_program
 from .hwconfig import HardwareConfig
 from .interp import execute_reference
-from .ir import Block, Program, ir_fingerprint
-from .lower_jnp import lower_program_jnp
+from .ir import Block, Program, RefDir, ir_fingerprint
+from .lower_jnp import Stacked, _acc_dtype, lower_program_jnp, select_stacked
 from .passes import PassManager, TilingOracle
 from .platform import resolve_interpret
 
@@ -107,6 +110,15 @@ class CompileRecord:
     # compile).  ``{"fallback": reason, ...}`` when the partitioner found
     # no legal split and the program compiled single-device instead.
     mesh: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # Inputs read at their stored dtype where it is narrower than the
+    # accumulator of a contraction that reads them (the kernel promotes
+    # the tile after the load), and inputs a caller handed in place
+    # (``Stacked``: a stacked array plus the index of the slice, which the
+    # Pallas kernels read where it lies):
+    # ``{input: {"dtype", "narrow", "in_place"}}``.  Narrow entries are
+    # fixed at compile time; ``in_place`` fills in as the program is
+    # called (the dict is shared with cache-hit records of the artifact).
+    stored_reads: Dict[str, Dict[str, Any]] = dataclasses.field(default_factory=dict)
 
     def fusion_decisions(self) -> List[Dict]:
         """Accepted/rejected merges recorded by the fusion pass."""
@@ -149,7 +161,36 @@ class CompiledProgram:
         return list(self.program.outputs)
 
     def __call__(self, arrays: Mapping[str, Any]) -> Dict[str, Any]:
+        stacked = [k for k, v in arrays.items() if isinstance(v, Stacked)]
+        if not stacked:
+            return self._fn(arrays)
+        for k in stacked:
+            entry = self.record.stored_reads.setdefault(k, {
+                "dtype": str(np.dtype(arrays[k].array.dtype)), "narrow": False})
+            entry["in_place"] = True
+        if not getattr(self._fn, "takes_stacked", False):
+            arrays = select_stacked(arrays)
         return self._fn(arrays)
+
+
+def stored_reads(prog: Program) -> Dict[str, Dict[str, Any]]:
+    """The narrow entries of ``CompileRecord.stored_reads``: each float
+    input that a contraction reads at a dtype narrower than its
+    accumulator."""
+    out: Dict[str, Dict[str, Any]] = {}
+    for blk in prog.entry.stmts:
+        if not isinstance(blk, Block):
+            continue
+        outs = [r for r in blk.refs if r.dir in (RefDir.OUT, RefDir.INOUT)]
+        if len(outs) != 1 or (outs[0].agg or "assign") == "assign":
+            continue
+        acc = np.dtype(_acc_dtype(outs[0].dtype))
+        for r in blk.refs:
+            d = np.dtype(r.dtype)
+            if (r.dir == RefDir.IN and r.from_buf in prog.inputs
+                    and jnp.issubdtype(d, jnp.floating) and d.itemsize < acc.itemsize):
+                out[r.from_buf] = {"dtype": str(d), "narrow": True, "in_place": False}
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -355,6 +396,7 @@ def _attach_profiling(low: _Lowered, record: CompileRecord,
                     pass  # measurement feedback must never fail a dispatch
         return out
 
+    wrapper.takes_stacked = getattr(inner, "takes_stacked", False)
     return wrapper
 
 
@@ -577,6 +619,7 @@ def stripe_jit(fn_or_contraction: Union[Program, TileProgram, str, Callable],
                     "age_s": max(time.time() - tuned.ts, 0.0),
                     "n_candidates": tuned.n_candidates}
                    if tuned is not None else {}),
+            stored_reads=stored_reads(prog),
         )
         fn = low.fn
         if profile:
@@ -744,7 +787,7 @@ def _stripe_jit_mesh(fn_or_contraction, hw: HardwareConfig, backend: str,
             decision_source=("tuned" if any(
                 s["decision_source"] == "tuned" for s in seg_summaries)
                 else "analytic"),
-            mesh=mesh_info,
+            mesh=mesh_info, stored_reads=stored_reads(prog),
         )
         if profile:
             record.predicted_latency_s = {"<program>": plan.cost_s}
@@ -806,5 +849,6 @@ def compile_with_tilings(fn_or_contraction: Union[Program, TileProgram, str, Cal
         block_backends=low.block_backends, block_fallbacks=low.block_fallbacks,
         profiled=bool(profile), ir_fingerprint=ir_fp,
         hw_fingerprint=hw.fingerprint(), decision_source="replay",
+        stored_reads=stored_reads(prog),
     )
     return CompiledProgram(opt, low.fn, hw, record)

@@ -62,6 +62,34 @@ _AGG_JNP = {
 
 
 # --------------------------------------------------------------------------
+# Inputs handed in place
+# --------------------------------------------------------------------------
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["array", "index"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class Stacked:
+    """A program input handed in place: ``array`` carries one leading
+    axis more than the declared operand, and ``index`` (an integer
+    scalar, traced or not) selects the operand along it — the stacked
+    ``(n_layers, d, f)`` weight of a layer loop plus the layer.  The
+    Pallas kernels read the selected slice where it lies (the index rides
+    in scalar prefetch); every other lowering takes :meth:`select`."""
+
+    array: object
+    index: object
+
+    def select(self) -> jnp.ndarray:
+        return jax.lax.dynamic_index_in_dim(jnp.asarray(self.array), self.index,
+                                            axis=0, keepdims=False)
+
+
+def select_stacked(arrays: Mapping[str, object]) -> Dict[str, object]:
+    """``arrays`` with every :class:`Stacked` input replaced by its slice."""
+    return {k: v.select() if isinstance(v, Stacked) else v
+            for k, v in arrays.items()}
+
+
+# --------------------------------------------------------------------------
 # Block analysis: rebuild the expression DAG from the statement list
 # --------------------------------------------------------------------------
 @dataclasses.dataclass

@@ -62,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import memplan
 from .ir import (Block, Constant, Intrinsic, Load, Program, Refinement,
                  RefDir, Store, TensorDecl)
-from .lower_jnp import _J_BINARY, _J_UNARY, _acc_dtype
+from .lower_jnp import _J_BINARY, _J_UNARY, Stacked, _acc_dtype, select_stacked
 
 MAX_WINDOW_STEPS = 512           # unrolled kernel steps per grid point
 MAX_HALO_BYTES = 256 * 2**20     # materialized (gathered) operand budget
@@ -768,6 +768,82 @@ def _index_map_for(gr: GridRef, gpos: Mapping[str, int]):
     return imap
 
 
+def _operand(arrays: Mapping[str, object], buf: str):
+    """(array, selector or None) of one kernel input: a :class:`Stacked`
+    input is handed whole, with the index that selects its slice."""
+    a = arrays[buf]
+    if isinstance(a, Stacked):
+        return jnp.asarray(a.array), a.index
+    return jnp.asarray(a), None
+
+
+def _promote_pair(a: jnp.ndarray, b: jnp.ndarray):
+    """Two float contraction operands of different dtypes meet at the
+    wider one, in the kernel body after the load: a weight stored in
+    bf16 crosses HBM and the DMA as bf16 and the dot sees the same f32
+    values a cast outside the kernel would have made."""
+    if (a.dtype == b.dtype or not jnp.issubdtype(a.dtype, jnp.floating)
+            or not jnp.issubdtype(b.dtype, jnp.floating)):
+        return a, b
+    wide = jnp.promote_types(a.dtype, b.dtype)
+    return a.astype(wide), b.astype(wide)
+
+
+def _kernel_launcher(kernel: Callable, grid: Tuple[int, ...],
+                     in_blocks: Sequence[Tuple[Tuple[int, ...], Callable]],
+                     out_block: Tuple[int, ...], out_imap: Callable,
+                     out_shape: jax.ShapeDtypeStruct, scratch: Sequence,
+                     interpret: bool, name: Optional[str], kwargs: Mapping):
+    """``launch(operands)`` for one kernel, ``operands`` being one
+    ``(array, selector or None)`` per ``in_blocks`` entry (block shape,
+    index map).  With no selector it is the plain ``pallas_call``.  A
+    selected operand is handed whole: the selectors ride in scalar
+    prefetch (``PrefetchScalarGridSpec``), its block gains a squeezed
+    leading dim and its index map leads with its selector, so the kernel
+    reads the same tile from where the slice lies and no slice is ever
+    copied.  One ``pallas_call`` is built per pattern of selected
+    operands."""
+    built: Dict[Tuple[bool, ...], Callable] = {}
+
+    def build(selected: Tuple[bool, ...]) -> Callable:
+        if not any(selected):
+            return pl.pallas_call(
+                kernel, grid=grid,
+                in_specs=[pl.BlockSpec(b, m) for b, m in in_blocks],
+                out_specs=pl.BlockSpec(out_block, out_imap), out_shape=out_shape,
+                scratch_shapes=list(scratch), interpret=interpret, name=name,
+                **kwargs)
+        def spec(block, imap, j=None):
+            # index maps get the prefetched selectors after the grid indices
+            if j is None:
+                return pl.BlockSpec(block, lambda *a: imap(*a[:-1]))
+            return pl.BlockSpec((pl.squeezed, *block),
+                                lambda *a: (a[-1][j], *imap(*a[:-1])))
+
+        slot = iter(range(sum(selected)))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[spec(b, m, next(slot) if s else None)
+                      for (b, m), s in zip(in_blocks, selected)],
+            out_specs=spec(out_block, out_imap), scratch_shapes=list(scratch))
+        return pl.pallas_call(lambda _sel, *refs: kernel(*refs),
+                              grid_spec=grid_spec, out_shape=out_shape,
+                              interpret=interpret, name=name, **kwargs)
+
+    def launch(operands: Sequence[Tuple[jnp.ndarray, object]]) -> jnp.ndarray:
+        selected = tuple(s is not None for _, s in operands)
+        if selected not in built:
+            built[selected] = build(selected)
+        arrays = [a for a, _ in operands]
+        if not any(selected):
+            return built[selected](*arrays)
+        sel = jnp.stack([jnp.asarray(s, jnp.int32).reshape(())
+                         for _, s in operands if s is not None])
+        return built[selected](sel, *arrays)
+
+    return launch
+
+
 def _halo_spec(gr: GridRef, grid_sizes: Mapping[str, int],
                buf_shape: Tuple[int, ...], gpos: Mapping[str, int]):
     """Emission plan for a halo-windowed input: ``prepare`` gathers the
@@ -893,6 +969,7 @@ def _contract_sides(sides_vals: List[Tuple[jnp.ndarray, List[str]]],
                tuple(rax.index(v) for v in contract)),
               (tuple(lax.index(v) for v in batch),
                tuple(rax.index(v) for v in batch)))
+        la, ra = _promote_pair(la, ra)
         val = jax.lax.dot_general(la, ra, dn, preferred_element_type=acc_dtype)
         axes = batch + [v for v in lax if v not in shared] + \
             [v for v in rax if v not in shared]
@@ -921,7 +998,7 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
             f"red={sorted(mp.red_vars)} vs emitter red={sorted(plan.red_vars)}")
 
     preps: List[Tuple[Optional[Callable], Tuple[int, ...]]] = []
-    in_specs = []
+    in_blocks = []
     for gr in plan.in_refs:
         if gr.halo:
             if buffers is None or gr.ref.from_buf not in buffers:
@@ -930,11 +1007,10 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
             prep, bshape, imap = _halo_spec(
                 gr, plan.grid_sizes, tuple(buffers[gr.ref.from_buf].shape), gpos)
             preps.append((prep, bshape))
-            in_specs.append(pl.BlockSpec(bshape, imap))
+            in_blocks.append((bshape, imap))
         else:
             preps.append((None, gr.block_shape))
-            in_specs.append(pl.BlockSpec(gr.block_shape, _index_map_for(gr, gpos)))
-    out_spec = pl.BlockSpec(out_block, _index_map_for(plan.out_ref, gpos))
+            in_blocks.append((gr.block_shape, _index_map_for(gr, gpos)))
     out_full_shape = tuple(
         s * (plan.grid_sizes[v] if v else 1)
         for s, v in zip(out_block, plan.out_ref.dim_vars))
@@ -1051,24 +1127,22 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
             mp, vmem_cap, blocks + [(out_block, out_full_shape, plan.out_ref.ref)],
             buffers)
     scratch = [pltpu.VMEM(out_block, acc_dtype)] if has_red else []
-    call = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct(out_full_shape, out_dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        name=name,
-        **kwargs,
-    )
+    launch = _kernel_launcher(
+        kernel, grid, in_blocks, out_block, _index_map_for(plan.out_ref, gpos),
+        jax.ShapeDtypeStruct(out_full_shape, out_dtype), scratch, interpret,
+        name, kwargs)
 
     def fn(arrays: Mapping[str, jnp.ndarray]) -> jnp.ndarray:
-        args = []
+        operands = []
         for (prep, _), gr in zip(preps, plan.in_refs):
-            a = jnp.asarray(arrays[gr.ref.from_buf])
-            args.append(prep(a) if prep is not None else a)
-        return call(*args)
+            if prep is None:
+                operands.append(_operand(arrays, gr.ref.from_buf))
+            else:
+                # a halo view gathers its tiles from the slice itself
+                a = arrays[gr.ref.from_buf]
+                a = a.select() if isinstance(a, Stacked) else jnp.asarray(a)
+                operands.append((prep(a), None))
+        return launch(operands)
 
     fn.out_shape = out_full_shape
     fn.out_dtype = out_dtype
@@ -1122,8 +1196,8 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
         tiles = {g.ref.into: ins[i][...] for i, g in enumerate(order)}
         if cast_ints:
             tiles = {k: v.astype(acc_dtype) for k, v in tiles.items()}
-        lhs = _eval_tnode(plan.lhs, tiles)
-        rhs = _eval_tnode(plan.rhs, tiles)
+        lhs, rhs = _promote_pair(_eval_tnode(plan.lhs, tiles),
+                                 _eval_tnode(plan.rhs, tiles))
         if plan.n_batch:
             lhs = lhs.reshape(plan.lhs_shape)
             rhs = rhs.reshape(plan.rhs_shape)
@@ -1159,8 +1233,6 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
                 val = _apply_epilogue(plan, val, tile_args)
             out_ref[...] = val.astype(out_ref.dtype)
 
-    in_specs = [pl.BlockSpec(g.block_shape, _index_map_for(g, gpos)) for g in order]
-    out_spec = pl.BlockSpec(out_block, _index_map_for(plan.out_ref, gpos))
     out_full_shape = tuple(
         s * (plan.grid_sizes[v] if v else 1)
         for s, v in zip(out_block, plan.out_ref.dim_vars)
@@ -1180,21 +1252,14 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
         # sized by the memory plan when available (acc_bytes == f32 out
         # tile, verified above), else by the emitter's own analysis
         scratch = [pltpu.VMEM(out_block, acc_dtype)]
-    call = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct(out_full_shape, out_dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        name=name,
-        **kwargs,
-    )
+    launch = _kernel_launcher(
+        kernel, grid, [(g.block_shape, _index_map_for(g, gpos)) for g in order],
+        out_block, _index_map_for(plan.out_ref, gpos),
+        jax.ShapeDtypeStruct(out_full_shape, out_dtype), scratch, interpret,
+        name, kwargs)
 
     def fn(arrays: Mapping[str, jnp.ndarray]) -> jnp.ndarray:
-        args = [jnp.asarray(arrays[g.ref.from_buf]) for g in order]
-        return call(*args)
+        return launch([_operand(arrays, g.ref.from_buf) for g in order])
 
     fn.out_shape = out_full_shape
     fn.out_dtype = out_dtype
@@ -1227,21 +1292,15 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool,
             plan.grid_order, (), mp, vmem_cap,
             [(g.block_shape, None, g.ref) for g in plan.in_refs]
             + [(out_block, out_full_shape, plan.out_ref.ref)], buffers)
-    call = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec(g.block_shape, _index_map_for(g, gpos))
-                  for g in plan.in_refs],
-        out_specs=pl.BlockSpec(out_block, _index_map_for(plan.out_ref, gpos)),
-        out_shape=jax.ShapeDtypeStruct(out_full_shape, out_dtype),
-        interpret=interpret,
-        name=name,
-        **kwargs,
-    )
+    launch = _kernel_launcher(
+        kernel, grid,
+        [(g.block_shape, _index_map_for(g, gpos)) for g in plan.in_refs],
+        out_block, _index_map_for(plan.out_ref, gpos),
+        jax.ShapeDtypeStruct(out_full_shape, out_dtype), (), interpret, name,
+        kwargs)
 
     def fn(arrays: Mapping[str, jnp.ndarray]) -> jnp.ndarray:
-        args = [jnp.asarray(arrays[g.ref.from_buf]) for g in plan.in_refs]
-        return call(*args)
+        return launch([_operand(arrays, g.ref.from_buf) for g in plan.in_refs])
 
     fn.out_shape = out_full_shape
     fn.out_dtype = out_dtype
@@ -1535,7 +1594,9 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
     unit_times: Dict[str, float] = {}
 
     def run(arrays: Mapping[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
-        env: Dict[str, jnp.ndarray] = {k: jnp.asarray(v) for k, v in arrays.items()}
+        env: Dict[str, jnp.ndarray] = {
+            k: v if isinstance(v, Stacked) else jnp.asarray(v)
+            for k, v in arrays.items()}
         for u, kind, obj in steps:
             if profile:
                 t0 = time.perf_counter()
@@ -1545,7 +1606,9 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
                 if profile:
                     jax.block_until_ready([env[fn.out_buf] for fn in obj])
             else:
-                updates = obj(env)
+                # a jnp unit reads the slice of an input handed in place
+                updates = obj(select_stacked(
+                    {b: env[b] for b in obj.needed if b in env}))
                 env.update(updates)
                 if profile:
                     jax.block_until_ready(list(updates.values()))
@@ -1558,6 +1621,7 @@ def lower_program_hybrid(prog: Program, interpret: bool = False,
     run.n_kernels = n_pallas + sum(1 for _, kind, _ in steps if kind == "jnp")
     run.n_pallas = n_pallas
     run.kernel_names = kernel_names
+    run.takes_stacked = True
     run.block_backends = backends
     run.block_reasons = reasons
     run.unit_times = unit_times

@@ -64,7 +64,7 @@ class ViewSpec:
     slots: int = 1       # pipeline slots (streamed views get pipeline_depth)
     start: int = 0       # live interval [start, end], inclusive, in
     end: int = 0         # scheduled-statement-order positions
-    kind: str = "resident"  # stream | halo | resident | acc | scratch | local
+    kind: str = "resident"  # stream | halo | resident | acc | scratch | promote | local
     halo_bytes: int = 0  # margin bytes of a halo-windowed streamed slot
     #                      (slot = tile core + this overlap, already in
     #                      nbytes — recorded so reports can price the
@@ -124,8 +124,8 @@ def bump_bytes(views: Iterable[ViewSpec], align: int = ARENA_ALIGN) -> int:
     real buffer both models must hold, and only the planner's *slot*
     policy is under comparison — doubling it would inflate the baseline
     with an allocation the legacy rule never made."""
-    return sum((1 if v.kind == "scratch" else 2) * align_up(v.nbytes, align)
-               for v in views)
+    return sum((1 if v.kind in ("scratch", "promote") else 2)
+               * align_up(v.nbytes, align) for v in views)
 
 
 # --------------------------------------------------------------------------
@@ -280,12 +280,32 @@ def plan_block(block: Block, depth: int = 2) -> BlockPlan:
                               slots=1, start=0, end=max(len(body) - 1, 0),
                               kind="scratch"))
 
+    ins = [(r.into, r.dtype, (prod_bytes(r) if grid else view_span_bytes(r, ranges))
+            // dtype_bytes(r.dtype))
+           for r in block.refs if r.dir == RefDir.IN and not r.is_scalar_view()]
+    for name, nbytes in promoted_views(ins):
+        views.append(ViewSpec(name=f"{name}.promoted", nbytes=nbytes, slots=1,
+                              start=0, end=max(len(body) - 1, 0), kind="promote"))
+
     allocs, peak = allocate(views)
     return BlockPlan(block=block.name, allocs=allocs, peak_bytes=peak,
                      bump_bytes=bump_bytes(views), depth=depth, grid=grid,
                      red_vars=red_vars, parallel_vars=parallel_vars,
                      acc_bytes=acc_bytes,
                      halo_bytes=sum(v.halo_bytes * max(v.slots, 1) for v in views))
+
+
+def promoted_views(ins: Sequence[Tuple[str, str, int]]) -> List[Tuple[str, int]]:
+    """``(name, bytes)`` of the copy the kernel body makes of each float
+    input tile narrower than the widest float input, ``ins`` being
+    ``(name, dtype, elements)`` of one tile's input views: a bf16 weight
+    tile meeting an f32 activation is promoted to f32 after the load
+    (``lower_pallas._promote_pair``), a temporary beside its pipeline
+    slots.  Integer inputs are never promoted here."""
+    floats = [(n, dtype_bytes(d), e) for n, d, e in ins
+              if d.startswith(("float", "bfloat"))]
+    wide = max((b for _, b, _ in floats), default=0)
+    return [(n, e * wide) for n, b, e in floats if b < wide]
 
 
 def halo_margin_bytes(ref: Refinement, grid_vars: Set[str]) -> int:

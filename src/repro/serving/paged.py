@@ -26,6 +26,11 @@ The dense blocks inside these steps route through the Stripe-compiled
 programs of :mod:`repro.serving.stripe_decode` when ``progs`` is given,
 or through equivalent plain-jnp ops when it is None (A/B path).  Both
 compute in float32, matching the reference attention path's upcast.
+The layer loop scans the layer index, the norm scales and the KV pages;
+the matmul weights stay whole, stacked ``(n_layers, ...)`` in their
+stored dtype, and reach the programs as ``Stacked(weight, layer)``: the
+kernels read the layer's slice in place and promote it to f32 tile by
+tile, so a step copies no weight.
 """
 from __future__ import annotations
 
@@ -35,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.lower_jnp import Stacked
 from ..models import lm
 from ..nn.attention import NEG_INF, causal_mask, mha
 from ..reliability import faults
@@ -119,6 +125,35 @@ def _mlp_jnp(x2d: jnp.ndarray, resid2d: jnp.ndarray, p, act: str) -> jnp.ndarray
     return _proj(a, p["w_down"]) + resid2d.astype(jnp.float32)
 
 
+# ----------------------------------------------------------- layer weights
+# per-layer matmul weights, read in place from the stacked parameter
+_MATMUL = {"attn": ("wq", "wk", "wv", "wo"), "mlp": ("w_gate", "w_up", "w_down")}
+
+
+def _split_blocks(blocks):
+    """(matmul weights, everything else) of the stacked per-layer tree:
+    the weights stay out of the layer scan's inputs, so no per-layer slice
+    of them is made; the rest (norm scales) is scanned as before."""
+    mats = {g: {k: v for k, v in blocks[g].items() if k in names}
+            for g, names in _MATMUL.items() if g in blocks}
+    rest = {g: ({k: v for k, v in sub.items() if k not in _MATMUL[g]}
+                if g in _MATMUL else sub)
+            for g, sub in blocks.items()}
+    return mats, rest
+
+
+def _layer_params(mats, rest_i, i, in_place: bool):
+    """Layer ``i``'s parameter tree: ``rest_i`` (the scanned slices) with
+    each matmul weight as ``Stacked(weight, i)`` for the programs, or its
+    slice for the plain-jnp path."""
+    p_i = {g: dict(sub) for g, sub in rest_i.items()}
+    for g, sub in mats.items():
+        for k, w in sub.items():
+            p_i[g][k] = (Stacked(w, i) if in_place else
+                         jax.lax.dynamic_index_in_dim(w, i, keepdims=False))
+    return p_i
+
+
 # ------------------------------------------------------------ decode step
 def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
     """Build the (jit-friendly) continuous decode step.
@@ -149,8 +184,11 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
         valid = kpos[None, :] < (pos + 1)[:, None]  # (S, T)
         mask = valid[:, None, None, :]  # (S, 1|KV, 1|G, T)
 
+        mats, rest = _split_blocks(params["blocks"])
+
         def layer(x, scanned):
-            p_i, pk, pv = scanned
+            i, rest_i, pk, pv = scanned
+            p_i = _layer_params(mats, rest_i, i, progs is not None)
             ap = p_i["attn"]
             xn = apply_norm(p_i["ln1"], x, cfg.norm)
             if progs is not None:
@@ -205,7 +243,8 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
                     (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape)))
 
         x, (pages_k, pages_v) = jax.lax.scan(
-            layer, x, (params["blocks"], pages_k, pages_v))
+            layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest,
+                       pages_k, pages_v))
         logits = lm._logits(params, cfg, x)  # (S, 1, V)
         nxt = jnp.argmax(logits[:, -1, : cfg.vocab], axis=-1).astype(jnp.int32)
         return nxt, pages_k, pages_v
@@ -238,8 +277,11 @@ def make_prefill_step(cfg, progs: Optional[DecodePrograms], page_size: int,
         positions = t[None]  # (1, Lb)
         cmask = causal_mask(lb)
 
+        mats, rest = _split_blocks(params["blocks"])
+
         def layer(x, scanned):
-            p_i, pk, pv = scanned
+            i, rest_i, pk, pv = scanned
+            p_i = _layer_params(mats, rest_i, i, progs is not None)
             ap = p_i["attn"]
             xn = apply_norm(p_i["ln1"], x, cfg.norm)
             if progs is not None:
@@ -277,7 +319,8 @@ def make_prefill_step(cfg, progs: Optional[DecodePrograms], page_size: int,
                     (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape)))
 
         x, (pages_k, pages_v) = jax.lax.scan(
-            layer, x, (params["blocks"], pages_k, pages_v))
+            layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest,
+                       pages_k, pages_v))
         x_last = jax.lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, x.shape[-1]))
         logits = lm._logits(params, cfg, x_last)  # (1, 1, V)
         tok = jnp.argmax(logits[0, 0, : cfg.vocab]).astype(jnp.int32)

@@ -29,13 +29,21 @@ length for prefill):
   (tanh-approximated ``gelu``), the matmuls compile through Stripe and
   the activation runs outside, recorded in ``act_outside``.
 
-Programs compute in float32 (matching the reference attention path,
-which upcasts for scores/values); callers cast in and out.
+Operands: activations, residuals and outputs are float32 (matching the
+reference attention path, which upcasts for scores/values), and so is
+every accumulator.  The matmul weights (``WEIGHT_INPUTS``) are declared
+at the configuration's dtype, the dtype they are stored in: a bf16
+weight crosses HBM and the DMA as bf16 and the kernel promotes each tile
+to f32 after the load.  A caller may hand a weight in place as
+``Stacked(stacked, layer)``, the layer loop's ``(n_layers, ...)``
+parameter plus the layer index: the kernels read the layer's slice where
+it lies, so no per-step copy of any weight is made.  Both show in each
+program's ``CompileRecord.stored_reads``.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Union
 
 import jax.numpy as jnp
 
@@ -43,6 +51,7 @@ from ..core import cache as _cache
 from ..core.driver import CompiledProgram, CompileRecord, stripe_jit
 from ..core.frontend import TileProgram
 from ..core.hwconfig import HardwareConfig
+from ..core.lower_jnp import Stacked
 
 # activations whose Stripe intrinsic chain is semantically identical to
 # the framework's nn.core._ACT implementation (see module docstring)
@@ -51,6 +60,11 @@ _FUSABLE_ACT = {
     "relu": "relu({x})",
     "relu2": "square(relu({x}))",
 }
+
+# the programs' matmul weight inputs, declared at the stored dtype
+WEIGHT_INPUTS = ("WQ", "WK", "WV", "WO", "Wg", "Wu", "Wd")
+
+Weight = Union[jnp.ndarray, Stacked]
 
 
 def _jit_opts(cfg: "EngineLikeConfig") -> Dict:
@@ -90,9 +104,9 @@ def build_qkv_program(cfg, m: int, jc: EngineLikeConfig) -> CompiledProgram:
     d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     tp = TileProgram(f"serve_qkv_m{m}")
     tp.input("X", (m, d))
-    tp.input("WQ", (d, h * hd))
-    tp.input("WK", (d, kv * hd))
-    tp.input("WV", (d, kv * hd))
+    tp.input("WQ", (d, h * hd), cfg.dtype)
+    tp.input("WK", (d, kv * hd), cfg.dtype)
+    tp.input("WV", (d, kv * hd), cfg.dtype)
     tp.output("Q", (m, h * hd))
     tp.output("K", (m, kv * hd))
     tp.output("V", (m, kv * hd))
@@ -107,7 +121,7 @@ def build_attn_out_program(cfg, m: int, jc: EngineLikeConfig) -> CompiledProgram
     tp = TileProgram(f"serve_attn_out_m{m}")
     tp.input("A", (m, h * hd))
     tp.input("R", (m, d))
-    tp.input("WO", (h * hd, d))
+    tp.input("WO", (h * hd, d), cfg.dtype)
     tp.temp("T", (m, d))
     tp.output("Y", (m, d))
     tp.op("T[b, d2] += A[b, e] * WO[e, d2]", name="proj_o")
@@ -128,10 +142,10 @@ def build_mlp_program(cfg, m: int, jc: EngineLikeConfig):
     tp = TileProgram(f"serve_mlp_m{m}")
     tp.input("X", (m, d))
     tp.input("R", (m, d))
-    tp.input("Wd", (f, d))
+    tp.input("Wd", (f, d), cfg.dtype)
     if glu:
-        tp.input("Wg", (d, f))
-        tp.input("Wu", (d, f))
+        tp.input("Wg", (d, f), cfg.dtype)
+        tp.input("Wu", (d, f), cfg.dtype)
         if fused:
             tp.temp("G", (m, f))
             tp.temp("U", (m, f))
@@ -145,7 +159,7 @@ def build_mlp_program(cfg, m: int, jc: EngineLikeConfig):
             # matmuls through Stripe, activation outside: split programs
             return _split_glu_programs(cfg, m, jc), base
     else:
-        tp.input("Wu", (d, f))
+        tp.input("Wu", (d, f), cfg.dtype)
         if fused:
             tp.temp("H", (m, f))
             tp.temp("A", (m, f))
@@ -166,7 +180,8 @@ def _split_glu_programs(cfg, m: int, jc: EngineLikeConfig):
     U, and a down program applying Wd + residual."""
     d, f = cfg.d_model, cfg.d_ff
     up = TileProgram(f"serve_mlp_up_m{m}")
-    up.input("X", (m, d)); up.input("Wg", (d, f)); up.input("Wu", (d, f))
+    up.input("X", (m, d)); up.input("Wg", (d, f), cfg.dtype)
+    up.input("Wu", (d, f), cfg.dtype)
     up.output("G", (m, f)); up.output("U", (m, f))
     up.op("G[b, f] += X[b, d] * Wg[d, f]", name="mm_gate")
     up.op("U[b, f] += X[b, d] * Wu[d, f]", name="mm_up")
@@ -178,7 +193,7 @@ def _split_glu_programs(cfg, m: int, jc: EngineLikeConfig):
 def _split_plain_programs(cfg, m: int, jc: EngineLikeConfig):
     d, f = cfg.d_model, cfg.d_ff
     up = TileProgram(f"serve_mlp_up_m{m}")
-    up.input("X", (m, d)); up.input("Wu", (d, f))
+    up.input("X", (m, d)); up.input("Wu", (d, f), cfg.dtype)
     up.output("H", (m, f))
     up.op("H[b, f] += X[b, d] * Wu[d, f]", name="mm_up")
     cup = stripe_jit(up.build(), jc.hw, **_jit_opts(jc))
@@ -188,7 +203,7 @@ def _split_plain_programs(cfg, m: int, jc: EngineLikeConfig):
 def _down_program(cfg, m: int, jc: EngineLikeConfig) -> CompiledProgram:
     d, f = cfg.d_model, cfg.d_ff
     tp = TileProgram(f"serve_mlp_down_m{m}")
-    tp.input("A", (m, f)); tp.input("R", (m, d)); tp.input("Wd", (f, d))
+    tp.input("A", (m, f)); tp.input("R", (m, d)); tp.input("Wd", (f, d), cfg.dtype)
     tp.temp("O", (m, d))
     tp.output("Y", (m, d))
     tp.op("O[b, d2] += A[b, f] * Wd[f, d2]", name="mm_down")
@@ -261,21 +276,24 @@ def build_programs(cfg, m: int, jc: EngineLikeConfig,
 
 
 # ------------------------------------------------------------------ apply
-def run_qkv(progs: DecodePrograms, x2d: jnp.ndarray, wq, wk, wv):
-    out = progs.qkv({"X": x2d.astype(jnp.float32), "WQ": wq.astype(jnp.float32),
-                     "WK": wk.astype(jnp.float32), "WV": wv.astype(jnp.float32)})
+# Weights go in as stored (an array at the declared dtype, or ``Stacked``);
+# activations and residuals are cast to the programs' f32.
+def run_qkv(progs: DecodePrograms, x2d: jnp.ndarray, wq: Weight, wk: Weight,
+            wv: Weight):
+    out = progs.qkv({"X": x2d.astype(jnp.float32), "WQ": wq, "WK": wk, "WV": wv})
     return out["Q"], out["K"], out["V"]
 
 
-def run_attn_out(progs: DecodePrograms, attn2d: jnp.ndarray, resid2d: jnp.ndarray, wo):
+def run_attn_out(progs: DecodePrograms, attn2d: jnp.ndarray, resid2d: jnp.ndarray,
+                 wo: Weight):
     out = progs.attn_out({"A": attn2d.astype(jnp.float32),
-                          "R": resid2d.astype(jnp.float32),
-                          "WO": wo.astype(jnp.float32)})
+                          "R": resid2d.astype(jnp.float32), "WO": wo})
     return out["Y"]
 
 
 def run_mlp(progs: DecodePrograms, x2d: jnp.ndarray, resid2d: jnp.ndarray, mlp_params, act: str):
-    """Apply the (possibly split) MLP program, matching nn.core.mlp_apply."""
+    """Apply the (possibly split) MLP program, matching nn.core.mlp_apply.
+    ``mlp_params`` holds the weights as stored (arrays or ``Stacked``)."""
     from ..nn.core import _ACT
 
     x2d = x2d.astype(jnp.float32)
@@ -284,18 +302,14 @@ def run_mlp(progs: DecodePrograms, x2d: jnp.ndarray, resid2d: jnp.ndarray, mlp_p
     glu = act.endswith("_glu")
     if isinstance(mlp, _SplitMLP):
         if glu:
-            got = mlp.up({"X": x2d, "Wg": mlp_params["w_gate"].astype(jnp.float32),
-                          "Wu": mlp_params["w_up"].astype(jnp.float32)})
+            got = mlp.up({"X": x2d, "Wg": mlp_params["w_gate"], "Wu": mlp_params["w_up"]})
             a = _ACT[progs.act_outside](got["G"]) * got["U"]
         else:
-            got = mlp.up({"X": x2d, "Wu": mlp_params["w_up"].astype(jnp.float32)})
+            got = mlp.up({"X": x2d, "Wu": mlp_params["w_up"]})
             a = _ACT[progs.act_outside](got["H"])
-        return mlp.down({"A": a, "R": resid2d,
-                         "Wd": mlp_params["w_down"].astype(jnp.float32)})["Y"]
-    arrays = {"X": x2d, "R": resid2d, "Wd": mlp_params["w_down"].astype(jnp.float32)}
+        return mlp.down({"A": a, "R": resid2d, "Wd": mlp_params["w_down"]})["Y"]
+    arrays = {"X": x2d, "R": resid2d, "Wd": mlp_params["w_down"],
+              "Wu": mlp_params["w_up"]}
     if glu:
-        arrays["Wg"] = mlp_params["w_gate"].astype(jnp.float32)
-        arrays["Wu"] = mlp_params["w_up"].astype(jnp.float32)
-    else:
-        arrays["Wu"] = mlp_params["w_up"].astype(jnp.float32)
+        arrays["Wg"] = mlp_params["w_gate"]
     return mlp(arrays)["Y"]

@@ -57,18 +57,25 @@ PROGRAMS = {
 }
 
 
+def _input_shapes(prog, sharding):
+    """Each input at its declared dtype: the matmul weights at the
+    configuration's (bf16), everything else f32."""
+    import jax
+
+    return {n: jax.ShapeDtypeStruct(prog.program.buffers[n].shape,
+                                    prog.program.buffers[n].dtype, sharding=sharding)
+            for n in prog.program.inputs}
+
+
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
 def test_served_program_compiles_for_v5e(name, one_chip, jc):
     import jax
-    import jax.numpy as jnp
 
     prog = PROGRAMS[name](jc)
     rec = prog.record
     assert rec.backend == "pallas", rec.fallback_reason
     assert set(rec.block_backends.values()) == {"pallas"}, rec.block_fallbacks
-    shapes = {n: jax.ShapeDtypeStruct(prog.program.buffers[n].shape, jnp.float32,
-                                      sharding=one_chip)
-              for n in prog.program.inputs}
+    shapes = _input_shapes(prog, one_chip)
     compiled = jax.jit(lambda arrays: prog(arrays)).lower(shapes).compile()
     assert compiled.as_text().count("tpu_custom_call") == rec.n_kernels
 
@@ -81,12 +88,9 @@ def test_served_kernels_carry_block_names(name, one_chip, jc):
     import re
 
     import jax
-    import jax.numpy as jnp
 
     prog = PROGRAMS[name](jc)
-    shapes = {n: jax.ShapeDtypeStruct(prog.program.buffers[n].shape, jnp.float32,
-                                      sharding=one_chip)
-              for n in prog.program.inputs}
+    shapes = _input_shapes(prog, one_chip)
     text = jax.jit(lambda arrays: prog(arrays)).lower(shapes).compile().as_text()
     ops = [re.match(r"\s*(?:ROOT )?%?(\S+) = ", line).group(1)
            for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -94,3 +98,59 @@ def test_served_kernels_carry_block_names(name, one_chip, jc):
     want = {f"{entry}.{unit.replace('+', '_')}" for unit in prog.record.block_backends}
     assert {re.sub(r"\.\d+$", "", op) for op in ops} == want
     assert len(ops) == prog.record.n_kernels
+
+
+def _step_hlo(phase, sharding, jc):
+    """Optimized HLO of the whole jitted qwen3-4b decode step (8 slots,
+    window 1024) or 128-bucket prefill, parameters as shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.build import build_model
+    from repro.serving.paged import make_decode_step, make_prefill_step
+
+    ps = 16
+    pps = KV_WINDOW // ps
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(build_model(QWEN).init, jax.random.PRNGKey(0)))
+    pages = sds((QWEN.n_layers, SLOTS * pps + SLOTS, ps, QWEN.n_kv_heads, QWEN.hd),
+                jnp.dtype(QWEN.dtype))
+    i32 = jnp.int32
+    if phase == "decode":
+        progs = sd.build_programs(QWEN, SLOTS, jc, kv_window=KV_WINDOW)
+        fn = jax.jit(make_decode_step(QWEN, progs, ps))
+        args = (params, pages, pages, sds((SLOTS, pps), i32), sds((SLOTS,), i32),
+                sds((SLOTS,), i32))
+    else:
+        progs = sd.build_programs(QWEN, PREFILL_BUCKET, jc)
+        fn = jax.jit(make_prefill_step(QWEN, progs, ps, PREFILL_BUCKET))
+        args = (params, sds((1, PREFILL_BUCKET), i32), sds((), i32), sds((pps,), i32),
+                pages, pages)
+    return fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_served_step_reads_stored_weights_in_place(phase, one_chip, jc):
+    """In the whole compiled step no weight is copied: no array of a
+    weight's shape is made (no f32 convert, no per-layer dynamic-slice),
+    and each matmul kernel takes the stacked bf16 ``(36, ...)`` weight
+    itself, selected by the layer index in scalar prefetch."""
+    import re
+
+    text = _step_hlo(phase, one_chip, jc)
+    d, h, kv, hd, f = QWEN.d_model, QWEN.n_heads, QWEN.n_kv_heads, QWEN.hd, QWEN.d_ff
+    weight_shapes = {(d, h * hd), (d, kv * hd), (h * hd, d), (d, f), (f, d)}
+    made = [line.strip()[:160] for line in text.splitlines()
+            for m in [re.search(r"= (?:f32|bf16)\[(\d+),(\d+)\]", line)]
+            if m and (int(m.group(1)), int(m.group(2))) in weight_shapes]
+    assert not made, made
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r"%serve_(qkv|attn_out|mlp)_m\d+\.", line)]
+    assert len(calls) == 7  # proj_q/k/v, proj_o_resid, mm_gate/up_glu/down_resid
+    for line in calls:
+        operands = line.split("operand_layout_constraints=", 1)[1]
+        assert operands.startswith("{s32[1]"), line[:200]
+        assert f"bf16[{QWEN.n_layers}," in operands.split("}}", 1)[0], line[:200]

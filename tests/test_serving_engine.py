@@ -72,6 +72,34 @@ def test_paged_matches_dense_reference_mixed_lengths(model, params):
             f"uid {r.uid}: paged decode diverged from dense reference"
 
 
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_paged_bf16_matches_cast_outside(backend):
+    """At a bf16 configuration the served programs read each layer's
+    stored weights in place from the stacked parameter and promote them
+    in the kernel.  Greedy tokens match the paged plain-jnp path, which
+    slices each layer and casts it to f32 outside.  (The dense reference
+    rounds its bf16 matmul outputs, so it is not token-exact at bf16.)"""
+    import dataclasses
+
+    import jax
+
+    model = build_model(dataclasses.replace(_tiny_cfg(), dtype="bfloat16"))
+    params = model.init(jax.random.PRNGKey(0))
+    plens = [3, 8, 13, 21, 32, 5]
+    runs = {}
+    for name, kw in (("cast_outside", dict(use_stripe_decode=False)),
+                     ("stored", dict(backend=backend))):
+        eng = _engine(model, slots=3, **kw)
+        for r in _mk_requests(model.cfg, plens, new=7):
+            eng.submit(r)
+        runs[name] = {r.uid: r.out_tokens for r in eng.run(params, max_steps=4096)}
+    assert sorted(runs["stored"]) == list(range(len(plens)))
+    assert runs["stored"] == runs["cast_outside"]
+    for key in ("decode/qkv", "decode/attn_out", "decode/mlp"):
+        reads = eng.compile_records()[key].stored_reads
+        assert reads and all(e["narrow"] and e["in_place"] for e in reads.values()), reads
+
+
 def test_determinism_across_runs(model, params):
     def run_once():
         eng = _engine(model)
