@@ -1,4 +1,8 @@
-"""Architecture registry: ``get(name)`` / ``names()``."""
+"""Architecture registry: ``get(name)`` / ``names()``.
+
+``names()`` lists the architectures; ``get`` also finds the shares a
+module declares (``SHARES``: one chip's part of a sharded deployment,
+such as ``qwen3-moe-30b-a3b-ep8``), which serve but do not train."""
 from __future__ import annotations
 
 from typing import Dict, List
@@ -19,6 +23,7 @@ _MODULES = [
 ]
 
 _REGISTRY: Dict[str, ArchConfig] = {}
+_SHARES: Dict[str, ArchConfig] = {}
 
 
 def _load() -> None:
@@ -30,11 +35,18 @@ def _load() -> None:
         mod = importlib.import_module(f".{m}", __package__)
         cfg: ArchConfig = mod.CONFIG
         _REGISTRY[cfg.name] = cfg
+        for share in getattr(mod, "SHARES", ()):
+            _SHARES[share.name] = share
 
 
 def get(name: str) -> ArchConfig:
     _load()
-    return _REGISTRY[name]
+    return _REGISTRY[name] if name in _REGISTRY else _SHARES[name]
+
+
+def shares() -> List[str]:
+    _load()
+    return list(_SHARES)
 
 
 def names() -> List[str]:
@@ -42,4 +54,4 @@ def names() -> List[str]:
     return list(_REGISTRY)
 
 
-__all__ = ["get", "names", "ArchConfig", "ShapeSpec", "SHAPES", "applicable_shapes"]
+__all__ = ["get", "names", "shares", "ArchConfig", "ShapeSpec", "SHAPES", "applicable_shapes"]

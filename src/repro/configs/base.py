@@ -13,10 +13,25 @@ from typing import Dict, Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
+    """Routed experts.  ``n_held`` (0: all) experts, from ``held_offset``
+    on, are the ones this layer holds and computes: one chip's share of
+    an expert-parallel deployment.  Routing always runs over all
+    ``n_experts``; the picks of experts held elsewhere give nothing here."""
+
     n_experts: int
     top_k: int
     d_ff_expert: int
     capacity_factor: float = 1.25
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+    @property
+    def is_share(self) -> bool:
+        return self.held < self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,7 +113,7 @@ class ArchConfig:
         # attention
         per_layer += d * (self.n_heads * hd) + d * (2 * self.n_kv_heads * hd) + (self.n_heads * hd) * d
         if self.moe:
-            per_layer += d * self.moe.n_experts * self.moe.d_ff_expert * 3 + d * self.moe.n_experts
+            per_layer += d * self.moe.held * self.moe.d_ff_expert * 3 + d * self.moe.n_experts
         elif self.d_ff:
             mult = 3 if self.act.endswith("_glu") else 2
             per_layer += mult * d * self.d_ff
@@ -114,7 +129,7 @@ class ArchConfig:
         if not self.moe:
             return self.param_count()
         d = self.d_model
-        per_layer_moe_all = d * self.moe.n_experts * self.moe.d_ff_expert * 3
+        per_layer_moe_all = d * self.moe.held * self.moe.d_ff_expert * 3
         per_layer_moe_act = d * self.moe.top_k * self.moe.d_ff_expert * 3
         return self.param_count() - self.n_layers * (per_layer_moe_all - per_layer_moe_act)
 
@@ -126,7 +141,13 @@ class ArchConfig:
             vocab_pad_multiple=32, dtype="float32",
         )
         if self.moe:
-            base["moe"] = MoECfg(n_experts=4, top_k=2, d_ff_expert=32)
+            if self.moe.is_share:
+                # a share stays a share: 2 of 8 experts held, top-4
+                base["moe"] = MoECfg(n_experts=8, top_k=4, d_ff_expert=32, n_held=2)
+            else:
+                base["moe"] = MoECfg(n_experts=4, top_k=2, d_ff_expert=32)
+            if self.d_ff == self.moe.d_ff_expert:
+                base["d_ff"] = 32
         if self.ssm:
             base["ssm"] = SSMCfg(d_state=8, head_dim=16, expand=2, conv_width=4)
         if self.xlstm:

@@ -407,6 +407,21 @@ class FusionDecision:
         return dataclasses.asdict(self)
 
 
+def indexed_vars(refs, buffers: Mapping) -> Dict[str, int]:
+    """The index variables that alone address the leading dim of an input
+    declared ``indexed`` (selected per block at call time), each pinned to
+    a tile of 1: a block of that dim is one selected row."""
+    pins: Dict[str, int] = {}
+    for r in refs:
+        d = buffers.get(r.from_buf)
+        if d is None or not getattr(d, "indexed", False) or not r.offsets:
+            continue
+        e = r.offsets[0]
+        if len(e.terms) == 1 and e.terms[0][1] == 1:
+            pins[e.terms[0][0]] = 1
+    return pins
+
+
 def canonical_tile(ranges: Mapping[str, int], params: Mapping,
                    clamp_vars=None) -> Dict[str, int]:
     """The tile shape the profitability model prices a group at — fusion
@@ -449,7 +464,8 @@ def refetch_bytes(ref_vars, free: Mapping[str, int], out_vars, tile: Mapping[str
 
 
 def fusion_vmem_pressure(refs, ranges: Mapping[str, int], hw: HardwareConfig,
-                         params: Mapping, clamp_vars=None) -> Tuple[int, int, bool]:
+                         params: Mapping, clamp_vars=None,
+                         pins: Optional[Mapping[str, int]] = None) -> Tuple[int, int, bool]:
     """(arena bytes for one canonical tile of the candidate group, cap,
     fits).  Pressure is priced with memplan's slot model: views streamed
     by a clamped (grid) index get ``pipeline_depth`` slots, grid-
@@ -458,11 +474,13 @@ def fusion_vmem_pressure(refs, ranges: Mapping[str, int], hw: HardwareConfig,
     the same arithmetic the autotiler's feasibility check and the
     schedule-time allocator use.  ``params["memplan"] = False`` restores
     the legacy blanket rule (everything double-buffered, no slot
-    classes)."""
+    classes).  ``pins`` fixes the tile of some variables, as the
+    autotiler will (``indexed_vars``)."""
     from . import memplan
     from .passes.schedule import arena_bytes
 
     tile = canonical_tile(ranges, params, clamp_vars)
+    tile.update({v: t for v, t in (pins or {}).items() if v in tile})
     cap = int(hw.inner_mem().size_bytes * params.get("mem_cap_frac", 0.45))
     if not params.get("memplan", True):
         sizes = [tile_view_bytes(r, ranges, tile) for r in refs]

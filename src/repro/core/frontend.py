@@ -305,8 +305,12 @@ class TileProgram:
         self.outputs: List[str] = []
         self.ops: List[Tuple[str, OpSpec]] = []
 
-    def input(self, name: str, shape: Sequence[int], dtype: str = "float32") -> str:
-        self.decls[name] = TensorDecl(name, tuple(shape), dtype)
+    def input(self, name: str, shape: Sequence[int], dtype: str = "float32",
+              indexed: bool = False) -> str:
+        """Declare an input; ``indexed`` marks one whose leading dim is
+        selected per block at call time (``Stacked`` with an index vector,
+        see :class:`~repro.core.ir.TensorDecl`)."""
+        self.decls[name] = TensorDecl(name, tuple(shape), dtype, indexed)
         self.inputs.append(name)
         return name
 

@@ -269,6 +269,11 @@ class TensorDecl:
     name: str
     shape: Tuple[int, ...]
     dtype: str = "float32"
+    # an input whose leading dim is selected per block: the caller hands
+    # it as ``Stacked(array, index vector)``, each block of the leading
+    # dim read from the row of ``array`` its index names, so the
+    # compiler tiles that dim by 1 (``autotile``)
+    indexed: bool = False
 
     @property
     def rank(self) -> int:
@@ -395,7 +400,8 @@ def canonical_ir(obj: Union[Program, Block]):
         return _canon_block(obj)
     return [
         "program",
-        sorted([d.name, list(d.shape), d.dtype] for d in obj.buffers.values()),
+        sorted([d.name, list(d.shape), d.dtype] + (["indexed"] if d.indexed else [])
+               for d in obj.buffers.values()),
         list(obj.inputs), list(obj.outputs),
         _canon_block(obj.entry),
     ]

@@ -65,22 +65,34 @@ _AGG_JNP = {
 # Inputs handed in place
 # --------------------------------------------------------------------------
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["array", "index"], meta_fields=[])
+         data_fields=["array", "index", "live"], meta_fields=[])
 @dataclasses.dataclass(frozen=True)
 class Stacked:
-    """A program input handed in place: ``array`` carries one leading
-    axis more than the declared operand, and ``index`` (an integer
-    scalar, traced or not) selects the operand along it — the stacked
-    ``(n_layers, d, f)`` weight of a layer loop plus the layer.  The
-    Pallas kernels read the selected slice where it lies (the index rides
-    in scalar prefetch); every other lowering takes :meth:`select`."""
+    """A program input handed in place.
+
+    With a scalar ``index`` (traced or not), ``array`` carries one leading
+    axis more than the declared operand and the index selects the operand
+    along it: the stacked ``(n_layers, d, f)`` weight of a layer loop plus
+    the layer.  With an index vector ``(k,)``, ``array`` has the operand's
+    rank and the operand is ``array[index]``: block ``j`` of the operand's
+    leading dim (declared ``indexed``, so tiled by 1) is row ``index[j]``
+    of ``array``, e.g. the expert whose weights a block of rows meets.
+    ``live`` (``(k,)``, with an index vector only) marks the blocks whose
+    results matter: the Pallas kernels skip the others, whose results are
+    then unspecified, and fetch nothing new for them.
+
+    The Pallas kernels read the selected rows where they lie (the indices
+    ride in scalar prefetch); every other lowering takes :meth:`select`."""
 
     array: object
     index: object
+    live: object = None
 
     def select(self) -> jnp.ndarray:
-        return jax.lax.dynamic_index_in_dim(jnp.asarray(self.array), self.index,
-                                            axis=0, keepdims=False)
+        a, idx = jnp.asarray(self.array), jnp.asarray(self.index)
+        if idx.ndim == 0:
+            return jax.lax.dynamic_index_in_dim(a, idx, axis=0, keepdims=False)
+        return jnp.take(a, idx, axis=0, mode="clip")
 
 
 def select_stacked(arrays: Mapping[str, object]) -> Dict[str, object]:

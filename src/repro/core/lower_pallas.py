@@ -769,12 +769,29 @@ def _index_map_for(gr: GridRef, gpos: Mapping[str, int]):
 
 
 def _operand(arrays: Mapping[str, object], buf: str):
-    """(array, selector or None) of one kernel input: a :class:`Stacked`
-    input is handed whole, with the index that selects its slice."""
+    """(array, :class:`Stacked` or None) of one kernel input: a
+    ``Stacked`` input is handed whole, with the indices that select it."""
     a = arrays[buf]
     if isinstance(a, Stacked):
-        return jnp.asarray(a.array), a.index
+        return jnp.asarray(a.array), a
     return jnp.asarray(a), None
+
+
+def _lead_axis(gr: GridRef, gpos: Mapping[str, int]) -> Optional[int]:
+    """The grid axis whose index addresses the leading dim of a view."""
+    v = gr.dim_vars[0] if gr.dim_vars else None
+    return gpos[v] if v is not None else None
+
+
+def _live_source(live: jnp.ndarray) -> jnp.ndarray:
+    """For each block, the block whose operands a step reads: itself where
+    live, else the last live block before it, else the first after it
+    (0 where none is live)."""
+    k = live.shape[0]
+    at = jnp.arange(k, dtype=jnp.int32)
+    prev = jax.lax.cummax(jnp.where(live, at, -1), axis=0)
+    nxt = jax.lax.cummin(jnp.where(live, at, k), axis=0, reverse=True)
+    return jnp.where(prev >= 0, prev, jnp.where(nxt < k, nxt, 0)).astype(jnp.int32)
 
 
 def _promote_pair(a: jnp.ndarray, b: jnp.ndarray):
@@ -790,56 +807,138 @@ def _promote_pair(a: jnp.ndarray, b: jnp.ndarray):
 
 
 def _kernel_launcher(kernel: Callable, grid: Tuple[int, ...],
-                     in_blocks: Sequence[Tuple[Tuple[int, ...], Callable]],
+                     in_blocks: Sequence[Tuple[Tuple[int, ...], Callable, Optional[int]]],
                      out_block: Tuple[int, ...], out_imap: Callable,
                      out_shape: jax.ShapeDtypeStruct, scratch: Sequence,
                      interpret: bool, name: Optional[str], kwargs: Mapping):
     """``launch(operands)`` for one kernel, ``operands`` being one
-    ``(array, selector or None)`` per ``in_blocks`` entry (block shape,
-    index map).  With no selector it is the plain ``pallas_call``.  A
-    selected operand is handed whole: the selectors ride in scalar
-    prefetch (``PrefetchScalarGridSpec``), its block gains a squeezed
-    leading dim and its index map leads with its selector, so the kernel
-    reads the same tile from where the slice lies and no slice is ever
-    copied.  One ``pallas_call`` is built per pattern of selected
-    operands."""
-    built: Dict[Tuple[bool, ...], Callable] = {}
+    ``(array, Stacked or None)`` per ``in_blocks`` entry (block shape,
+    index map, the grid axis that addresses the leading dim or None).
+    With no ``Stacked`` operand it is the plain ``pallas_call``.  A
+    selected operand is handed whole and its indices ride in scalar
+    prefetch (``PrefetchScalarGridSpec``), so the kernel reads its tiles
+    where they lie and no slice is ever copied:
 
-    def build(selected: Tuple[bool, ...]) -> Callable:
-        if not any(selected):
+    * a scalar index (``"one"``): the block gains a squeezed leading dim
+      and the index map leads with the index;
+    * an index vector (``"each"``): the block's leading dim is 1 and its
+      block index ``j`` becomes ``index[j]``.  With ``live`` flags, a step
+      of a dead block runs no kernel body, and every index map of it
+      (inputs and output) points where a neighbouring live step points,
+      the last step of the live block before it or the first of the one
+      after, so the pipeline fetches and writes back nothing new.  That
+      needs the selected axis to be the outermost one that varies, and
+      a launch with live flags on any other axis raises.
+
+    One ``pallas_call`` is built per pattern of selected operands."""
+    built: Dict[Tuple, Callable] = {}
+    n_grid = len(grid)
+
+    def build(kinds: Tuple[Optional[str], ...], live_axis: Optional[int]) -> Callable:
+        if not any(kinds):
             return pl.pallas_call(
                 kernel, grid=grid,
-                in_specs=[pl.BlockSpec(b, m) for b, m in in_blocks],
+                in_specs=[pl.BlockSpec(b, m) for b, m, _ in in_blocks],
                 out_specs=pl.BlockSpec(out_block, out_imap), out_shape=out_shape,
                 scratch_shapes=list(scratch), interpret=interpret, name=name,
                 **kwargs)
-        def spec(block, imap, j=None):
-            # index maps get the prefetched selectors after the grid indices
-            if j is None:
-                return pl.BlockSpec(block, lambda *a: imap(*a[:-1]))
-            return pl.BlockSpec((pl.squeezed, *block),
-                                lambda *a: (a[-1][j], *imap(*a[:-1])))
+        # prefetch: the scalar indices stacked in one vector, then one
+        # vector per "each" operand, then the live blocks' sources and flags
+        ones = "one" in kinds
+        slots, k = [], int(ones)
+        for kind in kinds:
+            if kind == "each":
+                slots.append(k)
+                k += 1
+            else:
+                slots.append(None)
+        n_pre = k + (2 if live_axis is not None else 0)
+        one_at = iter(range(kinds.count("one")))
 
-        slot = iter(range(sum(selected)))
+        def steps(gidx, pre):
+            if live_axis is None:
+                return gidx
+            src, live = pre[-2], pre[-1]
+            j = gidx[live_axis]
+            s = src[j]
+            on = live[j] != 0
+            before = s > j
+            return tuple(
+                s if a == live_axis else
+                g if a < live_axis else
+                jnp.where(on, g, jnp.where(before, 0, grid[a] - 1))
+                for a, g in enumerate(gidx))
+
+        def spec(block, imap, kind=None, slot=None, one=None):
+            def index(*a):
+                gidx, pre = a[:n_grid], a[n_grid:]
+                bi = imap(*steps(gidx, pre))
+                if kind == "one":
+                    return (pre[0][one], *bi)
+                if kind == "each":
+                    return (pre[slot][bi[0]], *bi[1:])
+                return bi
+            blk = (pl.squeezed, *block) if kind == "one" else block
+            return pl.BlockSpec(blk, index)
+
+        in_specs = [spec(b, m, kind, slot, next(one_at) if kind == "one" else None)
+                    for (b, m, _), kind, slot in zip(in_blocks, kinds, slots)]
+
+        def body(*args):
+            pre, refs = args[:n_pre], args[n_pre:]
+            if live_axis is None:
+                kernel(*refs)
+                return
+            pl.when(pre[-1][pl.program_id(live_axis)] != 0)(lambda: kernel(*refs))
+
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
-            in_specs=[spec(b, m, next(slot) if s else None)
-                      for (b, m), s in zip(in_blocks, selected)],
+            num_scalar_prefetch=n_pre, grid=grid, in_specs=in_specs,
             out_specs=spec(out_block, out_imap), scratch_shapes=list(scratch))
-        return pl.pallas_call(lambda _sel, *refs: kernel(*refs),
-                              grid_spec=grid_spec, out_shape=out_shape,
+        return pl.pallas_call(body, grid_spec=grid_spec, out_shape=out_shape,
                               interpret=interpret, name=name, **kwargs)
 
+    def live_axis_of(operands, kinds) -> Tuple[Optional[int], object]:
+        axis, live = None, None
+        for (block, _, lead), (_, st), kind in zip(in_blocks, operands, kinds):
+            if kind != "each":
+                continue
+            if lead is None or block[0] != 1:
+                raise ValueError(
+                    f"kernel {name}: an operand selected per block needs its "
+                    f"leading dim on the grid in blocks of 1 (declare it "
+                    f"indexed); got block {tuple(block)}")
+            if st.live is not None and live is None:
+                axis, live = lead, st.live
+        # a dead block repeats its live neighbour's indices, which holds
+        # only where the selected axis is the outermost that varies
+        if axis is not None and any(grid[a] > 1 for a in range(axis)):
+            raise ValueError(
+                f"kernel {name}: live flags need the per-block axis outermost "
+                f"on the grid; it is axis {axis} of grid {tuple(grid)}")
+        return axis, live
+
     def launch(operands: Sequence[Tuple[jnp.ndarray, object]]) -> jnp.ndarray:
-        selected = tuple(s is not None for _, s in operands)
-        if selected not in built:
-            built[selected] = build(selected)
+        kinds = tuple(None if st is None else
+                      ("one" if jnp.ndim(st.index) == 0 else "each")
+                      for _, st in operands)
+        live_axis, live = live_axis_of(operands, kinds)
+        key = (kinds, live_axis)
+        if key not in built:
+            built[key] = build(*key)
         arrays = [a for a, _ in operands]
-        if not any(selected):
-            return built[selected](*arrays)
-        sel = jnp.stack([jnp.asarray(s, jnp.int32).reshape(())
-                         for _, s in operands if s is not None])
-        return built[selected](sel, *arrays)
+        if not any(kinds):
+            return built[key](*arrays)
+        pre = []
+        if "one" in kinds:
+            pre.append(jnp.stack([jnp.asarray(st.index, jnp.int32).reshape(())
+                                  for (_, st), kind in zip(operands, kinds)
+                                  if kind == "one"]))
+        pre += [jnp.asarray(st.index, jnp.int32)
+                for (_, st), kind in zip(operands, kinds) if kind == "each"]
+        if live_axis is not None:
+            flags = jnp.asarray(live) != 0
+            pre += [_live_source(flags), flags.astype(jnp.int32)]
+        return built[key](*pre, *arrays)
 
     return launch
 
@@ -1007,10 +1106,11 @@ def _emit_windowed(plan: WindowedPlan, interpret: bool,
             prep, bshape, imap = _halo_spec(
                 gr, plan.grid_sizes, tuple(buffers[gr.ref.from_buf].shape), gpos)
             preps.append((prep, bshape))
-            in_blocks.append((bshape, imap))
+            in_blocks.append((bshape, imap, None))
         else:
             preps.append((None, gr.block_shape))
-            in_blocks.append((gr.block_shape, _index_map_for(gr, gpos)))
+            in_blocks.append((gr.block_shape, _index_map_for(gr, gpos),
+                              _lead_axis(gr, gpos)))
     out_full_shape = tuple(
         s * (plan.grid_sizes[v] if v else 1)
         for s, v in zip(out_block, plan.out_ref.dim_vars))
@@ -1253,7 +1353,8 @@ def _emit_contraction(plan: ContractionPlan, interpret: bool,
         # tile, verified above), else by the emitter's own analysis
         scratch = [pltpu.VMEM(out_block, acc_dtype)]
     launch = _kernel_launcher(
-        kernel, grid, [(g.block_shape, _index_map_for(g, gpos)) for g in order],
+        kernel, grid,
+        [(g.block_shape, _index_map_for(g, gpos), _lead_axis(g, gpos)) for g in order],
         out_block, _index_map_for(plan.out_ref, gpos),
         jax.ShapeDtypeStruct(out_full_shape, out_dtype), scratch, interpret,
         name, kwargs)
@@ -1294,7 +1395,8 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool,
             + [(out_block, out_full_shape, plan.out_ref.ref)], buffers)
     launch = _kernel_launcher(
         kernel, grid,
-        [(g.block_shape, _index_map_for(g, gpos)) for g in plan.in_refs],
+        [(g.block_shape, _index_map_for(g, gpos), _lead_axis(g, gpos))
+         for g in plan.in_refs],
         out_block, _index_map_for(plan.out_ref, gpos),
         jax.ShapeDtypeStruct(out_full_shape, out_dtype), (), interpret, name,
         kwargs)

@@ -22,7 +22,7 @@ import itertools
 import os
 from typing import Dict, List, Mapping, Optional, Tuple
 
-from ..cost import TileCost, evaluate_tiling
+from ..cost import TileCost, evaluate_tiling, indexed_vars
 from ..hwconfig import HardwareConfig
 from ..ir import Block, Program, dtype_bytes
 from ..poly import factors
@@ -162,7 +162,10 @@ def _search_parallel(block, hw, params, names, cands, workers):
     return best[2], best[3]
 
 
-def choose_tiling(block: Block, hw: HardwareConfig, params: Mapping) -> Tuple[Dict[str, int], TileCost]:
+def choose_tiling(block: Block, hw: HardwareConfig, params: Mapping,
+                  pin: Optional[Mapping[str, int]] = None) -> Tuple[Dict[str, int], TileCost]:
+    """The cheapest feasible tiling of ``block``; ``pin`` fixes the tile
+    of some index variables (``cost.indexed_vars``)."""
     free = {i.name: i.range for i in block.idxs if not i.is_passthrough()}
     if params.get("exact_macs") and "_macs_key" not in params:
         # hash the block once for the whole candidate sweep
@@ -183,6 +186,9 @@ def choose_tiling(block: Block, hw: HardwareConfig, params: Mapping) -> Tuple[Di
             v, m = t.split(":")[1].split("=")
             m = int(m)
             cands[v] = [c for c in cands[v] if c % m == 0] or [m]
+    for v, t in (pin or {}).items():
+        if v in cands:
+            cands[v] = [t]
 
     n_combos = 1
     for v in names:
@@ -261,7 +267,8 @@ def autotile_pass(prog: Program, hw: HardwareConfig, params: Mapping) -> Program
             cost = evaluate_tiling(s, tiles, hw, params)
             oracle.replays += 1
         else:
-            tiles, cost = choose_tiling(s, hw, params)
+            tiles, cost = choose_tiling(s, hw, params,
+                                        pin=indexed_vars(s.refs, prog.buffers))
             if oracle is not None:
                 oracle.searches += 1
         if oracle is not None:

@@ -50,7 +50,8 @@ from __future__ import annotations
 from typing import Dict, List, Mapping, Optional, Sequence, Set, Tuple, Union
 
 from ..affine import Affine, aff
-from ..cost import FusionDecision, fusion_vmem_pressure, canonical_tile, refetch_bytes
+from ..cost import (FusionDecision, canonical_tile, fusion_vmem_pressure, indexed_vars,
+                    refetch_bytes)
 from ..hwconfig import HardwareConfig
 from ..ir import (
     Block,
@@ -449,7 +450,8 @@ def _inline_prologues(prog: Program, hw: HardwareConfig, params: Mapping,
                                            _buf_bytes(prog, q.from_buf))
                 trial = [q for q in c.refs if q.dir != RefDir.NONE and q is not r] + p_in_refs
                 vmem, cap, fits = fusion_vmem_pressure(
-                    trial, anchor_ranges, hw, params, set(out_vars))
+                    trial, anchor_ranges, hw, params, set(out_vars),
+                    indexed_vars(trial, prog.buffers))
                 ok = fits and saved >= added
                 why = "" if ok else (
                     f"arena {vmem}B > cap {cap}B" if not fits
@@ -677,7 +679,8 @@ def _arbitrate(p: Block, members: List[_Member], chain: bool,
                         [t_buf] + [m.out_buf for m in members[:-1]])
         added = added_for(all_ext)
         vmem, cap, fits = fusion_vmem_pressure(
-            base_refs + all_ext, ranges, hw, params, set(out_vars))
+            base_refs + all_ext, ranges, hw, params, set(out_vars),
+            indexed_vars(base_refs + all_ext, prog.buffers))
         ok = fits and saved >= added
         why = "" if ok else (f"arena {vmem}B > cap {cap}B" if not fits
                              else f"refetch {added}B > saved {saved}B")
@@ -698,7 +701,8 @@ def _arbitrate(p: Block, members: List[_Member], chain: bool,
         saved = 2 * _buf_bytes(prog, consumed)
         added = added_for(refs_m)
         vmem, cap, fits = fusion_vmem_pressure(
-            cur_refs + refs_m, ranges, hw, params, set(out_vars))
+            cur_refs + refs_m, ranges, hw, params, set(out_vars),
+            indexed_vars(cur_refs + refs_m, prog.buffers))
         ok = fits and saved >= added
         why = "" if ok else (f"arena {vmem}B > cap {cap}B" if not fits
                              else f"refetch {added}B > saved {saved}B")

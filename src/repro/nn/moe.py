@@ -1,6 +1,8 @@
-"""Mixture-of-Experts layer: top-k routing with capacity-bounded
-scatter/gather dispatch (Switch-style) — expert weights are stacked on a
-leading expert axis so EP shards them over the ``model`` mesh axis."""
+"""Mixture-of-Experts layer for training: top-k routing with
+capacity-bounded scatter/gather dispatch (Switch-style) — expert weights
+are stacked on a leading expert axis so EP shards them over the ``model``
+mesh axis.  The served path (``serving.paged``) drops no token and has
+its own dispatch."""
 from __future__ import annotations
 
 from typing import Any, Dict, Tuple
@@ -13,13 +15,16 @@ from .core import Params, dense_init
 
 
 def moe_init(key, cfg, dtype) -> Params:
+    """The router over all ``n_experts`` (float32) and the weights of the
+    experts the layer holds (``MoECfg.held``), stacked on a leading expert
+    axis."""
     d = cfg.d_model
-    e, f = cfg.moe.n_experts, cfg.moe.d_ff_expert
+    e, f = cfg.moe.held, cfg.moe.d_ff_expert
     ks = jax.random.split(key, 4)
     scale = 1.0 / np.sqrt(d)
     fscale = 1.0 / np.sqrt(f)
     return {
-        "router": dense_init(ks[0], d, e, jnp.float32),
+        "router": dense_init(ks[0], d, cfg.moe.n_experts, jnp.float32),
         "w_gate": (jax.random.normal(ks[1], (e, d, f), jnp.float32) * scale).astype(dtype),
         "w_up": (jax.random.normal(ks[2], (e, d, f), jnp.float32) * scale).astype(dtype),
         "w_down": (jax.random.normal(ks[3], (e, f, d), jnp.float32) * fscale).astype(dtype),
@@ -31,6 +36,11 @@ MOE_EXPERT_MAJOR = True
 
 def moe_apply(p: Params, x: jnp.ndarray, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, D).  Returns (out, aux_loss)."""
+    if cfg.moe.is_share:
+        raise ValueError(
+            f"{cfg.name}: the layer holds {cfg.moe.held} of the "
+            f"{cfg.moe.n_experts} experts it routes over; training needs "
+            f"every expert (train the whole model's config)")
     b, s, d = x.shape
     e, k = cfg.moe.n_experts, cfg.moe.top_k
     t = b * s

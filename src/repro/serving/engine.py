@@ -17,6 +17,12 @@ Architecture (one PR-sized tour; DESIGN.md §9 has the long form):
   via ``stripe_jit`` — fusion grouping, memory planning, per-block
   hybrid backend fallback — with every :class:`CompileRecord` surfaced
   through :meth:`ServingEngine.compile_records`.
+* **Routed experts** (``family == "moe"``): the FFN of each layer is the
+  experts the configuration holds (:mod:`repro.serving.paged`); every
+  decode step and prefill returns its (token, held expert) pairs and the
+  held experts it met beside its tokens, in the same copy back, and the
+  engine adds them to the process-wide ``serve.moe.*`` counters
+  (:mod:`repro.obs.metrics`).
 * **Genuine compile buckets.**  Prefill compiles per power-of-two prompt
   bucket; each bucket's compiled step is a *real entry* in the
   :class:`~repro.core.cache.CompilationCache` keyed by a content hash,
@@ -148,12 +154,13 @@ class ServingEngine:
         config.validate()
         self.model = model
         self.cfg = model.cfg
-        if getattr(self.cfg, "family", "dense") != "dense" or \
+        if getattr(self.cfg, "family", "dense") not in ("dense", "moe") or \
                 getattr(self.cfg, "frontend", "none") != "none":
             raise ValueError(
-                f"ServingEngine serves dense-attention LMs (family='dense', "
-                f"frontend='none'); got family={self.cfg.family!r} "
-                f"frontend={self.cfg.frontend!r}. Use WaveEngine for other families.")
+                f"ServingEngine serves attention LMs with a dense or routed-"
+                f"expert FFN (family 'dense' or 'moe', frontend='none'); got "
+                f"family={self.cfg.family!r} frontend={self.cfg.frontend!r}. "
+                f"Use WaveEngine for other families.")
         self.config = config
         self.slots = config.slots
         self.max_len = config.max_len
@@ -249,6 +256,12 @@ class ServingEngine:
         self._retries_total = 0
         self._warmed = False
         self._decode_warm = False
+        # routed experts: run totals in the process-wide registry, added
+        # from the stats each step returns beside its tokens
+        self._moe_ctr = ({n: obs_metrics.counter(f"serve.moe.{n}") for n in (
+            "decode_steps", "decode_rows", "decode_experts_hit",
+            "prefill_calls", "prefill_rows", "prefill_experts_hit")}
+            if getattr(self.cfg, "moe", None) else None)
         self._h_decode = self._obs.histogram("serve.decode_step_s")
         self._h_prefill = self._obs.histogram("serve.prefill_s")
         self._h_queue = self._obs.histogram("serve.queue_wait_s")
@@ -751,8 +764,11 @@ class ServingEngine:
                     tok, self._pk, self._pv = fn(
                         params, jnp.asarray(prep.tokens), jnp.int32(prep.plen),
                         jnp.asarray(row), self._pk, self._pv)
-                    first = int(tok)
+                    got = np.asarray(tok).reshape(-1)
+                    first = int(got[0])
                 self._h_prefill.observe(time.perf_counter() - t_pf)
+                if self._moe_ctr is not None:
+                    self._count_moe("prefill", got[1:])
                 self._pos[slot] = prep.plen
                 self._last[slot] = first
                 replay = r.replay_len
@@ -781,6 +797,14 @@ class ServingEngine:
                     if first == r.sampling.eos_id or len(r.out_tokens) >= prep.eff_new:
                         self._evict(slot)
             return emitted
+
+    def _count_moe(self, phase: str, stats: np.ndarray) -> None:
+        """Add one step's (pairs, experts hit), copied back with its tokens,
+        to the ``serve.moe.<phase>_*`` counters."""
+        c = self._moe_ctr
+        c["decode_steps" if phase == "decode" else "prefill_calls"].inc()
+        c[f"{phase}_rows"].inc(int(stats[0]))
+        c[f"{phase}_experts_hit"].inc(int(stats[1]))
 
     def _release_slot(self, slot: int) -> None:
         """Return a slot's pages to the pool and reset its decode state;
@@ -897,6 +921,8 @@ class ServingEngine:
                 continue
             self._pk, self._pv = pk, pv
             self._h_decode.observe(time.perf_counter() - t0)
+            if self._moe_ctr is not None:
+                self._count_moe("decode", nxt[self.slots:])
             steps += 1
             self._steps += 1
             self._live_steps += len(live)

@@ -31,6 +31,19 @@ the matmul weights stay whole, stacked ``(n_layers, ...)`` in their
 stored dtype, and reach the programs as ``Stacked(weight, layer)``: the
 kernels read the layer's slice in place and promote it to f32 tile by
 tile, so a step copies no weight.
+
+Routed experts (``cfg.moe``) take the FFN's place: the router's softmax
+over all experts in f32, top-k, the k gate weights renormalised to sum
+to 1; the (token, expert) pairs whose expert this layer holds are sorted
+by expert into a row buffer in blocks of one expert each
+(``stripe_decode.moe_rows``), the grouped program runs the held experts'
+FFN over it, reading each block's expert weights in place from the
+``(n_layers * held, ...)`` stack (``Stacked`` with one index per block),
+and each row, scaled by its gate weight, is added back to its token.  No
+pair is dropped.  Padding tokens (prefill positions past the prompt,
+decode slots with no request, ``pos == 0``) route nowhere.  The MoE
+steps also return, after their tokens, the step's (token, held expert)
+pairs and held experts with a pair, summed over layers.
 """
 from __future__ import annotations
 
@@ -45,7 +58,8 @@ from ..models import lm
 from ..nn.attention import NEG_INF, causal_mask, mha
 from ..reliability import faults
 from ..nn.core import apply_norm, apply_rope, embed_lookup, rms_head_norm
-from .stripe_decode import DecodePrograms, run_attn_out, run_mlp, run_qkv
+from .stripe_decode import (DecodePrograms, moe_rows, run_attn_out, run_mlp, run_moe,
+                            run_qkv)
 
 
 # --------------------------------------------------------------- page pool
@@ -127,7 +141,8 @@ def _mlp_jnp(x2d: jnp.ndarray, resid2d: jnp.ndarray, p, act: str) -> jnp.ndarray
 
 # ----------------------------------------------------------- layer weights
 # per-layer matmul weights, read in place from the stacked parameter
-_MATMUL = {"attn": ("wq", "wk", "wv", "wo"), "mlp": ("w_gate", "w_up", "w_down")}
+_MATMUL = {"attn": ("wq", "wk", "wv", "wo"), "mlp": ("w_gate", "w_up", "w_down"),
+           "moe": ("w_gate", "w_up", "w_down")}
 
 
 def _split_blocks(blocks):
@@ -148,10 +163,89 @@ def _layer_params(mats, rest_i, i, in_place: bool):
     slice for the plain-jnp path."""
     p_i = {g: dict(sub) for g, sub in rest_i.items()}
     for g, sub in mats.items():
+        if g == "moe":  # selected per block of rows (_moe_ffn)
+            continue
         for k, w in sub.items():
             p_i[g][k] = (Stacked(w, i) if in_place else
                          jax.lax.dynamic_index_in_dim(w, i, keepdims=False))
     return p_i
+
+
+# ----------------------------------------------------------- routed experts
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _route(cfg, x2: jnp.ndarray, router: jnp.ndarray, valid: jnp.ndarray):
+    """Gate weights ``(m, k)`` and the held expert of each pick (``held``
+    where the expert is held elsewhere or the token is padding)."""
+    moe = cfg.moe
+    logits = jnp.einsum("md,de->me", x2, router.astype(jnp.float32), precision=_HI)
+    gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), moe.top_k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    local = idx - moe.held_offset
+    keep = (local >= 0) & (local < moe.held) & valid[:, None]
+    return gate, jnp.where(keep, local, moe.held).astype(jnp.int32)
+
+
+def _dispatch(expert: jnp.ndarray, held: int, bm: int, nb: int):
+    """Sort the pairs by expert into ``nb`` blocks of ``bm`` rows, each
+    expert's rows padded to whole blocks.  Returns each pair's row (the
+    sink row ``nb * bm`` for pairs not held), the pair feeding each row,
+    each block's expert and whether it holds a row, and each expert's
+    pair count."""
+    flat = expert.reshape(-1)
+    n_rows = nb * bm
+    onehot = (flat[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]).astype(jnp.int32)
+    counts = jnp.sum(onehot, axis=0)
+    e = jnp.minimum(flat, held - 1)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), e[:, None], axis=1)[:, 0] - 1
+    padded = (counts + bm - 1) // bm * bm
+    ends = jnp.cumsum(padded)
+    row = jnp.where(flat < held, ends[e] - padded[e] + rank, n_rows)
+    pair = jnp.zeros(n_rows + 1, jnp.int32).at[row].set(
+        jnp.arange(flat.shape[0], dtype=jnp.int32))[:n_rows]
+    start = jnp.arange(nb, dtype=jnp.int32) * bm
+    block_expert = jnp.minimum(jnp.searchsorted(ends, start, side="right"), held - 1)
+    return (row.reshape(expert.shape), pair, block_expert.astype(jnp.int32),
+            start < ends[-1], counts)
+
+
+def _experts_jnp(rows, wg, wu, wd, act: str) -> jnp.ndarray:
+    from ..nn.core import _ACT
+
+    f32 = jnp.float32
+    g = jnp.einsum("nrd,ndf->nrf", rows, wg.astype(f32))
+    u = jnp.einsum("nrd,ndf->nrf", rows, wu.astype(f32))
+    return jnp.einsum("nrf,nfd->nrd", _ACT[act.split("_")[0]](g) * u, wd.astype(f32))
+
+
+def _moe_ffn(cfg, progs: Optional[DecodePrograms], x2: jnp.ndarray,
+             resid: jnp.ndarray, experts, router: jnp.ndarray, layer,
+             valid: jnp.ndarray):
+    """Routed experts plus the residual, ``(m, d)`` float32, and the
+    layer's (pairs, experts hit).  ``experts`` holds the whole
+    ``(n_layers, held, ...)`` stacks; each block reads its expert in
+    place through ``Stacked(stack, layer * held + expert, live)``."""
+    held, k = cfg.moe.held, cfg.moe.top_k
+    m = x2.shape[0]
+    bm, nb = moe_rows(cfg, m)
+    x2 = x2.astype(jnp.float32)
+    gate, expert = _route(cfg, x2, router, valid)
+    row, pair, block_expert, live, counts = _dispatch(expert, held, bm, nb)
+    rows = x2[pair // k].reshape(nb, bm, x2.shape[1])
+    w = {n: Stacked(a.reshape((-1,) + a.shape[2:]), layer * held + block_expert, live)
+         for n, a in experts.items()}
+    if progs is not None:
+        y = run_moe(progs, rows, w["w_gate"], w["w_up"], w["w_down"])
+    else:
+        y = _experts_jnp(rows, w["w_gate"].select(), w["w_up"].select(),
+                         w["w_down"].select(), cfg.act)
+    # a block with no row holds nothing defined: only rows of pairs (and
+    # the zero sink row) are read back
+    y = jnp.concatenate([y.reshape(nb * bm, -1), jnp.zeros((1, y.shape[-1]), y.dtype)])
+    out = jnp.einsum("mk,mkd->md", gate, y[row], precision=_HI)
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0)]).astype(jnp.int32)
+    return out + resid.astype(jnp.float32), stats
 
 
 # ------------------------------------------------------------ decode step
@@ -160,7 +254,9 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
 
     Signature: ``fn(params, pages_k, pages_v, page_table, pos, tok) ->
     (next_tok, pages_k, pages_v)`` with ``page_table (S, PPS) int32``,
-    ``pos (S,) int32`` (per-slot lengths), ``tok (S,) int32``.
+    ``pos (S,) int32`` (per-slot lengths; 0 for a slot with no request),
+    ``tok (S,) int32``.  With routed experts ``next_tok`` is ``(S + 2,)``:
+    the tokens, then the step's pairs and experts hit (module docstring).
     """
     ps = int(page_size)
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -235,18 +331,24 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
             x1 = x1.astype(x.dtype)
 
             xn2 = apply_norm(p_i["ln2"], x1[:, None], cfg.norm)
+            pages = (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape))
+            if cfg.moe:
+                y, st = _moe_ffn(cfg, progs, xn2[:, 0], x1, mats["moe"],
+                                 p_i["moe"]["router"], i, pos > 0)
+                return y.astype(x.dtype)[:, None], pages + (st,)
             if progs is not None:
                 y = run_mlp(progs, xn2[:, 0], x1, p_i["mlp"], cfg.act)
             else:
                 y = _mlp_jnp(xn2[:, 0], x1, p_i["mlp"], cfg.act)
-            return (y.astype(x.dtype)[:, None],
-                    (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape)))
+            return y.astype(x.dtype)[:, None], pages
 
-        x, (pages_k, pages_v) = jax.lax.scan(
+        x, (pages_k, pages_v, *st) = jax.lax.scan(
             layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest,
                        pages_k, pages_v))
         logits = lm._logits(params, cfg, x)  # (S, 1, V)
         nxt = jnp.argmax(logits[:, -1, : cfg.vocab], axis=-1).astype(jnp.int32)
+        if st:
+            nxt = jnp.concatenate([nxt, jnp.sum(st[0], axis=0)])
         return nxt, pages_k, pages_v
 
     return step
@@ -259,7 +361,8 @@ def make_prefill_step(cfg, progs: Optional[DecodePrograms], page_size: int,
 
     Signature: ``fn(params, tokens (1, Lb), length (int32 scalar),
     page_row (PPS,) int32, pages_k, pages_v) -> (first_tok scalar,
-    pages_k, pages_v)``.  Tokens are right-padded to the bucket; rows at
+    pages_k, pages_v)``; with routed experts ``first_tok`` is ``(3,)``:
+    the token, then the prefill's pairs and experts hit.  Tokens are right-padded to the bucket; rows at
     positions ``>= length`` scatter junk into the slot's own allocated /
     garbage pages, which attention masks, and which decode overwrites
     in-place before each position ever becomes visible.
@@ -311,19 +414,25 @@ def make_prefill_step(cfg, progs: Optional[DecodePrograms], page_size: int,
                 x1 = _proj(out.reshape(lb, h * hd), ap["wo"]) + x[0].astype(jnp.float32)
             x1 = x1.astype(x.dtype)
             xn2 = apply_norm(p_i["ln2"], x1[None], cfg.norm)
+            pages = (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape))
+            if cfg.moe:
+                y, st = _moe_ffn(cfg, progs, xn2[0], x1, mats["moe"],
+                                 p_i["moe"]["router"], i, t < length)
+                return y.astype(x.dtype)[None], pages + (st,)
             if progs is not None:
                 y = run_mlp(progs, xn2[0], x1, p_i["mlp"], cfg.act)
             else:
                 y = _mlp_jnp(xn2[0], x1, p_i["mlp"], cfg.act)
-            return (y.astype(x.dtype)[None],
-                    (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape)))
+            return y.astype(x.dtype)[None], pages
 
-        x, (pages_k, pages_v) = jax.lax.scan(
+        x, (pages_k, pages_v, *st) = jax.lax.scan(
             layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest,
                        pages_k, pages_v))
         x_last = jax.lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, x.shape[-1]))
         logits = lm._logits(params, cfg, x_last)  # (1, 1, V)
         tok = jnp.argmax(logits[0, 0, : cfg.vocab]).astype(jnp.int32)
+        if st:
+            tok = jnp.concatenate([tok[None], jnp.sum(st[0], axis=0)])
         return tok, pages_k, pages_v
 
     return step

@@ -27,7 +27,14 @@ length for prefill):
   intrinsics (``silu``/``relu``/``relu2`` and their GLU forms); for
   activations whose framework semantics differ from the intrinsic
   (tanh-approximated ``gelu``), the matmuls compile through Stripe and
-  the activation runs outside, recorded in ``act_outside``.
+  the activation runs outside, recorded in ``act_outside``;
+* ``moe``    — in place of ``mlp`` for routed experts: the GLU FFN of the
+  held experts over a buffer of rows sorted by expert, in blocks of
+  ``bm`` rows that each meet one expert (``moe_rows``).  The expert
+  weights are declared ``indexed`` with one row per block and handed as
+  ``Stacked(experts, index per block, live per block)``, so each block
+  reads its expert's weights where they lie and a block with no row
+  computes and fetches nothing.
 
 Operands: activations, residuals and outputs are float32 (matching the
 reference attention path, which upcasts for scores/values), and so is
@@ -43,7 +50,7 @@ program's ``CompileRecord.stored_reads``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional, Union
+from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
 
@@ -93,11 +100,12 @@ class DecodePrograms:
     m: int
     qkv: Callable
     attn_out: Callable
-    mlp: Callable
+    mlp: Optional[Callable]     # None where the FFN is routed experts
     act_outside: Optional[str]  # activation applied outside the program, if any
     records: Dict[str, CompileRecord]
     scores: Optional[Callable] = None  # decode only (needs the KV window T)
     values: Optional[Callable] = None
+    moe: Optional[CompiledProgram] = None  # routed experts, in place of mlp
 
 
 def build_qkv_program(cfg, m: int, jc: EngineLikeConfig) -> CompiledProgram:
@@ -224,6 +232,48 @@ class _SplitMLP:
         return {"mlp_up": self.up.record, "mlp_down": self.down.record}
 
 
+def moe_rows(cfg, m: int) -> Tuple[int, int]:
+    """(rows per block ``bm``, blocks) of the grouped expert program for
+    ``m`` token rows.  A block meets one expert; ``bm`` is the rows an
+    expert meets on average (8 to 128).  The buffer holds every pair of a
+    token and a held expert it picks (at most ``m * min(top_k, held)``)
+    plus one block of padding per held expert, so no token is dropped
+    however the router sends them."""
+    moe = cfg.moe
+    bm, want = 8, -(-m * moe.top_k // moe.n_experts)
+    while bm < min(want, 128):
+        bm *= 2
+    pairs = m * min(moe.top_k, moe.held)
+    return bm, -(-pairs // bm) + moe.held
+
+
+def build_moe_program(cfg, m: int, jc: EngineLikeConfig) -> CompiledProgram:
+    """The held experts' GLU FFN over the sorted row buffer: ``G = X·Wg``,
+    ``U = X·Wu``, ``A = act(G)·U``, ``Y = A·Wd``, one expert per block of
+    ``bm`` rows (``moe_rows``)."""
+    d, f = cfg.d_model, cfg.moe.d_ff_expert
+    base = cfg.act.split("_")[0]
+    if not cfg.act.endswith("_glu") or base not in _FUSABLE_ACT:
+        raise ValueError(f"{cfg.name}: served experts need a fusable GLU "
+                         f"activation, not {cfg.act!r}")
+    bm, nb = moe_rows(cfg, m)
+    tp = TileProgram(f"serve_moe_m{m}")
+    tp.input("X", (nb, bm, d))
+    tp.input("Wg", (nb, d, f), cfg.dtype, indexed=True)
+    tp.input("Wu", (nb, d, f), cfg.dtype, indexed=True)
+    tp.input("Wd", (nb, f, d), cfg.dtype, indexed=True)
+    tp.temp("G", (nb, bm, f))
+    tp.temp("U", (nb, bm, f))
+    tp.temp("A", (nb, bm, f))
+    tp.output("Y", (nb, bm, d))
+    tp.op("G[n, r, f] += X[n, r, d] * Wg[n, d, f]", name="mm_gate")
+    tp.op("U[n, r, f] += X[n, r, d] * Wu[n, d, f]", name="mm_up")
+    gexpr = _FUSABLE_ACT[base].format(x="G[n, r, f]")
+    tp.op(f"A[n, r, f] = {gexpr} * U[n, r, f]", name="glu")
+    tp.op("Y[n, r, d2] += A[n, r, f] * Wd[n, f, d2]", name="mm_down")
+    return stripe_jit(tp.build(), jc.hw, **_jit_opts(jc))
+
+
 def build_scores_program(cfg, m: int, t: int, jc: EngineLikeConfig) -> CompiledProgram:
     kv, hd = cfg.n_kv_heads, cfg.hd
     g = cfg.n_heads // kv
@@ -256,14 +306,19 @@ def build_programs(cfg, m: int, jc: EngineLikeConfig,
     """
     qkv = build_qkv_program(cfg, m, jc)
     attn_out = build_attn_out_program(cfg, m, jc)
-    mlp, act_outside = build_mlp_program(cfg, m, jc)
     records: Dict[str, CompileRecord] = {
         "qkv": qkv.record, "attn_out": attn_out.record,
     }
-    if isinstance(mlp, _SplitMLP):
-        records.update(mlp.records)
+    mlp = moe = act_outside = None
+    if cfg.moe:
+        moe = build_moe_program(cfg, m, jc)
+        records["moe"] = moe.record
     else:
-        records["mlp"] = mlp.record
+        mlp, act_outside = build_mlp_program(cfg, m, jc)
+        if isinstance(mlp, _SplitMLP):
+            records.update(mlp.records)
+        else:
+            records["mlp"] = mlp.record
     scores = values = None
     if kv_window is not None:
         scores = build_scores_program(cfg, m, kv_window, jc)
@@ -272,7 +327,7 @@ def build_programs(cfg, m: int, jc: EngineLikeConfig,
         records["attn_values"] = values.record
     return DecodePrograms(m=m, qkv=qkv, attn_out=attn_out, mlp=mlp,
                           act_outside=act_outside, records=records,
-                          scores=scores, values=values)
+                          scores=scores, values=values, moe=moe)
 
 
 # ------------------------------------------------------------------ apply
@@ -313,3 +368,11 @@ def run_mlp(progs: DecodePrograms, x2d: jnp.ndarray, resid2d: jnp.ndarray, mlp_p
     if glu:
         arrays["Wg"] = mlp_params["w_gate"]
     return mlp(arrays)["Y"]
+
+
+def run_moe(progs: DecodePrograms, rows: jnp.ndarray, wg: Stacked, wu: Stacked,
+            wd: Stacked) -> jnp.ndarray:
+    """The held experts' FFN over the row buffer ``(blocks, bm, d)``; the
+    weights as ``Stacked`` with one expert per block."""
+    return progs.moe({"X": rows.astype(jnp.float32), "Wg": wg, "Wu": wu,
+                      "Wd": wd})["Y"]

@@ -134,3 +134,21 @@ def test_long_context_skip_rules():
             assert "long_500k" in shapes, name
         else:
             assert "long_500k" not in shapes, name
+
+
+@pytest.mark.parametrize("name", configs.shares())
+def test_expert_share_serves_but_refuses_training(name):
+    """A share (one chip's experts of an expert-parallel deployment) keeps
+    its router over every expert and holds only its own experts' weights;
+    the training layer refuses it with a clear error instead of indexing
+    experts that are not there."""
+    cfg = configs.get(name).scaled()
+    assert cfg.moe.is_share and cfg.moe.held < cfg.moe.n_experts
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    moe = params["blocks"]["moe"]
+    assert moe["router"].shape[-1] == cfg.moe.n_experts
+    assert moe["w_gate"].shape[1] == cfg.moe.held
+    batch = make_batch(cfg, "train", 2, 16, seed=1)
+    with pytest.raises(ValueError, match=f"holds {cfg.moe.held} of the {cfg.moe.n_experts}"):
+        m.loss(params, batch)

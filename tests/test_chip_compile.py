@@ -1,5 +1,5 @@
-"""Compile the served qwen3-4b programs for a TPU v5e that is described,
-not attached.
+"""Compile the served qwen3-4b programs, and qwen3-moe-30b-a3b's expert
+share (experts 0-15 of 128), for a TPU v5e that is described, not attached.
 
 Each program is built by ``stripe_jit`` with compiled Pallas kernels
 (``interpret=False``) at the served widths (decode: 8 slots, KV window
@@ -22,6 +22,8 @@ from repro.serving import stripe_decode as sd
 
 QWEN = get_arch("qwen3-4b")
 SLOTS, KV_WINDOW, PREFILL_BUCKET = 8, 1024, 128
+MOE = get_arch("qwen3-moe-30b-a3b-ep8")
+MOE_ROWS = {"decode": 16, "prefill": 256}  # the cell's slots; its largest bucket
 
 
 @pytest.fixture(scope="module")
@@ -154,3 +156,109 @@ def test_served_step_reads_stored_weights_in_place(phase, one_chip, jc):
         operands = line.split("operand_layout_constraints=", 1)[1]
         assert operands.startswith("{s32[1]"), line[:200]
         assert f"bf16[{QWEN.n_layers}," in operands.split("}}", 1)[0], line[:200]
+
+
+def _moe_inputs(prog, cfg, sharding):
+    """The grouped expert program's inputs as the step hands them: the
+    rows f32, each expert stack whole (``layers * held`` experts, bf16)
+    with one expert index and one live flag per block."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.lower_jnp import Stacked
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)  # noqa: E731
+    bufs = prog.program.buffers
+    nb = bufs["X"].shape[0]
+    n = cfg.n_layers * cfg.moe.held
+    out = {"X": sds(bufs["X"].shape, jnp.float32)}
+    for w in ("Wg", "Wu", "Wd"):
+        out[w] = Stacked(sds((n,) + bufs[w].shape[1:], jnp.dtype(cfg.dtype)),
+                         sds((nb,), jnp.int32), sds((nb,), jnp.int32))
+    return out
+
+
+@pytest.mark.parametrize("phase", sorted(MOE_ROWS))
+def test_served_moe_program_compiles_for_v5e(phase, one_chip, jc):
+    """``serve_moe_m<rows>``: every block Pallas, three named kernels, each
+    taking the whole ``(layers * held, ...)`` bf16 expert stack with the
+    per-block expert indices, their sources and live flags in scalar
+    prefetch (so the block axis is outermost on every kernel's grid: live
+    flags on any other axis raise)."""
+    import re
+
+    import jax
+
+    m = MOE_ROWS[phase]
+    prog = sd.build_moe_program(MOE, m, jc)
+    rec = prog.record
+    assert rec.backend == "pallas", rec.fallback_reason
+    assert set(rec.block_backends.values()) == {"pallas"}, rec.block_fallbacks
+    nb = prog.program.buffers["X"].shape[0]
+    text = jax.jit(lambda arrays: prog(arrays)).lower(
+        _moe_inputs(prog, MOE, one_chip)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = {re.sub(r"\.\d+$", "", re.match(r"\s*(?:ROOT )?%?(\S+) = ", c).group(1))
+             for c in calls}
+    assert names == {f"serve_moe_m{m}.{k}" for k in ("mm_gate", "mm_up_glu", "mm_down")}
+    n = MOE.n_layers * MOE.moe.held
+    for line in calls:
+        operands = line.split("operand_layout_constraints=", 1)[1].split("}}", 1)[0]
+        assert operands.startswith(f"{{s32[{nb}]{{0}}, s32[{nb}]{{0}}, s32[{nb}]{{0}}"), line[:200]
+        assert f"bf16[{n}," in operands, line[:200]
+
+
+def _moe_step_hlo(phase, sharding, jc):
+    """Optimized HLO of the whole jitted MoE decode step (16 slots,
+    window 1024) or 256-bucket prefill, parameters as shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.build import build_model
+    from repro.serving.paged import make_decode_step, make_prefill_step
+
+    ps, m = 16, MOE_ROWS[phase]
+    pps = KV_WINDOW // ps
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(build_model(MOE).init, jax.random.PRNGKey(0)))
+    pages = sds((MOE.n_layers, 16 * pps + 16, ps, MOE.n_kv_heads, MOE.hd),
+                jnp.dtype(MOE.dtype))
+    i32 = jnp.int32
+    if phase == "decode":
+        progs = sd.build_programs(MOE, m, jc, kv_window=KV_WINDOW)
+        fn = jax.jit(make_decode_step(MOE, progs, ps))
+        args = (params, pages, pages, sds((m, pps), i32), sds((m,), i32), sds((m,), i32))
+    else:
+        progs = sd.build_programs(MOE, m, jc)
+        fn = jax.jit(make_prefill_step(MOE, progs, ps, m))
+        args = (params, sds((1, m), i32), sds((), i32), sds((pps,), i32), pages, pages)
+    assert {k: r.backend for k, r in progs.records.items()}["moe"] == "pallas"
+    return fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("phase", sorted(MOE_ROWS))
+def test_moe_step_reads_expert_weights_in_place(phase, one_chip, jc):
+    """In the whole compiled MoE step no expert weight is copied: an array
+    of an expert matrix's shape is only ever the parameter itself or a
+    view of it (``bitcast``: the ``(layers, held)`` axes merged), never a
+    convert, slice, gather or copy; and each expert kernel reads the
+    merged stack."""
+    import re
+
+    text = _moe_step_hlo(phase, one_chip, jc)
+    d, f = MOE.d_model, MOE.moe.d_ff_expert
+    shaped = re.compile(rf"= (?:f32|bf16)\[(?:\d+,)*(?:{d},{f}|{f},{d})\]\S* ([a-z-]+)\(")
+    made = [line.strip()[:160] for line in text.splitlines()
+            for mt in [shaped.search(line)]
+            if mt and mt.group(1) not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not made, made
+    n = MOE.n_layers * MOE.moe.held
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r"%serve_moe_m\d+\.", line)]
+    assert len(calls) == 3
+    for line in calls:
+        assert f"bf16[{n}," in line.split("operand_layout_constraints=", 1)[1], line[:200]

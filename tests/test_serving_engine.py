@@ -283,8 +283,10 @@ def test_temperature_not_implemented():
 
 
 def test_non_dense_family_rejected(params):
+    # routed experts (family "moe") are served; a hybrid state-space
+    # family is not yet
     cfg = _tiny_cfg()
-    cfg = cfg.scaled(family="moe")
+    cfg = cfg.scaled(family="hybrid")
     model = build_model(cfg)
     with pytest.raises(ValueError, match="WaveEngine"):
         ServingEngine(model, EngineConfig(slots=2, max_len=32))
