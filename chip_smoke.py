@@ -16,7 +16,11 @@ as read in place (and, for a bf16 configuration, as narrow).  Then each
 of the five decode-step programs is checked on the chip against
 ``jnp.einsum`` in f32 at ``HIGHEST`` precision on seeded inputs at the
 served widths, each weight handed as the engine hands it: stacked in the
-stored dtype, with the index of the slice to read.
+stored dtype, with the index of the slice to read, and the keys and
+values of the two paged attention programs as a page pool with a page
+table and slot lengths, every row past a slot's length NaN.  The share
+of the KV window the decode steps read (``serve.kv.pages_read /
+pages_window``) is printed once.
 
 Four chips: the multi-device compile path alone, i.e. an output-split
 SiLU-GLU FFN at Qwen3-4B MLP widths (all_gather) and a reduction-split
@@ -102,11 +106,14 @@ def _serve_round(api, engine, params, cfg, rng, uid0: int, problems):
 
 
 def _decode_references(progs, cfg, rng):
-    """(name, program, inputs, reference outputs) for the five decode
-    programs, on seeded inputs at the served widths: each weight stacked
-    ``(STACK, ...)`` in its declared (stored) dtype and handed with the
-    index of its last slice, everything else f32.  The reference reads
-    the same slice, cast to f32."""
+    """(name, program, inputs, reference outputs, rows compared) for the
+    five decode programs, on seeded inputs at the served widths: each
+    weight stacked ``(STACK, ...)`` in its declared (stored) dtype and
+    handed with the index of its last slice, the paged keys and values as
+    ``_paged_pool`` makes them, everything else f32.  The reference reads
+    the same slice, or the gathered window with the rows past each
+    slot's length at zero, cast to f32; scores are compared below each
+    slot's length only (past it they are unspecified)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -117,7 +124,7 @@ def _decode_references(progs, cfg, rng):
     hp = jax.lax.Precision.HIGHEST
 
     def ein(spec, a, b):
-        a, b = (v.select() if isinstance(v, Stacked) else v for v in (a, b))
+        a, b = (v.select() if isinstance(v, Stacked) else v for v in (a, b))  # Paged too
         return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32),
                           precision=hp)
 
@@ -138,17 +145,62 @@ def _decode_references(progs, cfg, rng):
     a = inputs(progs.qkv)
     yield "qkv", progs.qkv, a, {"Q": ein("bd,de->be", a["X"], a["WQ"]),
                                 "K": ein("bd,de->be", a["X"], a["WK"]),
-                                "V": ein("bd,de->be", a["X"], a["WV"])}
+                                "V": ein("bd,de->be", a["X"], a["WV"])}, None
     a = inputs(progs.attn_out)
     yield "attn_out", progs.attn_out, a, {
-        "Y": ein("be,ed->bd", a["A"], a["WO"]) + a["R"]}
+        "Y": ein("be,ed->bd", a["A"], a["WO"]) + a["R"]}, None
     a = inputs(progs.mlp)
     h = jax.nn.silu(ein("bd,df->bf", a["X"], a["Wg"])) * ein("bd,df->bf", a["X"], a["Wu"])
-    yield "mlp", progs.mlp, a, {"Y": ein("bf,fd->bd", h, a["Wd"]) + a["R"]}
-    a = inputs(progs.scores)
-    yield "scores", progs.scores, a, {"S": ein("bkgd,bktd->bkgt", a["Q"], a["K"])}
-    a = inputs(progs.values)
-    yield "values", progs.values, a, {"O": ein("bkgt,bktd->bkgd", a["P"], a["V"])}
+    yield "mlp", progs.mlp, a, {"Y": ein("bf,fd->bd", h, a["Wd"]) + a["R"]}, None
+    pool = _paged_pool(progs.paged_scores, rng)
+    a = inputs(progs.paged_scores)
+    a["K"] = pool
+    valid = _live_rows(pool)
+    yield "paged_scores", progs.paged_scores, a, {
+        "S": jnp.where(valid, ein("bkgd,btkd->bkgt", a["Q"], pool), 0.0)}, valid
+    a = inputs(progs.paged_values)
+    a["V"] = pool
+    a["P"] = jnp.where(_live_rows(pool), a["P"], 0.0)
+    yield "paged_values", progs.paged_values, a, {
+        "O": ein("bkgt,btkd->bkgd", a["P"], pool)}, None
+
+
+def _paged_pool(prog, rng):
+    """A ``Paged`` input of a paged program: ``STACK`` layers of a pool
+    of twice the pages the slots can hold, each slot's pages drawn at
+    random, the last layer read.  Slot lengths: 0, 1, one page, a whole
+    window less one row, the rest at random; every row at or past a
+    slot's length is NaN, as a recycled page may hold."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.lower_jnp import Paged
+
+    name = next(n for n in prog.program.inputs if prog.program.buffers[n].paged)
+    decl = prog.program.buffers[name]
+    m, window, row = decl.shape[0], decl.shape[1], decl.shape[2:]
+    page = decl.paged
+    pps = window // page
+    n_pages = 2 * m * pps
+    table = rng.permutation(n_pages)[: m * pps].reshape(m, pps).astype(np.int32)
+    lengths = rng.integers(1, window, m).astype(np.int32)
+    lengths[:4] = (0, 1, page, window - 1)[:m]
+    pool = rng.standard_normal((STACK, n_pages, page) + row, dtype=np.float32)
+    live = np.zeros((n_pages, page), bool)
+    for s in range(m):
+        for t in range(lengths[s]):
+            live[table[s, t // page], t % page] = True
+    pool[-1][~live] = np.nan
+    return Paged(jnp.asarray(pool, decl.dtype), STACK - 1, table=jnp.asarray(table),
+                 lengths=jnp.asarray(lengths))
+
+
+def _live_rows(pool):
+    """``(slots, 1, 1, window)``: the rows below each slot's length."""
+    import jax.numpy as jnp
+
+    window = pool.table.shape[1] * pool.array.shape[2]
+    return (jnp.arange(window)[None, :] < pool.lengths[:, None])[:, None, None, :]
 
 
 def one_chip(args, api, jax, on_tpu: bool, problems) -> None:
@@ -215,10 +267,20 @@ def one_chip(args, api, jax, on_tpu: bool, problems) -> None:
         if ev["event"] in BAD_EVENTS:
             problems.append(f"engine event {ev}")
     engine.close()
+    from repro.obs import metrics as obs_metrics
 
-    for name, prog, inputs, want in _decode_references(
+    read, window = (obs_metrics.counter(f"serve.kv.{n}").value
+                    for n in ("pages_read", "pages_window"))
+    print(f"KV pages read: {read} of a full window's {window} "
+          f"({read / max(window, 1):.3f})")
+    if not 0 < read < window:
+        problems.append(f"KV pages read {read} of {window}")
+
+    for name, prog, inputs, want, rows in _decode_references(
             engine.decode_programs(), cfg, np.random.default_rng(args.seed + 1)):
         got = jax.block_until_ready(prog(inputs))
+        if rows is not None:
+            got = {k: jax.numpy.where(rows, v, 0.0) for k, v in got.items()}
         err = max(_rel_err(got[k], v) for k, v in want.items())
         print(f"program {name}: max error {err:.3e} of the reference's scale "
               f"(tolerance {REL_TOL:g}), backend {prog.record.backend}")

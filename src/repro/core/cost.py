@@ -422,6 +422,87 @@ def indexed_vars(refs, buffers: Mapping) -> Dict[str, int]:
     return pins
 
 
+# Fixed cost of one step of a Pallas kernel's grid on a TPU v5e, and of
+# one block of pages a paged kernel fetches and waits for: DMA issue and
+# wait, pipeline and loop bookkeeping
+GRID_STEP_S = 0.35e-6
+
+
+def _paged_ref(refs, buffers):
+    """(ref, rows per page, slot var) of the first ref of an input kept in
+    a page pool (declared ``paged``) whose slot dim one var addresses."""
+    for r in refs:
+        d = buffers.get(r.from_buf)
+        if d is None or not getattr(d, "paged", 0) or not r.offsets:
+            continue
+        e = r.offsets[0]
+        if len(e.terms) == 1 and e.terms[0][1] == 1:
+            return r, d.paged, e.terms[0][0]
+    return None
+
+
+def paged_vars(refs, buffers: Mapping, ranges: Mapping[str, int]) -> Dict[str, int]:
+    """The tiling of a block that reads an input kept in a page pool (no
+    other is searched): its slot var in tiles of 1 and every other var
+    whole, since the paged kernel walks one slot's live pages itself in
+    blocks it sizes (``paged_block_pages``).  Empty for any other block."""
+    found = _paged_ref(refs, buffers)
+    if found is None:
+        return {}
+    slot = found[2]
+    return {v: 1 if v == slot else r for v, r in ranges.items()}
+
+
+def paged_block_pages(block: Block, buffers: Mapping, hw: HardwareConfig,
+                      params: Mapping) -> int:
+    """Pages a paged kernel fetches and computes on as one block (0 where
+    the block reads no paged input).  A slot of ``L`` live rows takes
+    ``ceil(L / (C * page))`` blocks of ``C`` pages; a block costs a grid
+    step's fixed overhead (``GRID_STEP_S``) plus its pages' DMA and their
+    MXU time (the dense operand's rows padded to the stencil), added: the
+    kernel waits for a block's pages before it computes on them, and only
+    a slot's later blocks hide their fetch, and it computes on every row
+    of a block, live or not.  ``C`` minimizes the mean over live lengths
+    ``1..T``, among the divisors of the pages per slot whose rows fill
+    whole lanes (``tile_align``) or the whole window, with two blocks of
+    pages (double buffering) within the VMEM cap."""
+    found = _paged_ref(block.refs, buffers)
+    if found is None:
+        return 0
+    ref, page, _ = found
+    shape = buffers[ref.from_buf].shape  # (slot, row, ...)
+    free = block.idx_ranges()
+    row_elems = 1
+    for n in shape[2:]:
+        row_elems *= n
+    ref_vars = {n for e in ref.offsets for n in e.names()}
+    out = next(r for r in block.refs if r.dir in (RefDir.OUT, RefDir.INOUT))
+    group = 1
+    for n in {n for e in out.offsets for n in e.names()} - ref_vars:
+        group *= free[n]
+    mult = 128
+    for st in hw.stencils:
+        if st.name == params.get("stencil", "mxu"):
+            mult = st.dims[0]
+    padded = ceil_div(group, mult) * mult
+    page_bytes = page * row_elems * dtype_bytes(ref.dtype)
+    page_s = page_bytes / hw.mem_units[0].bandwidth
+    if hw.peak_flops > 0:
+        page_s += 2.0 * page * row_elems * padded / hw.peak_flops
+    lane = (params.get("tile_align") or (1, 1))[1]
+    cap = hw.inner_mem().size_bytes * params.get("mem_cap_frac", 0.45)
+    pps = max(shape[1] // page, 1)
+    best = None
+    for c in range(1, pps + 1):
+        if pps % c or (c * page % lane and c != pps) or 2 * c * page_bytes > cap:
+            continue
+        blocks = (pps // c + 1) / 2.0  # mean of ceil(L / (c * page))
+        cost = blocks * (GRID_STEP_S + c * page_s)
+        if best is None or cost < best[0]:
+            best = (cost, c)
+    return best[1] if best else 1
+
+
 def canonical_tile(ranges: Mapping[str, int], params: Mapping,
                    clamp_vars=None) -> Dict[str, int]:
     """The tile shape the profitability model prices a group at — fusion

@@ -306,11 +306,12 @@ class TileProgram:
         self.ops: List[Tuple[str, OpSpec]] = []
 
     def input(self, name: str, shape: Sequence[int], dtype: str = "float32",
-              indexed: bool = False) -> str:
+              indexed: bool = False, paged: int = 0) -> str:
         """Declare an input; ``indexed`` marks one whose leading dim is
-        selected per block at call time (``Stacked`` with an index vector,
-        see :class:`~repro.core.ir.TensorDecl`)."""
-        self.decls[name] = TensorDecl(name, tuple(shape), dtype, indexed)
+        selected per block at call time (``Stacked`` with an index vector),
+        ``paged`` (rows per page) one kept in a page pool (``Paged``); see
+        :class:`~repro.core.ir.TensorDecl`."""
+        self.decls[name] = TensorDecl(name, tuple(shape), dtype, indexed, paged)
         self.inputs.append(name)
         return name
 

@@ -274,6 +274,12 @@ class TensorDecl:
     # dim read from the row of ``array`` its index names, so the
     # compiler tiles that dim by 1 (``autotile``)
     indexed: bool = False
+    # rows per page of an input kept in a page pool: its dims are (slot,
+    # row, ...), and the caller hands it as ``Paged(pool, layer, table,
+    # lengths)``, row ``t`` of slot ``b`` lying in page ``table[b, t //
+    # paged]`` of the layer's pool.  Rows at or past ``lengths[b]`` are
+    # not live: the kernels fetch no page of them and they add nothing
+    paged: int = 0
 
     @property
     def rank(self) -> int:
@@ -401,6 +407,7 @@ def canonical_ir(obj: Union[Program, Block]):
     return [
         "program",
         sorted([d.name, list(d.shape), d.dtype] + (["indexed"] if d.indexed else [])
+               + ([f"paged:{d.paged}"] if d.paged else [])
                for d in obj.buffers.values()),
         list(obj.inputs), list(obj.outputs),
         _canon_block(obj.entry),

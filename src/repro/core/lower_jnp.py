@@ -95,6 +95,38 @@ class Stacked:
         return jnp.take(a, idx, axis=0, mode="clip")
 
 
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["array", "index", "live", "table", "lengths"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class Paged(Stacked):
+    """An input kept in a page pool (declared ``paged``), handed in place.
+
+    ``array`` is the whole pool ``(n_layers, n_pages, page, ...)`` and
+    ``index`` the layer; ``table`` ``(slots, pages_per_slot)`` names the
+    physical page of each logical page of a slot, and ``lengths``
+    ``(slots,)`` each slot's live rows.  Row ``t`` of slot ``b`` is
+    ``array[index, table[b, t // page], t % page]``; a page that holds no
+    row below ``lengths[b]`` is not live.  The Pallas kernel fetches only
+    live pages (``lower_pallas._emit_paged``); every other lowering takes
+    :meth:`select`."""
+
+    table: object = None
+    lengths: object = None
+
+    def select(self) -> jnp.ndarray:
+        """The gathered window ``(slots, pages_per_slot * page, ...)``, rows
+        at or past a slot's length set to zero, so they add nothing to a
+        sum whatever the recycled page held."""
+        layer = jax.lax.dynamic_index_in_dim(jnp.asarray(self.array),
+                                             jnp.asarray(self.index), keepdims=False)
+        table = jnp.asarray(self.table)
+        rows = table.shape[1] * layer.shape[1]
+        win = layer[table].reshape((table.shape[0], rows) + layer.shape[2:])
+        live = jnp.arange(rows)[None, :] < jnp.asarray(self.lengths)[:, None]
+        live = live.reshape(live.shape + (1,) * (win.ndim - 2))
+        return jnp.where(live, win, jnp.zeros((), win.dtype))
+
+
 def select_stacked(arrays: Mapping[str, object]) -> Dict[str, object]:
     """``arrays`` with every :class:`Stacked` input replaced by its slice."""
     return {k: v.select() if isinstance(v, Stacked) else v

@@ -62,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import memplan
 from .ir import (Block, Constant, Intrinsic, Load, Program, Refinement,
                  RefDir, Store, TensorDecl)
-from .lower_jnp import _J_BINARY, _J_UNARY, Stacked, _acc_dtype, select_stacked
+from .lower_jnp import _J_BINARY, _J_UNARY, Paged, Stacked, _acc_dtype, select_stacked
 
 MAX_WINDOW_STEPS = 512           # unrolled kernel steps per grid point
 MAX_HALO_BYTES = 256 * 2**20     # materialized (gathered) operand budget
@@ -1411,6 +1411,246 @@ def _emit_elementwise(plan: ElementwisePlan, interpret: bool,
     return fn
 
 
+# --------------------------------------------------------------------------
+# Paged operands: a contraction over rows kept in a page pool
+# --------------------------------------------------------------------------
+@dataclasses.dataclass
+class PagedPlan:
+    """A contraction of a dense operand ``X[b, h, g, x]`` with an input
+    kept in a page pool, ``Y[b, r, h, f]`` (slot, row, head, feature),
+    into ``O[b, h, g, y]``: ``x = f, y = r`` puts the rows on the output
+    (``"rows_out"``, attention scores), ``x = r, y = f`` sums them away
+    (``"rows_summed"``, attention values).  One grid step per slot."""
+
+    n_slots: int
+    paged: GridRef
+    dense: GridRef
+    out_ref: GridRef
+    mode: str
+    page: int                    # rows per page
+    pages: int                   # pages per block (the cost model's choice)
+    row_shape: Tuple[int, ...]   # one row of the pool: (heads, features)
+    dtype: np.dtype              # the pool's, as declared
+    scale: float
+
+
+def _reads_paged(outer: Block, buffers: Optional[Mapping[str, TensorDecl]]) -> bool:
+    return buffers is not None and any(
+        r.dir == RefDir.IN and getattr(buffers.get(r.from_buf), "paged", 0)
+        for r in outer.refs)
+
+
+def extract_paged(outer: Block, buffers: Mapping[str, TensorDecl]) -> PagedPlan:
+    grid_ranges, ins, out, _local, leaf, epilogue = _collect(outer)
+    if epilogue or (out.ref.agg or "assign") != "add":
+        raise UnsupportedPallas("paged operand outside a plain sum of products")
+    paged = [g for g in ins if getattr(buffers.get(g.ref.from_buf), "paged", 0)]
+    if len(ins) != 2 or len(paged) != 1:
+        raise UnsupportedPallas("a paged contraction takes one paged and one dense input")
+    pg = paged[0]
+    dense = next(g for g in ins if g is not pg)
+    sides, scale = _split_sides(_leaf_root(leaf.stmts),
+                                {g.ref.into: (g.dim_vars, g.block_shape) for g in ins})
+    if len(sides) != 2 or any(sd.kind != "load" for sd in sides):
+        raise UnsupportedPallas("paged contraction operands carry a prologue")
+    pn = _dim_names(_leaf_ref(leaf, pg.ref.into), "p")
+    dn = _dim_names(_leaf_ref(leaf, dense.ref.into), "x")
+    on = _dim_names(next(r for r in leaf.refs if r.dir in (RefDir.OUT, RefDir.INOUT)), "o")
+    if not len(pn) == len(dn) == len(on) == 4:
+        raise UnsupportedPallas("paged contraction needs rank-4 operands")
+    slot, row, head, feat = pn
+    if dn[:2] != [slot, head] or on[:3] != dn[:3]:
+        raise UnsupportedPallas(f"paged operand dims {pn} do not lead as (slot, row, "
+                                f"head, feature) against {dn} -> {on}")
+    if (dn[3], on[3]) == (feat, row):
+        mode = "rows_out"
+    elif (dn[3], on[3]) == (row, feat):
+        mode = "rows_summed"
+    else:
+        raise UnsupportedPallas(f"paged contraction {dn} x {pn} -> {on}")
+    slot_var = pg.dim_vars[0]
+    n_slots = grid_ranges[slot_var] if slot_var else 1
+    for g in (pg, dense, out):
+        full = buffers[g.ref.from_buf].shape
+        if (g.block_shape[0] != 1 or g.dim_vars[0] != slot_var or n_slots != full[0]
+                or tuple(g.block_shape[1:]) != tuple(full[1:])
+                or any(v is not None and grid_ranges[v] > 1 for v in g.dim_vars[1:])):
+            raise UnsupportedPallas(
+                f"paged contraction needs one slot per grid step and the rest "
+                f"whole; {g.ref.from_buf} has block {g.block_shape} of {full}")
+    if any(r > 1 for v, r in grid_ranges.items() if v != slot_var):
+        raise UnsupportedPallas("paged contraction with a grid axis besides the slot")
+    decl = buffers[pg.ref.from_buf]
+    page = decl.paged
+    pps = decl.shape[1] // page
+    if decl.shape[1] % page:
+        raise UnsupportedPallas(f"window {decl.shape[1]} is not whole pages of {page}")
+    pages = next((int(t.split(":", 1)[1]) for t in outer.tags
+                  if t.startswith("paged_pages:")), pps)
+    if pps % pages:
+        raise UnsupportedPallas(f"{pages} pages a block do not divide {pps}")
+    heads, dt = decl.shape[2], np.dtype(decl.dtype)
+    if not (dt == np.dtype(jnp.float32) or (dt == np.dtype(jnp.bfloat16) and heads % 2 == 0)):
+        raise UnsupportedPallas(f"paged rows of {heads} heads at {decl.dtype}")
+    return PagedPlan(n_slots=n_slots, paged=pg, dense=dense, out_ref=out, mode=mode,
+                     page=page, pages=pages, row_shape=tuple(decl.shape[2:]),
+                     dtype=dt, scale=scale)
+
+
+def _head_rows(buf2d, head: int, heads: int, rows: int) -> jnp.ndarray:
+    """Rows of one head from a VMEM block of pages viewed as ``(rows *
+    heads, features)``, in float32.  A float32 pool is read with a
+    strided load; a bfloat16 one through its 32-bit words, each holding
+    one row's even and odd head side by side: a bfloat16 in the high half
+    of a 32-bit word is that number as a float32, the value
+    ``astype(float32)`` makes."""
+    if np.dtype(buf2d.dtype).itemsize == 4:
+        return buf2d[pl.ds(head, rows, stride=heads), :].astype(jnp.float32)
+    words = buf2d.bitcast(jnp.uint32)
+    w = (words[pl.ds(head // 2, rows, stride=heads // 2), :] if heads > 2
+         else words[...])
+    w = w << 16 if head % 2 == 0 else w & jnp.uint32(0xFFFF0000)
+    return pltpu.bitcast(w, jnp.float32)
+
+
+def _emit_paged(plan: PagedPlan, interpret: bool, vmem_cap: Optional[int] = None,
+                name: Optional[str] = None) -> Callable:
+    """One kernel, one grid step per slot, the pool left in HBM
+    (``pl.ANY``).  The layer, the page table and the slots' lengths ride
+    in scalar prefetch; each step walks its slot's live pages in blocks
+    of ``plan.pages``, DMAing each block's pages into one of two VMEM
+    buffers while the other is computed on (the next slot's first block
+    is started under this slot's last), and never fetches a page with no
+    live row.  Rows at or past the slot's length are unspecified on the
+    output (``rows_out``) or add exactly nothing (``rows_summed``: they
+    are selected to zero before the dot, so a recycled page's stale or
+    NaN rows cannot reach the sum)."""
+    m, page, c_pages = plan.n_slots, plan.page, plan.pages
+    heads, feat = plan.row_shape[0], int(np.prod(plan.row_shape[1:]))
+    x_block, out_block = plan.dense.block_shape, plan.out_ref.block_shape
+    window = plan.paged.block_shape[1]
+    n_blocks = window // (page * c_pages)
+    w = page * c_pages  # rows a block
+    out_dtype = np.dtype(plan.out_ref.ref.dtype)
+    summed = plan.mode == "rows_summed"
+
+    def kernel(layer_ref, table_ref, len_ref, x_ref, pool_ref, o_ref, buf, sem, state):
+        b = pl.program_id(0)
+        nb = pl.num_programs(0)
+
+        def n_pages(s):
+            return (len_ref[s] + page - 1) // page
+
+        def each_page(s, blk, p, act):
+            """``act`` on the DMA of each live page of block ``blk``."""
+            def page(j, carry):
+                act(pltpu.make_async_copy(
+                    pool_ref.at[layer_ref[0], table_ref[s, blk * c_pages + j]],
+                    buf.at[p, j], sem.at[p]))
+                return carry
+
+            live = jnp.clip(n_pages(s) - blk * c_pages, 0, c_pages)
+            jax.lax.fori_loop(0, live, page, 0)
+
+        @pl.when(b == 0)
+        def _():
+            state[0] = 0
+            state[1] = 0
+
+        p0 = state[0]
+        n = (len_ref[b] + w - 1) // w
+        nxt = jnp.minimum(b + 1, nb - 1)
+        go = (b + 1 < nb) & (len_ref[nxt] > 0)
+
+        @pl.when((state[1] == 0) & (n > 0))
+        def _():
+            each_page(b, 0, p0, lambda cp: cp.start())
+
+        if summed:
+            o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        for blk in range(n_blocks):
+            @pl.when(blk < n)
+            def _():
+                p = (p0 + blk) % 2
+
+                @pl.when(blk + 1 < n)
+                def _():
+                    each_page(b, blk + 1, 1 - p, lambda cp: cp.start())
+
+                @pl.when((blk + 1 == n) & go)
+                def _():
+                    each_page(nxt, 0, 1 - p, lambda cp: cp.start())
+
+                each_page(b, blk, p, lambda cp: cp.wait())
+                rows2d = buf.at[p].reshape(w * heads, feat)
+                lo = blk * w
+                for h in range(heads):
+                    y = _head_rows(rows2d, h, heads, w)
+                    if summed:
+                        live = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0) < len_ref[b] - lo
+                        y = jnp.where(live, y, 0.0)
+                        part = jax.lax.dot_general(
+                            x_ref[0, h, :, lo:lo + w].astype(jnp.float32), y,
+                            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                    else:
+                        part = jax.lax.dot_general(
+                            x_ref[0, h].astype(jnp.float32), y,
+                            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                    if plan.scale != 1.0:
+                        part = part * jnp.float32(plan.scale)
+                    if summed:
+                        o_ref[0, h] += part.astype(o_ref.dtype)
+                    else:
+                        o_ref[0, h, :, lo:lo + w] = part.astype(o_ref.dtype)
+
+        @pl.when((n == 0) & go)
+        def _():
+            each_page(nxt, 0, p0, lambda cp: cp.start())
+
+        state[0] = (p0 + n) % 2
+        state[1] = go.astype(jnp.int32)
+
+    x_bytes = int(np.prod(x_block)) * np.dtype(plan.dense.ref.dtype).itemsize
+    o_bytes = int(np.prod(out_block)) * out_dtype.itemsize
+    need = 2 * w * heads * feat * plan.dtype.itemsize + 2 * (x_bytes + o_bytes) + VMEM_SLACK
+    kwargs = {}
+    if not interpret:
+        if vmem_cap is not None and need > vmem_cap:
+            raise UnsupportedPallas(
+                f"paged kernel needs {need}B of VMEM; a kernel is granted {vmem_cap}B")
+        # the slots run in order: each step starts the next one's DMA
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(need, MOSAIC_DEFAULT_VMEM))
+    spec = lambda blk: pl.BlockSpec(blk, lambda i, *_: (i,) + (0,) * (len(blk) - 1))  # noqa: E731
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(m,),
+            in_specs=[spec(x_block), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=spec(out_block),
+            scratch_shapes=[pltpu.VMEM((2, c_pages, page) + plan.row_shape, plan.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((2,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((m,) + tuple(out_block[1:]), out_dtype),
+        interpret=interpret, name=name, **kwargs)
+
+    def fn(arrays: Mapping[str, object]) -> jnp.ndarray:
+        y = arrays[plan.paged.ref.from_buf]
+        if not isinstance(y, Paged):
+            raise TypeError(f"{name}: input {plan.paged.ref.from_buf} is declared "
+                            f"paged; hand it as Paged(pool, layer, table, lengths)")
+        x = arrays[plan.dense.ref.from_buf]
+        x = x.select() if isinstance(x, Stacked) else jnp.asarray(x)
+        return call(jnp.asarray(y.index, jnp.int32).reshape(1),
+                    jnp.asarray(y.table, jnp.int32), jnp.asarray(y.lengths, jnp.int32),
+                    x, jnp.asarray(y.array))
+
+    fn.out_shape = (m,) + tuple(out_block[1:])
+    fn.out_base = plan.out_ref.base
+    return fn
+
+
 def lower_op_pallas(outer: Block, interpret: bool = False,
                     pipeline_depth: int = 2,
                     buffers: Optional[Mapping[str, TensorDecl]] = None,
@@ -1444,6 +1684,12 @@ def lower_op_pallas(outer: Block, interpret: bool = False,
 
     fn: Optional[Callable] = None
     errors: List[str] = []
+    if _reads_paged(outer, buffers):
+        # only the paged kernel reads a pool in place; a refusal falls
+        # back to the jnp lowering, which gathers the live window
+        fn = _emit_paged(extract_paged(outer, buffers), interpret, vmem_cap, name)
+        fn.out_buf = out_ref.from_buf
+        return fn
 
     def attempt(name: str, build: Callable[[], Callable]) -> None:
         nonlocal fn

@@ -22,7 +22,8 @@ import itertools
 import os
 from typing import Dict, List, Mapping, Optional, Tuple
 
-from ..cost import TileCost, evaluate_tiling, indexed_vars
+from ..cost import (TileCost, evaluate_tiling, indexed_vars, paged_block_pages,
+                    paged_vars)
 from ..hwconfig import HardwareConfig
 from ..ir import Block, Program, dtype_bytes
 from ..poly import factors
@@ -267,12 +268,22 @@ def autotile_pass(prog: Program, hw: HardwareConfig, params: Mapping) -> Program
             cost = evaluate_tiling(s, tiles, hw, params)
             oracle.replays += 1
         else:
-            tiles, cost = choose_tiling(s, hw, params,
-                                        pin=indexed_vars(s.refs, prog.buffers))
+            paged = paged_vars(s.refs, prog.buffers, free)
+            if paged:
+                # one slot a step, the rest whole: the paged kernel sizes
+                # its own VMEM (blocks of pages), so no search
+                tiles, cost = paged, evaluate_tiling(s, paged, hw, params)
+            else:
+                tiles, cost = choose_tiling(s, hw, params,
+                                            pin=indexed_vars(s.refs, prog.buffers))
             if oracle is not None:
                 oracle.searches += 1
         if oracle is not None:
             oracle.record(key, tiles)
+        pages = paged_block_pages(s, prog.buffers, hw, params)
+        if pages:
+            # the paged kernel's block of pages (lower_pallas._emit_paged)
+            s.add_tag(f"paged_pages:{pages}")
         if report is not None:
             # per-block analytic record — cost.score_pass_trace aggregates
             # these into the explore subsystem's predicted-latency axis

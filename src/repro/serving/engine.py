@@ -262,6 +262,12 @@ class ServingEngine:
             "decode_steps", "decode_rows", "decode_experts_hit",
             "prefill_calls", "prefill_rows", "prefill_experts_hit")}
             if getattr(self.cfg, "moe", None) else None)
+        # paged attention: run totals of the KV pages the decode steps read
+        # (live ones, K and V of every layer) and of those a full window
+        # would read, in the process-wide registry
+        self._kv_ctr = ({n: obs_metrics.counter(f"serve.kv.{n}")
+                         for n in ("pages_read", "pages_window")}
+                        if self._decode_progs is not None else None)
         self._h_decode = self._obs.histogram("serve.decode_step_s")
         self._h_prefill = self._obs.histogram("serve.prefill_s")
         self._h_queue = self._obs.histogram("serve.queue_wait_s")
@@ -314,7 +320,8 @@ class ServingEngine:
             built = hit is None
             if built:
                 progs = (build_programs(self.cfg, self.slots, self._jc,
-                                        kv_window=self._kv_window)
+                                        kv_window=self._kv_window,
+                                        page_size=self._ps)
                          if self.config.use_stripe_decode else None)
                 fn = jax.jit(make_decode_step(self.cfg, progs, self._ps))
                 hit = (fn, progs)
@@ -806,6 +813,14 @@ class ServingEngine:
         c[f"{phase}_rows"].inc(int(stats[0]))
         c[f"{phase}_experts_hit"].inc(int(stats[1]))
 
+    def _count_kv_pages(self) -> None:
+        """Add one decode step's KV pages to the ``serve.kv.*`` counters:
+        each slot's pages below its length, and its whole window."""
+        per_page = 2 * self.cfg.n_layers  # K and V of every layer
+        live = int(np.sum(-(-self._pos // self._ps)))
+        self._kv_ctr["pages_read"].inc(per_page * live)
+        self._kv_ctr["pages_window"].inc(per_page * self.slots * self._pps)
+
     def _release_slot(self, slot: int) -> None:
         """Return a slot's pages to the pool and reset its decode state;
         says nothing about the request's fate (callers finish or requeue)."""
@@ -923,6 +938,8 @@ class ServingEngine:
             self._h_decode.observe(time.perf_counter() - t0)
             if self._moe_ctr is not None:
                 self._count_moe("decode", nxt[self.slots:])
+            if self._kv_ctr is not None:
+                self._count_kv_pages()
             steps += 1
             self._steps += 1
             self._live_steps += len(live)

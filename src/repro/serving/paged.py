@@ -4,10 +4,9 @@ steps for dense-attention LMs.
 Layout: the physical KV store is ``(n_layers, n_pages, page_size, kv_heads,
 head_dim)``.  A slot's logical KV window is ``pages_per_slot =
 ceil(max_len / page_size)`` pages, mapped through a ``page_table`` row of
-physical page ids; the logical window length ``T = pages_per_slot *
-page_size`` is what attention sees, with positions ``>= pos`` masked.
-Every shape is static — slots grow and shrink purely by rewriting the
-(tiny, host-side) page table and per-slot ``pos``.
+physical page ids; the logical window length is ``T = pages_per_slot *
+page_size``.  Every shape is static — slots grow and shrink purely by
+rewriting the (tiny, host-side) page table and per-slot ``pos``.
 
 Physical pages ``[0, pool_pages)`` form the shared allocation pool;
 pages ``[pool_pages, pool_pages + slots)`` are per-slot *garbage pages*:
@@ -16,21 +15,36 @@ always-full-batch decode step's KV writes from dead slots land in
 disjoint junk rows (never a scatter collision with a live slot, which
 keeps runs deterministic) and are never read.
 
-Because masked score entries are exact zeros after softmax (the
-``NEG_INF`` shift underflows ``exp`` to 0.0), recycled pages need no
-zeroing: stale values contribute exactly nothing.  Greedy decode through
-the paged path therefore reproduces the dense-cache reference decode
-token-for-token (asserted in tests/test_serving_engine.py).
+Decode attention reads the pool in place.  A slot's history is its rows
+at positions ``< pos``; a page is *live* while it holds one of them, so a
+slot has ``ceil(pos / page_size)`` live pages and an empty slot (``pos ==
+0``) none.  The score and value programs take the step's whole input
+pool as ``Paged(pool, layer, page_table, pos)``: their kernel DMAs a
+slot's live pages, and only those, through the page table into VMEM, at
+the stored dtype, and widens them to f32 after the load
+(``lower_pallas._emit_paged``).  This step's own key and value join as
+one more score column and ``p_new * v_new``.  A score past ``pos`` is
+unspecified and is selected away (``jnp.where``), never multiplied; the
+values kernel selects the rows past ``pos`` to zero before its dot, so a
+skipped page, or a recycled page's stale or NaN rows, add exactly
+nothing.  Recycled pages therefore need no zeroing, and greedy decode
+through the paged path reproduces the dense-cache reference decode
+token-for-token (asserted in tests/test_serving_engine.py and
+tests/test_paged_attention.py).  The layer scan carries no pages: it
+returns each layer's new rows, and after it each slot's rows of every
+layer go into the output pool in one in-place update (the output is a
+copy of the input pool, which the step does not own).
 
 The dense blocks inside these steps route through the Stripe-compiled
 programs of :mod:`repro.serving.stripe_decode` when ``progs`` is given,
-or through equivalent plain-jnp ops when it is None (A/B path).  Both
-compute in float32, matching the reference attention path's upcast.
-The layer loop scans the layer index, the norm scales and the KV pages;
-the matmul weights stay whole, stacked ``(n_layers, ...)`` in their
-stored dtype, and reach the programs as ``Stacked(weight, layer)``: the
-kernels read the layer's slice in place and promote it to f32 tile by
-tile, so a step copies no weight.
+or through equivalent plain-jnp ops when it is None (A/B path, which
+gathers each slot's whole window and masks positions past ``pos``).
+Both compute in float32, matching the reference attention path's upcast.
+The layer loop scans the layer index and the norm scales (prefill's also
+the KV pages); the matmul weights stay whole, stacked ``(n_layers, ...)``
+in their stored dtype, and reach the programs as ``Stacked(weight,
+layer)``: the kernels read the layer's slice in place and promote it to
+f32 tile by tile, so a step copies no weight.
 
 Routed experts (``cfg.moe``) take the FFN's place: the router's softmax
 over all experts in f32, top-k, the k gate weights renormalised to sum
@@ -53,7 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.lower_jnp import Stacked
+from ..core.lower_jnp import Paged, Stacked
 from ..models import lm
 from ..nn.attention import NEG_INF, causal_mask, mha
 from ..reliability import faults
@@ -270,20 +284,14 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
         n_phys = pages_k.shape[1]
         x = embed_lookup(params["embed"], tok[:, None])  # (S, 1, D)
 
-        # flat-row addressing over (n_phys * ps) KV rows
-        gather_rows = (page_table[:, :, None] * ps
-                       + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
-                       ).reshape(s, t_total)
         cur_page = jnp.take_along_axis(page_table, (pos // ps)[:, None], axis=1)[:, 0]
-        write_rows = cur_page * ps + pos % ps  # (S,) — disjoint by construction
         kpos = jnp.arange(t_total, dtype=jnp.int32)
-        valid = kpos[None, :] < (pos + 1)[:, None]  # (S, T)
-        mask = valid[:, None, None, :]  # (S, 1|KV, 1|G, T)
+        history = (kpos[None, :] < pos[:, None])[:, None, None, :]
 
         mats, rest = _split_blocks(params["blocks"])
 
         def layer(x, scanned):
-            i, rest_i, pk, pv = scanned
+            i, rest_i = scanned
             p_i = _layer_params(mats, rest_i, i, progs is not None)
             ap = p_i["attn"]
             xn = apply_norm(p_i["ln1"], x, cfg.norm)
@@ -301,28 +309,40 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
                 k = rms_head_norm(k, ap["k_norm"])
             q = apply_rope(q, pos[:, None], cfg.rope, cfg.rope_theta)
             k = apply_rope(k, pos[:, None], cfg.rope, cfg.rope_theta)
-
-            flat_k = pk.reshape(n_phys * ps, kv, hd).at[write_rows].set(
-                k[:, 0].astype(pk.dtype))
-            flat_v = pv.reshape(n_phys * ps, kv, hd).at[write_rows].set(
-                v[:, 0].astype(pv.dtype))
-            # head-major window (S, KV, T, hd): slot and head lead, as the
-            # batch dims of the score/value contractions
-            ck = flat_k[gather_rows].astype(jnp.float32).transpose(0, 2, 1, 3)
-            cv = flat_v[gather_rows].astype(jnp.float32).transpose(0, 2, 1, 3)
-
+            # this position's rows as the pool stores them
+            k_row = k[:, 0].astype(pages_k.dtype)
+            v_row = v[:, 0].astype(pages_v.dtype)
             qg = q[:, 0].reshape(s, kv, g, hd).astype(jnp.float32)
-            if progs is not None and progs.scores is not None:
-                scores = progs.scores({"Q": qg, "K": ck})["S"]
+            if progs is not None:
+                # history (positions < pos) read in place from the step's
+                # input pool, live pages only; this position's row joins
+                # as one more score column
+                k_new, v_new = k_row.astype(jnp.float32), v_row.astype(jnp.float32)
+                hist_k = Paged(pages_k, i, table=page_table, lengths=pos)
+                hist_v = Paged(pages_v, i, table=page_table, lengths=pos)
+                s_hist = progs.paged_scores({"Q": qg, "K": hist_k})["S"] * sm_scale
+                s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new, precision=_HI) * sm_scale
+                # a row past pos holds no defined score: select, never scale
+                s_hist = jnp.where(history, s_hist, NEG_INF)
+                top = jnp.maximum(jnp.max(s_hist, axis=-1), s_new)
+                e_hist = jnp.where(history, jnp.exp(s_hist - top[..., None]), 0.0)
+                e_new = jnp.exp(s_new - top)
+                total = jnp.sum(e_hist, axis=-1) + e_new
+                o = (progs.paged_values({"P": e_hist / total[..., None], "V": hist_v})["O"]
+                     + (e_new / total)[..., None] * v_new[:, :, None, :])
             else:
-                scores = jnp.einsum("bkgd,bktd->bkgt", qg, ck)
-            scores = scores * sm_scale
-            scores = jnp.where(mask, scores, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1)
-            if progs is not None and progs.values is not None:
-                o = progs.values({"P": probs, "V": cv})["O"]
-            else:
-                o = jnp.einsum("bkgt,bktd->bkgd", probs, cv)
+                # the plain-jnp reference: the layer's whole window with
+                # this row written, gathered head-major (S, KV, T, hd),
+                # positions past pos masked
+                rows = (page_table[:, :, None] * ps
+                        + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(s, t_total)
+                at = cur_page * ps + pos % ps
+                ck, cv = (pool[i].reshape(n_phys * ps, kv, hd).at[at].set(row)[rows]
+                          .astype(jnp.float32).transpose(0, 2, 1, 3)
+                          for pool, row in ((pages_k, k_row), (pages_v, v_row)))
+                scores = jnp.einsum("bkgd,bktd->bkgt", qg, ck) * sm_scale
+                scores = jnp.where(kpos <= pos[:, None, None, None], scores, NEG_INF)
+                o = jnp.einsum("bkgt,bktd->bkgd", jax.nn.softmax(scores, axis=-1), cv)
             a2 = o.reshape(s, h * hd)
             if progs is not None:
                 x1 = run_attn_out(progs, a2, x[:, 0], ap["wo"])
@@ -331,20 +351,24 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
             x1 = x1.astype(x.dtype)
 
             xn2 = apply_norm(p_i["ln2"], x1[:, None], cfg.norm)
-            pages = (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape))
             if cfg.moe:
                 y, st = _moe_ffn(cfg, progs, xn2[:, 0], x1, mats["moe"],
                                  p_i["moe"]["router"], i, pos > 0)
-                return y.astype(x.dtype)[:, None], pages + (st,)
+                return y.astype(x.dtype)[:, None], (k_row, v_row, st)
             if progs is not None:
                 y = run_mlp(progs, xn2[:, 0], x1, p_i["mlp"], cfg.act)
             else:
                 y = _mlp_jnp(xn2[:, 0], x1, p_i["mlp"], cfg.act)
-            return y.astype(x.dtype)[:, None], pages
+            return y.astype(x.dtype)[:, None], (k_row, v_row)
 
-        x, (pages_k, pages_v, *st) = jax.lax.scan(
-            layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest,
-                       pages_k, pages_v))
+        x, (k_rows, v_rows, *st) = jax.lax.scan(
+            layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest))
+        # every layer's new row of each slot written at once, one in-place
+        # update a slot (dead slots into their garbage pages: disjoint)
+        for j in range(s):
+            at = (0, cur_page[j], pos[j] % ps, 0, 0)
+            pages_k = jax.lax.dynamic_update_slice(pages_k, k_rows[:, j, None, None], at)
+            pages_v = jax.lax.dynamic_update_slice(pages_v, v_rows[:, j, None, None], at)
         logits = lm._logits(params, cfg, x)  # (S, 1, V)
         nxt = jnp.argmax(logits[:, -1, : cfg.vocab], axis=-1).astype(jnp.int32)
         if st:

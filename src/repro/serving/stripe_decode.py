@@ -8,19 +8,23 @@ compile leaves a :class:`~repro.core.driver.CompileRecord` (fusion
 groups, kernel counts, per-block backend choices and fallback reasons)
 that the engine surfaces via ``compile_records()``.
 
-Four programs cover one transformer layer at decode time (``m`` = rows
+The programs cover one transformer layer at decode time (``m`` = rows
 flowing through the block: the slot count for decode, the padded bucket
 length for prefill):
 
 * ``qkv``    — the three attention input projections sharing one operand;
-* ``scores`` — the GQA score contraction ``S[b,k,g,t] += Q·K`` over the
-  gathered paged KV (decode only; softmax stays outside — it is not a
-  contraction);
-* ``values`` — the GQA value contraction ``O[b,k,g,d] += P·V``;
+* ``paged_scores`` — the GQA score contraction ``S[b,k,g,t] += Q·K``
+  over a slot's KV history (decode only; softmax stays outside — it is
+  not a contraction);
+* ``paged_values`` — the GQA value contraction ``O[b,k,g,d] += P·V``;
 
-  both take the KV window head-major, ``(b, k, t, d)``, so the slot and
-  KV-head dims lead every operand and become the one batch dim of the
-  TPU matmul;
+  both declare K or V ``paged`` in the pool's own row order ``(b, t, k,
+  d)`` and take it as ``Paged(pool, layer, page_table, lengths)``: the
+  kernel reads each slot's live pages where they lie, at the stored
+  dtype, and nothing past the slot's length (``lower_pallas._emit_paged``;
+  the cost model picks its pages per block).  ``build_scores_program`` /
+  ``build_values_program`` keep the earlier form over a head-major f32
+  window ``(b, k, t, d)``, which decode no longer runs;
 * ``attn_out`` — output projection fused with the residual add;
 * ``mlp``    — the FFN with its activation chain fused between the
   matmuls when the activation is exactly representable as Stripe
@@ -103,8 +107,9 @@ class DecodePrograms:
     mlp: Optional[Callable]     # None where the FFN is routed experts
     act_outside: Optional[str]  # activation applied outside the program, if any
     records: Dict[str, CompileRecord]
-    scores: Optional[Callable] = None  # decode only (needs the KV window T)
-    values: Optional[Callable] = None
+    # decode only: attention over the KV pages in place (window T, page)
+    paged_scores: Optional[CompiledProgram] = None
+    paged_values: Optional[CompiledProgram] = None
     moe: Optional[CompiledProgram] = None  # routed experts, in place of mlp
 
 
@@ -296,13 +301,46 @@ def build_values_program(cfg, m: int, t: int, jc: EngineLikeConfig) -> CompiledP
     return stripe_jit(tp.build(), jc.hw, **_jit_opts(jc))
 
 
+def build_paged_scores_program(cfg, m: int, t: int, page_size: int,
+                               jc: EngineLikeConfig) -> CompiledProgram:
+    """``S[b, k, g, t] += Q[b, k, g, d] * K[b, t, k, d]`` with ``K`` the
+    slots' keys in the page pool (``paged``, at the stored dtype, handed
+    as ``Paged``): only each slot's live pages are read, and the scores of
+    rows past its length are unspecified."""
+    kv, hd = cfg.n_kv_heads, cfg.hd
+    g = cfg.n_heads // kv
+    tp = TileProgram(f"serve_paged_scores_m{m}_t{t}")
+    tp.input("Q", (m, kv, g, hd))
+    tp.input("K", (m, t, kv, hd), cfg.dtype, paged=page_size)
+    tp.output("S", (m, kv, g, t))
+    tp.op("S[b, k, g, t] += Q[b, k, g, d] * K[b, t, k, d]", name="paged_scores")
+    return stripe_jit(tp.build(), jc.hw, **_jit_opts(jc))
+
+
+def build_paged_values_program(cfg, m: int, t: int, page_size: int,
+                               jc: EngineLikeConfig) -> CompiledProgram:
+    """``O[b, k, g, d] += P[b, k, g, t] * V[b, t, k, d]`` with ``V`` in the
+    page pool (``Paged``): rows past a slot's length add nothing, whatever
+    their ``P`` and whatever a recycled page holds there."""
+    kv, hd = cfg.n_kv_heads, cfg.hd
+    g = cfg.n_heads // kv
+    tp = TileProgram(f"serve_paged_values_m{m}_t{t}")
+    tp.input("P", (m, kv, g, t))
+    tp.input("V", (m, t, kv, hd), cfg.dtype, paged=page_size)
+    tp.output("O", (m, kv, g, hd))
+    tp.op("O[b, k, g, d] += P[b, k, g, t] * V[b, t, k, d]", name="paged_values")
+    return stripe_jit(tp.build(), jc.hw, **_jit_opts(jc))
+
+
 def build_programs(cfg, m: int, jc: EngineLikeConfig,
-                   kv_window: Optional[int] = None) -> DecodePrograms:
+                   kv_window: Optional[int] = None,
+                   page_size: Optional[int] = None) -> DecodePrograms:
     """Compile the serving block programs for row count ``m``.
 
-    ``kv_window`` (the logical paged-KV length T) adds the decode-only
-    score/value contractions; prefill callers leave it None (their
-    attention is the causal full-sequence einsum).
+    ``kv_window`` and ``page_size`` (the logical paged-KV length T and
+    the rows per page) add the decode-only paged score/value
+    contractions; prefill callers leave them None (their attention is
+    the causal full-sequence einsum).
     """
     qkv = build_qkv_program(cfg, m, jc)
     attn_out = build_attn_out_program(cfg, m, jc)
@@ -321,13 +359,13 @@ def build_programs(cfg, m: int, jc: EngineLikeConfig,
             records["mlp"] = mlp.record
     scores = values = None
     if kv_window is not None:
-        scores = build_scores_program(cfg, m, kv_window, jc)
-        values = build_values_program(cfg, m, kv_window, jc)
-        records["attn_scores"] = scores.record
-        records["attn_values"] = values.record
+        scores = build_paged_scores_program(cfg, m, kv_window, page_size, jc)
+        values = build_paged_values_program(cfg, m, kv_window, page_size, jc)
+        records["paged_scores"] = scores.record
+        records["paged_values"] = values.record
     return DecodePrograms(m=m, qkv=qkv, attn_out=attn_out, mlp=mlp,
                           act_outside=act_outside, records=records,
-                          scores=scores, values=values, moe=moe)
+                          paged_scores=scores, paged_values=values, moe=moe)
 
 
 # ------------------------------------------------------------------ apply

@@ -21,7 +21,7 @@ from repro.core.hwconfig import get_config
 from repro.serving import stripe_decode as sd
 
 QWEN = get_arch("qwen3-4b")
-SLOTS, KV_WINDOW, PREFILL_BUCKET = 8, 1024, 128
+SLOTS, KV_WINDOW, PREFILL_BUCKET, PAGE = 8, 1024, 128, 16
 MOE = get_arch("qwen3-moe-30b-a3b-ep8")
 MOE_ROWS = {"decode": 16, "prefill": 256}  # the cell's slots; its largest bucket
 
@@ -54,6 +54,10 @@ PROGRAMS = {
     "decode_mlp": lambda jc: sd.build_mlp_program(QWEN, SLOTS, jc)[0],
     "decode_scores": lambda jc: sd.build_scores_program(QWEN, SLOTS, KV_WINDOW, jc),
     "decode_values": lambda jc: sd.build_values_program(QWEN, SLOTS, KV_WINDOW, jc),
+    "decode_paged_scores": lambda jc: sd.build_paged_scores_program(
+        QWEN, SLOTS, KV_WINDOW, PAGE, jc),
+    "decode_paged_values": lambda jc: sd.build_paged_values_program(
+        QWEN, SLOTS, KV_WINDOW, PAGE, jc),
     "prefill_qkv": lambda jc: sd.build_qkv_program(QWEN, PREFILL_BUCKET, jc),
     "prefill_mlp": lambda jc: sd.build_mlp_program(QWEN, PREFILL_BUCKET, jc)[0],
 }
@@ -61,12 +65,26 @@ PROGRAMS = {
 
 def _input_shapes(prog, sharding):
     """Each input at its declared dtype: the matmul weights at the
-    configuration's (bf16), everything else f32."""
+    configuration's (bf16), everything else f32; an input kept in pages as
+    the decode step hands it, ``Paged`` over a whole 36-layer pool."""
     import jax
+    import jax.numpy as jnp
 
-    return {n: jax.ShapeDtypeStruct(prog.program.buffers[n].shape,
-                                    prog.program.buffers[n].dtype, sharding=sharding)
-            for n in prog.program.inputs}
+    from repro.core.lower_jnp import Paged
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)  # noqa: E731
+    out = {}
+    for n in prog.program.inputs:
+        decl = prog.program.buffers[n]
+        if decl.paged:
+            slots, pps = decl.shape[0], decl.shape[1] // decl.paged
+            out[n] = Paged(sds((QWEN.n_layers, slots * pps + slots, decl.paged)
+                               + decl.shape[2:], decl.dtype), sds((), jnp.int32),
+                           table=sds((slots, pps), jnp.int32),
+                           lengths=sds((slots,), jnp.int32))
+        else:
+            out[n] = sds(decl.shape, decl.dtype)
+    return out
 
 
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
@@ -102,6 +120,21 @@ def test_served_kernels_carry_block_names(name, one_chip, jc):
     assert len(ops) == prog.record.n_kernels
 
 
+_HLO = {}
+
+
+def _cached(fn):
+    """One compile of each whole step per test process: several tests
+    read the same optimized HLO."""
+    def get(phase, sharding, jc):
+        key = (fn.__name__, phase)
+        if key not in _HLO:
+            _HLO[key] = fn(phase, sharding, jc)
+        return _HLO[key]
+    return get
+
+
+@_cached
 def _step_hlo(phase, sharding, jc):
     """Optimized HLO of the whole jitted qwen3-4b decode step (8 slots,
     window 1024) or 128-bucket prefill, parameters as shapes."""
@@ -111,7 +144,7 @@ def _step_hlo(phase, sharding, jc):
     from repro.models.build import build_model
     from repro.serving.paged import make_decode_step, make_prefill_step
 
-    ps = 16
+    ps = PAGE
     pps = KV_WINDOW // ps
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)  # noqa: E731
     params = jax.tree_util.tree_map(
@@ -121,7 +154,7 @@ def _step_hlo(phase, sharding, jc):
                 jnp.dtype(QWEN.dtype))
     i32 = jnp.int32
     if phase == "decode":
-        progs = sd.build_programs(QWEN, SLOTS, jc, kv_window=KV_WINDOW)
+        progs = sd.build_programs(QWEN, SLOTS, jc, kv_window=KV_WINDOW, page_size=ps)
         fn = jax.jit(make_decode_step(QWEN, progs, ps))
         args = (params, pages, pages, sds((SLOTS, pps), i32), sds((SLOTS,), i32),
                 sds((SLOTS,), i32))
@@ -209,6 +242,7 @@ def test_served_moe_program_compiles_for_v5e(phase, one_chip, jc):
         assert f"bf16[{n}," in operands, line[:200]
 
 
+@_cached
 def _moe_step_hlo(phase, sharding, jc):
     """Optimized HLO of the whole jitted MoE decode step (16 slots,
     window 1024) or 256-bucket prefill, parameters as shapes."""
@@ -218,7 +252,7 @@ def _moe_step_hlo(phase, sharding, jc):
     from repro.models.build import build_model
     from repro.serving.paged import make_decode_step, make_prefill_step
 
-    ps, m = 16, MOE_ROWS[phase]
+    ps, m = PAGE, MOE_ROWS[phase]
     pps = KV_WINDOW // ps
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)  # noqa: E731
     params = jax.tree_util.tree_map(
@@ -228,7 +262,7 @@ def _moe_step_hlo(phase, sharding, jc):
                 jnp.dtype(MOE.dtype))
     i32 = jnp.int32
     if phase == "decode":
-        progs = sd.build_programs(MOE, m, jc, kv_window=KV_WINDOW)
+        progs = sd.build_programs(MOE, m, jc, kv_window=KV_WINDOW, page_size=ps)
         fn = jax.jit(make_decode_step(MOE, progs, ps))
         args = (params, pages, pages, sds((m, pps), i32), sds((m,), i32), sds((m,), i32))
     else:
@@ -262,3 +296,33 @@ def test_moe_step_reads_expert_weights_in_place(phase, one_chip, jc):
     assert len(calls) == 3
     for line in calls:
         assert f"bf16[{n}," in line.split("operand_layout_constraints=", 1)[1], line[:200]
+
+
+@pytest.mark.parametrize("model", ["qwen3-4b", "qwen3-moe-30b-a3b"])
+def test_decode_step_reads_kv_pages_in_place(model, one_chip, jc):
+    """In the whole compiled decode step no KV window is made: no array of
+    the window's rows, in any dtype or order (the gather, the f32 convert
+    and the head-major transpose are gone), and each paged attention
+    kernel takes the step's whole bf16 page pool, with the layer, the
+    page table and the slots' lengths in scalar prefetch."""
+    import re
+
+    cfg, slots = (QWEN, SLOTS) if model == "qwen3-4b" else (MOE, MOE_ROWS["decode"])
+    text = (_step_hlo if model == "qwen3-4b" else _moe_step_hlo)("decode", one_chip, jc)
+    kv, hd, pps = cfg.n_kv_heads, cfg.hd, KV_WINDOW // PAGE
+    window = {(slots * KV_WINDOW, kv, hd), (slots, KV_WINDOW, kv, hd),
+              (slots, kv, KV_WINDOW, hd)}
+    made = [line.strip()[:160] for line in text.splitlines()
+            for mt in [re.search(r"= (?:f32|bf16)\[([\d,]+)\]", line)]
+            if mt and tuple(int(n) for n in mt.group(1).split(",")) in window]
+    assert not made, made
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r"%serve_paged_(scores|values)_m\d+_t\d+\.", line)]
+    assert len(calls) == 2
+    pool = f"bf16[{cfg.n_layers},{slots * pps + slots},{PAGE},{kv},{hd}]"
+    for line in calls:
+        operands = line.split("operand_layout_constraints=", 1)[1]
+        assert operands.startswith(f"{{s32[1]{{0}}, s32[{slots},{pps}]{{1,0}}, "
+                                   f"s32[{slots}]{{0}}"), line[:200]
+        assert pool in operands.split("}}", 1)[0], line[:200]
