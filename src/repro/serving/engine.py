@@ -105,7 +105,8 @@ from ..core.hwconfig import get_config as _get_hw
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from ..reliability import faults
-from .paged import PagePool, init_pages, make_decode_step, make_prefill_step, pages_needed
+from .paged import (PagePool, PlainBlocks, init_pages, make_decode_step, make_prefill_step,
+                    pages_needed)
 from .request import EngineConfig, Request, SamplingParams
 from .stripe_decode import EngineLikeConfig, build_programs
 from .wave import WaveEngine  # re-exported: the legacy engine lives on as the baseline
@@ -204,7 +205,7 @@ class ServingEngine:
         self._model_fp = stripe_cache.stable_hash(dataclasses.asdict(self.cfg))
         self._manifest_key = stripe_cache.content_key(
             "serve_manifest", self._model_fp, self._ps, self._pps,
-            config.backend, config.use_stripe_decode)
+            config.backend)
         self._records: Dict[str, CompileRecord] = {}
         self._compile_log: List[Dict[str, Any]] = []
         self._pending_tuned: List[Dict[str, Any]] = []
@@ -265,9 +266,8 @@ class ServingEngine:
         # paged attention: run totals of the KV pages the decode steps read
         # (live ones, K and V of every layer) and of those a full window
         # would read, in the process-wide registry
-        self._kv_ctr = ({n: obs_metrics.counter(f"serve.kv.{n}")
-                         for n in ("pages_read", "pages_window")}
-                        if self._decode_progs is not None else None)
+        self._kv_ctr = {n: obs_metrics.counter(f"serve.kv.{n}")
+                        for n in ("pages_read", "pages_window")}
         self._h_decode = self._obs.histogram("serve.decode_step_s")
         self._h_prefill = self._obs.histogram("serve.prefill_s")
         self._h_queue = self._obs.histogram("serve.queue_wait_s")
@@ -311,7 +311,6 @@ class ServingEngine:
         key = stripe_cache.content_key(
             "serve_decode", self._model_fp, self.slots, self._ps, self._pps,
             self.config.backend, self.config.interpret,
-            self.config.use_stripe_decode,
             # tuned replays lower different tilings, so a tuned bucket
             # never aliases an untuned one in a shared live cache
             self.config.tune)
@@ -319,10 +318,8 @@ class ServingEngine:
             hit = self._compile_cache.get_memory(key)
             built = hit is None
             if built:
-                progs = (build_programs(self.cfg, self.slots, self._jc,
-                                        kv_window=self._kv_window,
-                                        page_size=self._ps)
-                         if self.config.use_stripe_decode else None)
+                progs = build_programs(self.cfg, self.slots, self._jc,
+                                       kv_window=self._kv_window, page_size=self._ps)
                 fn = jax.jit(make_decode_step(self.cfg, progs, self._ps))
                 hit = (fn, progs)
                 self._compile_cache.put_memory(key, hit)
@@ -330,12 +327,10 @@ class ServingEngine:
             self._compile_log.append({
                 "kind": "decode_programs", "slots": self.slots,
                 "kv_window": self._kv_window, "first_call_s": sp.dur})
-            if progs is not None:
-                self._note_tuned("decode", progs.records)
+            self._note_tuned("decode", progs.records)
         self._decode_fn, self._decode_progs = hit
-        if self._decode_progs is not None:
-            self._records.update(
-                {f"decode/{k}": v for k, v in self._decode_progs.records.items()})
+        self._records.update(
+            {f"decode/{k}": v for k, v in self._decode_progs.records.items()})
 
     def _note_tuned(self, kind: str, records) -> None:
         """Emit one ``tuned_replay`` event per freshly-compiled program
@@ -359,8 +354,7 @@ class ServingEngine:
     def _prefill_key(self, bucket: int) -> str:
         return stripe_cache.content_key(
             "serve_prefill", self._model_fp, self._ps, self._pps, bucket,
-            self.config.backend, self.config.interpret,
-            self.config.use_stripe_decode, self.config.tune)
+            self.config.backend, self.config.interpret, self.config.tune)
 
     def _get_prefill(self, bucket: int, params, warm: bool = False):
         """Fetch-or-compile the prefill step for one prompt bucket.
@@ -405,8 +399,7 @@ class ServingEngine:
             return fn, None
         try:
             faults.check("serve.prefill_compile", bucket=bucket)
-            progs = (build_programs(self.cfg, bucket, self._jc)
-                     if self.config.use_stripe_decode else None)
+            progs = build_programs(self.cfg, bucket, self._jc)
             fn = jax.jit(make_prefill_step(self.cfg, progs, self._ps, bucket))
             # trace + compile now (dummy call into the slot-0 garbage page,
             # result discarded) so the admission that triggered this pays the
@@ -421,10 +414,9 @@ class ServingEngine:
                         fail_count=qe.fail_count,
                         backoff_s=round(qe.backoff_s, 4))
             return self._prefill_fallback(bucket, params)
-        if progs is not None:
-            self._records.update(
-                {f"prefill_L{bucket}/{k}": v for k, v in progs.records.items()})
-            self._note_tuned(f"prefill_L{bucket}", progs.records)
+        self._records.update(
+            {f"prefill_L{bucket}/{k}": v for k, v in progs.records.items()})
+        self._note_tuned(f"prefill_L{bucket}", progs.records)
         if entry is not None:
             # post-embargo retry succeeded: the bucket is healthy again
             self._quarantine.clear(key)
@@ -445,7 +437,8 @@ class ServingEngine:
         fn = self._compile_cache.get_memory(fkey)
         if fn is not None:
             return fn, None
-        fn = jax.jit(make_prefill_step(self.cfg, None, self._ps, bucket))
+        fn = jax.jit(make_prefill_step(self.cfg, PlainBlocks(self.cfg.act), self._ps,
+                                       bucket))
         row = np.full(self._pps, self._garbage[0], np.int32)
         out = fn(params, jnp.zeros((1, bucket), jnp.int32), jnp.int32(1),
                  jnp.asarray(row), self._pk, self._pv)
@@ -938,8 +931,7 @@ class ServingEngine:
             self._h_decode.observe(time.perf_counter() - t0)
             if self._moe_ctr is not None:
                 self._count_moe("decode", nxt[self.slots:])
-            if self._kv_ctr is not None:
-                self._count_kv_pages()
+            self._count_kv_pages()
             steps += 1
             self._steps += 1
             self._live_steps += len(live)
@@ -1011,8 +1003,7 @@ class ServingEngine:
 
     def decode_programs(self):
         """The decode step's Stripe-compiled block programs
-        (:class:`~repro.serving.stripe_decode.DecodePrograms`), or None
-        when ``use_stripe_decode`` is off."""
+        (:class:`~repro.serving.stripe_decode.DecodePrograms`)."""
         return self._decode_progs
 
     def events(self) -> List[Dict[str, Any]]:

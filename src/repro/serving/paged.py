@@ -35,16 +35,20 @@ returns each layer's new rows, and after it each slot's rows of every
 layer go into the output pool in one in-place update (the output is a
 copy of the input pool, which the step does not own).
 
-The dense blocks inside these steps route through the Stripe-compiled
-programs of :mod:`repro.serving.stripe_decode` when ``progs`` is given,
-or through equivalent plain-jnp ops when it is None (A/B path, which
-gathers each slot's whole window and masks positions past ``pos``).
-Both compute in float32, matching the reference attention path's upcast.
-The layer loop scans the layer index and the norm scales (prefill's also
-the KV pages); the matmul weights stay whole, stacked ``(n_layers, ...)``
-in their stored dtype, and reach the programs as ``Stacked(weight,
-layer)``: the kernels read the layer's slice in place and promote it to
-f32 tile by tile, so a step copies no weight.
+Both steps run one layer body (``_run_layers``) over the ``(batch,
+rows)`` layout, decode's ``(S, 1)`` and prefill's ``(1, Lb)``; they
+differ only in their attention and in where the new KV rows go (decode:
+into the output pool after the layer scan; prefill: into the layer's
+pages inside it).  The body calls its blocks through one interface
+(``run_qkv``, ``run_attn_out``, ``run_mlp``, ``run_experts``), which the
+Stripe-compiled programs of :mod:`repro.serving.stripe_decode` offer and,
+in plain jnp, :class:`PlainBlocks` (the prefill of a bucket whose compile
+is quarantined).  Both compute in float32, matching the reference
+attention path's upcast.  The layer loop scans the layer index and the
+norm scales (prefill's also the KV pages); the matmul weights stay whole,
+stacked ``(n_layers, ...)`` in their stored dtype, and reach the blocks as
+``Stacked(weight, layer)``: the kernels read the layer's slice in place
+and promote it to f32 tile by tile, so a step copies no weight.
 
 Routed experts (``cfg.moe``) take the FFN's place: the router's softmax
 over all experts in f32, top-k, the k gate weights renormalised to sum
@@ -61,7 +65,7 @@ pairs and held experts with a pair, summed over layers.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -70,10 +74,9 @@ import numpy as np
 from ..core.lower_jnp import Paged, Stacked
 from ..models import lm
 from ..nn.attention import NEG_INF, causal_mask, mha
+from ..nn.core import _ACT, apply_norm, apply_rope, embed_lookup, rms_head_norm
 from ..reliability import faults
-from ..nn.core import apply_norm, apply_rope, embed_lookup, rms_head_norm
-from .stripe_decode import (DecodePrograms, moe_rows, run_attn_out, run_mlp, run_moe,
-                            run_qkv)
+from .stripe_decode import DecodePrograms, Weight, moe_rows
 
 
 # --------------------------------------------------------------- page pool
@@ -137,20 +140,45 @@ def init_pages(cfg, total_pages: int, page_size: int) -> Tuple[jnp.ndarray, jnp.
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
-# ----------------------------------------------------------- jnp fallback
-def _proj(x2d: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    return jnp.einsum("bd,de->be", x2d.astype(jnp.float32), w.astype(jnp.float32))
+# ----------------------------------------------------------- plain blocks
+def _f32(w: Weight) -> jnp.ndarray:
+    return (w.select() if isinstance(w, Stacked) else w).astype(jnp.float32)
 
 
-def _mlp_jnp(x2d: jnp.ndarray, resid2d: jnp.ndarray, p, act: str) -> jnp.ndarray:
-    from ..nn.core import _ACT
+def _proj(x2d: jnp.ndarray, w: Weight) -> jnp.ndarray:
+    return jnp.einsum("bd,de->be", x2d.astype(jnp.float32), _f32(w))
 
-    x2d = x2d.astype(jnp.float32)
-    if act.endswith("_glu"):
-        a = _ACT[act.split("_")[0]](_proj(x2d, p["w_gate"])) * _proj(x2d, p["w_up"])
-    else:
-        a = _ACT[act](_proj(x2d, p["w_up"]))
-    return _proj(a, p["w_down"]) + resid2d.astype(jnp.float32)
+
+class PlainBlocks:
+    """The layer's blocks in plain jnp, called as the programs are
+    (:class:`~repro.serving.stripe_decode.DecodePrograms`): each weight,
+    as stored, is selected and cast to f32 outside the dot.  A prefill
+    bucket whose compile is quarantined serves through these."""
+
+    def __init__(self, act: str):
+        self.act = act
+
+    def run_qkv(self, x2d, wq: Weight, wk: Weight, wv: Weight):
+        return _proj(x2d, wq), _proj(x2d, wk), _proj(x2d, wv)
+
+    def run_attn_out(self, attn2d, resid2d, wo: Weight):
+        return _proj(attn2d, wo) + resid2d.astype(jnp.float32)
+
+    def run_mlp(self, x2d, resid2d, p):
+        x2d = x2d.astype(jnp.float32)
+        if "w_gate" in p:
+            a = _ACT[self.act.split("_")[0]](_proj(x2d, p["w_gate"])) * _proj(x2d, p["w_up"])
+        else:
+            a = _ACT[self.act](_proj(x2d, p["w_up"]))
+        return _proj(a, p["w_down"]) + resid2d.astype(jnp.float32)
+
+    def run_experts(self, rows, wg: Stacked, wu: Stacked, wd: Stacked):
+        g = jnp.einsum("nrd,ndf->nrf", rows, _f32(wg))
+        u = jnp.einsum("nrd,ndf->nrf", rows, _f32(wu))
+        return jnp.einsum("nrf,nfd->nrd", _ACT[self.act.split("_")[0]](g) * u, _f32(wd))
+
+
+Blocks = Union[DecodePrograms, PlainBlocks]
 
 
 # ----------------------------------------------------------- layer weights
@@ -171,17 +199,15 @@ def _split_blocks(blocks):
     return mats, rest
 
 
-def _layer_params(mats, rest_i, i, in_place: bool):
+def _layer_params(mats, rest_i, i):
     """Layer ``i``'s parameter tree: ``rest_i`` (the scanned slices) with
-    each matmul weight as ``Stacked(weight, i)`` for the programs, or its
-    slice for the plain-jnp path."""
+    each matmul weight as ``Stacked(weight, i)``."""
     p_i = {g: dict(sub) for g, sub in rest_i.items()}
     for g, sub in mats.items():
         if g == "moe":  # selected per block of rows (_moe_ffn)
             continue
         for k, w in sub.items():
-            p_i[g][k] = (Stacked(w, i) if in_place else
-                         jax.lax.dynamic_index_in_dim(w, i, keepdims=False))
+            p_i[g][k] = Stacked(w, i)
     return p_i
 
 
@@ -224,18 +250,8 @@ def _dispatch(expert: jnp.ndarray, held: int, bm: int, nb: int):
             start < ends[-1], counts)
 
 
-def _experts_jnp(rows, wg, wu, wd, act: str) -> jnp.ndarray:
-    from ..nn.core import _ACT
-
-    f32 = jnp.float32
-    g = jnp.einsum("nrd,ndf->nrf", rows, wg.astype(f32))
-    u = jnp.einsum("nrd,ndf->nrf", rows, wu.astype(f32))
-    return jnp.einsum("nrf,nfd->nrd", _ACT[act.split("_")[0]](g) * u, wd.astype(f32))
-
-
-def _moe_ffn(cfg, progs: Optional[DecodePrograms], x2: jnp.ndarray,
-             resid: jnp.ndarray, experts, router: jnp.ndarray, layer,
-             valid: jnp.ndarray):
+def _moe_ffn(cfg, blocks: Blocks, x2: jnp.ndarray, resid: jnp.ndarray, experts,
+             router: jnp.ndarray, layer, valid: jnp.ndarray):
     """Routed experts plus the residual, ``(m, d)`` float32, and the
     layer's (pairs, experts hit).  ``experts`` holds the whole
     ``(n_layers, held, ...)`` stacks; each block reads its expert in
@@ -249,11 +265,7 @@ def _moe_ffn(cfg, progs: Optional[DecodePrograms], x2: jnp.ndarray,
     rows = x2[pair // k].reshape(nb, bm, x2.shape[1])
     w = {n: Stacked(a.reshape((-1,) + a.shape[2:]), layer * held + block_expert, live)
          for n, a in experts.items()}
-    if progs is not None:
-        y = run_moe(progs, rows, w["w_gate"], w["w_up"], w["w_down"])
-    else:
-        y = _experts_jnp(rows, w["w_gate"].select(), w["w_up"].select(),
-                         w["w_down"].select(), cfg.act)
+    y = blocks.run_experts(rows, w["w_gate"], w["w_up"], w["w_down"])
     # a block with no row holds nothing defined: only rows of pairs (and
     # the zero sink row) are read back
     y = jnp.concatenate([y.reshape(nb * bm, -1), jnp.zeros((1, y.shape[-1]), y.dtype)])
@@ -262,8 +274,65 @@ def _moe_ffn(cfg, progs: Optional[DecodePrograms], x2: jnp.ndarray,
     return out + resid.astype(jnp.float32), stats
 
 
+# ------------------------------------------------------------- the layers
+def _run_layers(cfg, blocks: Blocks, params, x, positions, valid, attend, per_layer=()):
+    """The served transformer layers over ``x`` ``(batch, rows, d)``,
+    decode's ``(S, 1)`` or prefill's ``(1, Lb)``: norm, qkv, qk-norm, rope
+    at ``positions`` ``(batch * rows,)``, attention, ``attn_out`` plus the
+    residual, norm, then the MLP or the routed experts plus the residual,
+    every block through ``blocks``.
+
+    ``attend(i, q, k, v, *per_layer_i)`` is the step's own attention: it
+    returns the attention rows ``(batch * rows, heads * hd)`` and what the
+    step keeps of layer ``i`` (its new KV rows, or its pages).
+    ``per_layer`` are arrays with a leading layer axis, scanned beside the
+    layers and handed to ``attend``.  ``valid()`` gives the rows ``(batch *
+    rows,)`` that route to experts, computed in the layer beside the
+    routing that reads it.  Returns ``x``, the stacked kept values, and
+    each layer's (pairs, experts hit), or None without routed experts."""
+    b, r = x.shape[:2]
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    mats, rest = _split_blocks(params["blocks"])
+    # the blocks take rows (batch * rows, d): index away the axis of size
+    # one (decode's rows, prefill's batch), and put it back
+    unit = (slice(None),) * (0 if b == 1 else 1)
+    flat = lambda a: a[unit + (0,)]  # noqa: E731
+    wide = lambda a: a[unit + (None,)]  # noqa: E731
+
+    def layer(x, scanned):
+        i, rest_i, *mine = scanned
+        p = _layer_params(mats, rest_i, i)
+        ap = p["attn"]
+        xn = apply_norm(p["ln1"], x, cfg.norm)
+        q2, k2, v2 = blocks.run_qkv(flat(xn), ap["wq"], ap["wk"], ap["wv"])
+        q = q2.reshape(b, r, h, hd)
+        k = k2.reshape(b, r, kv, hd)
+        v = v2.reshape(b, r, kv, hd)
+        if cfg.qk_norm:
+            q = rms_head_norm(q, ap["q_norm"])
+            k = rms_head_norm(k, ap["k_norm"])
+        q = apply_rope(q, wide(positions), cfg.rope, cfg.rope_theta)
+        k = apply_rope(k, wide(positions), cfg.rope, cfg.rope_theta)
+        a2, kept = attend(i, q, k, v, *mine)
+        x1 = blocks.run_attn_out(a2, flat(x), ap["wo"]).astype(x.dtype)
+        xn2 = apply_norm(p["ln2"], wide(x1), cfg.norm)
+        if cfg.moe:
+            y, st = _moe_ffn(cfg, blocks, flat(xn2), x1, mats["moe"], p["moe"]["router"],
+                             i, valid())
+            kept += (st,)
+        else:
+            y = blocks.run_mlp(flat(xn2), x1, p["mlp"])
+        return wide(y.astype(x.dtype)), kept
+
+    x, kept = jax.lax.scan(
+        layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest, *per_layer))
+    if cfg.moe:
+        return x, kept[:-1], kept[-1]
+    return x, kept, None
+
+
 # ------------------------------------------------------------ decode step
-def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
+def make_decode_step(cfg, progs: DecodePrograms, page_size: int):
     """Build the (jit-friendly) continuous decode step.
 
     Signature: ``fn(params, pages_k, pages_v, page_table, pos, tok) ->
@@ -279,90 +348,35 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
 
     def step(params, pages_k, pages_v, page_table, pos, tok):
         s = page_table.shape[0]
-        pps = page_table.shape[1]
-        t_total = pps * ps
-        n_phys = pages_k.shape[1]
         x = embed_lookup(params["embed"], tok[:, None])  # (S, 1, D)
-
         cur_page = jnp.take_along_axis(page_table, (pos // ps)[:, None], axis=1)[:, 0]
-        kpos = jnp.arange(t_total, dtype=jnp.int32)
+        kpos = jnp.arange(page_table.shape[1] * ps, dtype=jnp.int32)
         history = (kpos[None, :] < pos[:, None])[:, None, None, :]
 
-        mats, rest = _split_blocks(params["blocks"])
-
-        def layer(x, scanned):
-            i, rest_i = scanned
-            p_i = _layer_params(mats, rest_i, i, progs is not None)
-            ap = p_i["attn"]
-            xn = apply_norm(p_i["ln1"], x, cfg.norm)
-            if progs is not None:
-                q2, k2, v2 = run_qkv(progs, xn[:, 0], ap["wq"], ap["wk"], ap["wv"])
-            else:
-                q2 = _proj(xn[:, 0], ap["wq"])
-                k2 = _proj(xn[:, 0], ap["wk"])
-                v2 = _proj(xn[:, 0], ap["wv"])
-            q = q2.reshape(s, 1, h, hd)
-            k = k2.reshape(s, 1, kv, hd)
-            v = v2.reshape(s, 1, kv, hd)
-            if cfg.qk_norm:
-                q = rms_head_norm(q, ap["q_norm"])
-                k = rms_head_norm(k, ap["k_norm"])
-            q = apply_rope(q, pos[:, None], cfg.rope, cfg.rope_theta)
-            k = apply_rope(k, pos[:, None], cfg.rope, cfg.rope_theta)
-            # this position's rows as the pool stores them
-            k_row = k[:, 0].astype(pages_k.dtype)
+        def attend(i, q, k, v):
+            # history (positions < pos) read in place from the step's input
+            # pool, live pages only; this position's row joins as one more
+            # score column
+            k_row = k[:, 0].astype(pages_k.dtype)  # as the pool stores them
             v_row = v[:, 0].astype(pages_v.dtype)
             qg = q[:, 0].reshape(s, kv, g, hd).astype(jnp.float32)
-            if progs is not None:
-                # history (positions < pos) read in place from the step's
-                # input pool, live pages only; this position's row joins
-                # as one more score column
-                k_new, v_new = k_row.astype(jnp.float32), v_row.astype(jnp.float32)
-                hist_k = Paged(pages_k, i, table=page_table, lengths=pos)
-                hist_v = Paged(pages_v, i, table=page_table, lengths=pos)
-                s_hist = progs.paged_scores({"Q": qg, "K": hist_k})["S"] * sm_scale
-                s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new, precision=_HI) * sm_scale
-                # a row past pos holds no defined score: select, never scale
-                s_hist = jnp.where(history, s_hist, NEG_INF)
-                top = jnp.maximum(jnp.max(s_hist, axis=-1), s_new)
-                e_hist = jnp.where(history, jnp.exp(s_hist - top[..., None]), 0.0)
-                e_new = jnp.exp(s_new - top)
-                total = jnp.sum(e_hist, axis=-1) + e_new
-                o = (progs.paged_values({"P": e_hist / total[..., None], "V": hist_v})["O"]
-                     + (e_new / total)[..., None] * v_new[:, :, None, :])
-            else:
-                # the plain-jnp reference: the layer's whole window with
-                # this row written, gathered head-major (S, KV, T, hd),
-                # positions past pos masked
-                rows = (page_table[:, :, None] * ps
-                        + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(s, t_total)
-                at = cur_page * ps + pos % ps
-                ck, cv = (pool[i].reshape(n_phys * ps, kv, hd).at[at].set(row)[rows]
-                          .astype(jnp.float32).transpose(0, 2, 1, 3)
-                          for pool, row in ((pages_k, k_row), (pages_v, v_row)))
-                scores = jnp.einsum("bkgd,bktd->bkgt", qg, ck) * sm_scale
-                scores = jnp.where(kpos <= pos[:, None, None, None], scores, NEG_INF)
-                o = jnp.einsum("bkgt,bktd->bkgd", jax.nn.softmax(scores, axis=-1), cv)
-            a2 = o.reshape(s, h * hd)
-            if progs is not None:
-                x1 = run_attn_out(progs, a2, x[:, 0], ap["wo"])
-            else:
-                x1 = _proj(a2, ap["wo"]) + x[:, 0].astype(jnp.float32)
-            x1 = x1.astype(x.dtype)
+            k_new, v_new = k_row.astype(jnp.float32), v_row.astype(jnp.float32)
+            hist_k = Paged(pages_k, i, table=page_table, lengths=pos)
+            hist_v = Paged(pages_v, i, table=page_table, lengths=pos)
+            s_hist = progs.paged_scores({"Q": qg, "K": hist_k})["S"] * sm_scale
+            s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new, precision=_HI) * sm_scale
+            # a row past pos holds no defined score: select, never scale
+            s_hist = jnp.where(history, s_hist, NEG_INF)
+            top = jnp.maximum(jnp.max(s_hist, axis=-1), s_new)
+            e_hist = jnp.where(history, jnp.exp(s_hist - top[..., None]), 0.0)
+            e_new = jnp.exp(s_new - top)
+            total = jnp.sum(e_hist, axis=-1) + e_new
+            o = (progs.paged_values({"P": e_hist / total[..., None], "V": hist_v})["O"]
+                 + (e_new / total)[..., None] * v_new[:, :, None, :])
+            return o.reshape(s, h * hd), (k_row, v_row)
 
-            xn2 = apply_norm(p_i["ln2"], x1[:, None], cfg.norm)
-            if cfg.moe:
-                y, st = _moe_ffn(cfg, progs, xn2[:, 0], x1, mats["moe"],
-                                 p_i["moe"]["router"], i, pos > 0)
-                return y.astype(x.dtype)[:, None], (k_row, v_row, st)
-            if progs is not None:
-                y = run_mlp(progs, xn2[:, 0], x1, p_i["mlp"], cfg.act)
-            else:
-                y = _mlp_jnp(xn2[:, 0], x1, p_i["mlp"], cfg.act)
-            return y.astype(x.dtype)[:, None], (k_row, v_row)
-
-        x, (k_rows, v_rows, *st) = jax.lax.scan(
-            layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest))
+        x, (k_rows, v_rows), st = _run_layers(cfg, progs, params, x, pos,
+                                              lambda: pos > 0, attend)
         # every layer's new row of each slot written at once, one in-place
         # update a slot (dead slots into their garbage pages: disjoint)
         for j in range(s):
@@ -371,25 +385,25 @@ def make_decode_step(cfg, progs: Optional[DecodePrograms], page_size: int):
             pages_v = jax.lax.dynamic_update_slice(pages_v, v_rows[:, j, None, None], at)
         logits = lm._logits(params, cfg, x)  # (S, 1, V)
         nxt = jnp.argmax(logits[:, -1, : cfg.vocab], axis=-1).astype(jnp.int32)
-        if st:
-            nxt = jnp.concatenate([nxt, jnp.sum(st[0], axis=0)])
+        if st is not None:
+            nxt = jnp.concatenate([nxt, jnp.sum(st, axis=0)])
         return nxt, pages_k, pages_v
 
     return step
 
 
 # ----------------------------------------------------------------- prefill
-def make_prefill_step(cfg, progs: Optional[DecodePrograms], page_size: int,
-                      bucket_len: int):
+def make_prefill_step(cfg, blocks: Blocks, page_size: int, bucket_len: int):
     """Build the batch-1 paged prefill for one compile bucket.
 
     Signature: ``fn(params, tokens (1, Lb), length (int32 scalar),
     page_row (PPS,) int32, pages_k, pages_v) -> (first_tok scalar,
     pages_k, pages_v)``; with routed experts ``first_tok`` is ``(3,)``:
-    the token, then the prefill's pairs and experts hit.  Tokens are right-padded to the bucket; rows at
-    positions ``>= length`` scatter junk into the slot's own allocated /
-    garbage pages, which attention masks, and which decode overwrites
-    in-place before each position ever becomes visible.
+    the token, then the prefill's pairs and experts hit.  Tokens are
+    right-padded to the bucket; rows at positions ``>= length`` scatter
+    junk into the slot's own allocated / garbage pages, which attention
+    masks, and which decode overwrites in-place before each position ever
+    becomes visible.
     """
     ps = int(page_size)
     lb = int(bucket_len)
@@ -401,62 +415,24 @@ def make_prefill_step(cfg, progs: Optional[DecodePrograms], page_size: int,
         x = embed_lookup(params["embed"], tokens)  # (1, Lb, D)
         t = jnp.arange(lb, dtype=jnp.int32)
         write_rows = page_row[t // ps] * ps + t % ps  # (Lb,)
-        positions = t[None]  # (1, Lb)
         cmask = causal_mask(lb)
 
-        mats, rest = _split_blocks(params["blocks"])
-
-        def layer(x, scanned):
-            i, rest_i, pk, pv = scanned
-            p_i = _layer_params(mats, rest_i, i, progs is not None)
-            ap = p_i["attn"]
-            xn = apply_norm(p_i["ln1"], x, cfg.norm)
-            if progs is not None:
-                q2, k2, v2 = run_qkv(progs, xn[0], ap["wq"], ap["wk"], ap["wv"])
-            else:
-                q2 = _proj(xn[0], ap["wq"])
-                k2 = _proj(xn[0], ap["wk"])
-                v2 = _proj(xn[0], ap["wv"])
-            q = q2.reshape(1, lb, h, hd)
-            k = k2.reshape(1, lb, kv, hd)
-            v = v2.reshape(1, lb, kv, hd)
-            if cfg.qk_norm:
-                q = rms_head_norm(q, ap["q_norm"])
-                k = rms_head_norm(k, ap["k_norm"])
-            q = apply_rope(q, positions, cfg.rope, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope, cfg.rope_theta)
-
+        def attend(i, q, k, v, pk, pv):
             flat_k = pk.reshape(n_phys * ps, kv, hd).at[write_rows].set(
                 k[0].astype(pk.dtype))
             flat_v = pv.reshape(n_phys * ps, kv, hd).at[write_rows].set(
                 v[0].astype(pv.dtype))
-
             out = mha(q, k, v, cmask, sm_scale)  # causal full-sequence
-            if progs is not None:
-                x1 = run_attn_out(progs, out.reshape(lb, h * hd), x[0], ap["wo"])
-            else:
-                x1 = _proj(out.reshape(lb, h * hd), ap["wo"]) + x[0].astype(jnp.float32)
-            x1 = x1.astype(x.dtype)
-            xn2 = apply_norm(p_i["ln2"], x1[None], cfg.norm)
-            pages = (flat_k.reshape(pk.shape), flat_v.reshape(pv.shape))
-            if cfg.moe:
-                y, st = _moe_ffn(cfg, progs, xn2[0], x1, mats["moe"],
-                                 p_i["moe"]["router"], i, t < length)
-                return y.astype(x.dtype)[None], pages + (st,)
-            if progs is not None:
-                y = run_mlp(progs, xn2[0], x1, p_i["mlp"], cfg.act)
-            else:
-                y = _mlp_jnp(xn2[0], x1, p_i["mlp"], cfg.act)
-            return y.astype(x.dtype)[None], pages
+            return out.reshape(lb, h * hd), (flat_k.reshape(pk.shape),
+                                             flat_v.reshape(pv.shape))
 
-        x, (pages_k, pages_v, *st) = jax.lax.scan(
-            layer, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), rest,
-                       pages_k, pages_v))
+        x, (pages_k, pages_v), st = _run_layers(
+            cfg, blocks, params, x, t, lambda: t < length, attend, (pages_k, pages_v))
         x_last = jax.lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, x.shape[-1]))
         logits = lm._logits(params, cfg, x_last)  # (1, 1, V)
         tok = jnp.argmax(logits[0, 0, : cfg.vocab]).astype(jnp.int32)
-        if st:
-            tok = jnp.concatenate([tok[None], jnp.sum(st[0], axis=0)])
+        if st is not None:
+            tok = jnp.concatenate([tok[None], jnp.sum(st, axis=0)])
         return tok, pages_k, pages_v
 
     return step

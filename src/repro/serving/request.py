@@ -67,13 +67,13 @@ class EngineConfig:
       remaining job first among the prepared requests).
     * ``backend`` / ``hw`` / ``interpret`` — the ``stripe_jit`` backend,
       hardware config name, and Pallas interpret flag used to compile the
-      decode-time attention/MLP blocks.  Left ``None``, the platform
-      decides: on a TPU, compiled Pallas kernels for the attached chip's
-      config (an unknown chip is an error); elsewhere the ``jnp`` backend
-      and the ``tpu_v5e`` config, with Pallas in interpret mode.
-    * ``use_stripe_decode`` — route decode blocks through ``stripe_jit``
-      (the default); ``False`` uses plain jnp ops (same math, no compile
-      records) for A/B measurement.
+      served layer's blocks (qkv, paged attention, ``attn_out``, MLP or
+      routed experts) for the decode step and every prefill bucket;
+      decode always runs through these programs.  Left ``None``, the
+      platform decides: on a TPU, compiled Pallas kernels for the
+      attached chip's config (an unknown chip is an error); elsewhere the
+      ``jnp`` backend and the ``tpu_v5e`` config, with Pallas in
+      interpret mode.
     * ``use_disk_cache`` — let the engine's compilation cache persist
       tilings + the bucket manifest to disk so the next boot warm-starts.
     * ``max_queue`` — bounded admission queue: when more than this many
@@ -110,7 +110,6 @@ class EngineConfig:
     backend: Optional[str] = None
     hw: Optional[str] = None
     interpret: Optional[bool] = None
-    use_stripe_decode: bool = True
     use_disk_cache: bool = False
     max_queue: Optional[int] = None
     default_ttl_s: Optional[float] = None

@@ -99,7 +99,14 @@ class EngineLikeConfig:
 
 @dataclasses.dataclass
 class DecodePrograms:
-    """Stripe-compiled callables for one row-count ``m`` plus records."""
+    """Stripe-compiled callables for one row-count ``m`` plus records.
+
+    The served layer (``paged._run_layers``) calls its blocks through
+    ``run_qkv``, ``run_attn_out`` and ``run_mlp`` (each with the residual
+    where the block has one) or ``run_experts``; ``paged.PlainBlocks``
+    offers the same four calls in plain jnp.  Weights go in as stored (an
+    array at the declared dtype, or ``Stacked``); activations and
+    residuals are cast to the programs' f32."""
 
     m: int
     qkv: Callable
@@ -111,6 +118,44 @@ class DecodePrograms:
     paged_scores: Optional[CompiledProgram] = None
     paged_values: Optional[CompiledProgram] = None
     moe: Optional[CompiledProgram] = None  # routed experts, in place of mlp
+
+    def run_qkv(self, x2d: jnp.ndarray, wq: Weight, wk: Weight, wv: Weight):
+        out = self.qkv({"X": x2d.astype(jnp.float32), "WQ": wq, "WK": wk, "WV": wv})
+        return out["Q"], out["K"], out["V"]
+
+    def run_attn_out(self, attn2d: jnp.ndarray, resid2d: jnp.ndarray, wo: Weight):
+        out = self.attn_out({"A": attn2d.astype(jnp.float32),
+                             "R": resid2d.astype(jnp.float32), "WO": wo})
+        return out["Y"]
+
+    def run_mlp(self, x2d: jnp.ndarray, resid2d: jnp.ndarray, p):
+        """The (possibly split) MLP program plus the residual, matching
+        nn.core.mlp_apply; ``p`` holds the weights as stored (``w_gate``
+        for a GLU)."""
+        from ..nn.core import _ACT
+
+        x2d = x2d.astype(jnp.float32)
+        resid2d = resid2d.astype(jnp.float32)
+        mlp = self.mlp
+        if isinstance(mlp, _SplitMLP):
+            if mlp.glu:
+                got = mlp.up({"X": x2d, "Wg": p["w_gate"], "Wu": p["w_up"]})
+                a = _ACT[self.act_outside](got["G"]) * got["U"]
+            else:
+                got = mlp.up({"X": x2d, "Wu": p["w_up"]})
+                a = _ACT[self.act_outside](got["H"])
+            return mlp.down({"A": a, "R": resid2d, "Wd": p["w_down"]})["Y"]
+        arrays = {"X": x2d, "R": resid2d, "Wd": p["w_down"], "Wu": p["w_up"]}
+        if "w_gate" in p:
+            arrays["Wg"] = p["w_gate"]
+        return mlp(arrays)["Y"]
+
+    def run_experts(self, rows: jnp.ndarray, wg: Stacked, wu: Stacked,
+                    wd: Stacked) -> jnp.ndarray:
+        """The held experts' FFN over the row buffer ``(blocks, bm, d)``;
+        the weights as ``Stacked`` with one expert per block."""
+        return self.moe({"X": rows.astype(jnp.float32), "Wg": wg, "Wu": wu,
+                         "Wd": wd})["Y"]
 
 
 def build_qkv_program(cfg, m: int, jc: EngineLikeConfig) -> CompiledProgram:
@@ -366,51 +411,3 @@ def build_programs(cfg, m: int, jc: EngineLikeConfig,
     return DecodePrograms(m=m, qkv=qkv, attn_out=attn_out, mlp=mlp,
                           act_outside=act_outside, records=records,
                           paged_scores=scores, paged_values=values, moe=moe)
-
-
-# ------------------------------------------------------------------ apply
-# Weights go in as stored (an array at the declared dtype, or ``Stacked``);
-# activations and residuals are cast to the programs' f32.
-def run_qkv(progs: DecodePrograms, x2d: jnp.ndarray, wq: Weight, wk: Weight,
-            wv: Weight):
-    out = progs.qkv({"X": x2d.astype(jnp.float32), "WQ": wq, "WK": wk, "WV": wv})
-    return out["Q"], out["K"], out["V"]
-
-
-def run_attn_out(progs: DecodePrograms, attn2d: jnp.ndarray, resid2d: jnp.ndarray,
-                 wo: Weight):
-    out = progs.attn_out({"A": attn2d.astype(jnp.float32),
-                          "R": resid2d.astype(jnp.float32), "WO": wo})
-    return out["Y"]
-
-
-def run_mlp(progs: DecodePrograms, x2d: jnp.ndarray, resid2d: jnp.ndarray, mlp_params, act: str):
-    """Apply the (possibly split) MLP program, matching nn.core.mlp_apply.
-    ``mlp_params`` holds the weights as stored (arrays or ``Stacked``)."""
-    from ..nn.core import _ACT
-
-    x2d = x2d.astype(jnp.float32)
-    resid2d = resid2d.astype(jnp.float32)
-    mlp = progs.mlp
-    glu = act.endswith("_glu")
-    if isinstance(mlp, _SplitMLP):
-        if glu:
-            got = mlp.up({"X": x2d, "Wg": mlp_params["w_gate"], "Wu": mlp_params["w_up"]})
-            a = _ACT[progs.act_outside](got["G"]) * got["U"]
-        else:
-            got = mlp.up({"X": x2d, "Wu": mlp_params["w_up"]})
-            a = _ACT[progs.act_outside](got["H"])
-        return mlp.down({"A": a, "R": resid2d, "Wd": mlp_params["w_down"]})["Y"]
-    arrays = {"X": x2d, "R": resid2d, "Wd": mlp_params["w_down"],
-              "Wu": mlp_params["w_up"]}
-    if glu:
-        arrays["Wg"] = mlp_params["w_gate"]
-    return mlp(arrays)["Y"]
-
-
-def run_moe(progs: DecodePrograms, rows: jnp.ndarray, wg: Stacked, wu: Stacked,
-            wd: Stacked) -> jnp.ndarray:
-    """The held experts' FFN over the row buffer ``(blocks, bm, d)``; the
-    weights as ``Stacked`` with one expert per block."""
-    return progs.moe({"X": rows.astype(jnp.float32), "Wg": wg, "Wu": wu,
-                      "Wd": wd})["Y"]

@@ -80,9 +80,15 @@ def test_prep_item_fault_fails_request_thread_survives(model, params):
 
 def test_prep_thread_death_detected_fast_and_restarted(model, params):
     # regression for the old 10s-timeout stall: a dying worker must hand
-    # its exception back under the condition variable, immediately
+    # its exception back under the condition variable, immediately.  The
+    # engine serves the same prompt lengths first, so its decode step and
+    # prefill buckets are compiled before the clock starts: the timed run
+    # measures the detection, not compiles
+    eng = _engine(model)
+    for r in _mk_requests(model.cfg, [4, 7, 9], base_uid=100):
+        eng.submit(r)
+    assert len(eng.run(params, max_steps=4096)) == 3
     with faults.inject(faults.fail_nth("serve.prep_thread", 1)):
-        eng = _engine(model)
         for r in _mk_requests(model.cfg, [4, 7, 9]):
             eng.submit(r)
         t0 = time.perf_counter()

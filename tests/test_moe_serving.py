@@ -115,12 +115,12 @@ def _jc(backend):
 
 def _served_layer(cfg, p_moe, x, resid, layer=1, backend=None, valid=None):
     """The served expert block of one layer: (out, (pairs, experts hit))."""
-    progs = (None if backend is None else
-             sd.build_programs(cfg, x.shape[0], _jc(backend)))
+    blocks = (paged.PlainBlocks(cfg.act) if backend is None else
+              sd.build_programs(cfg, x.shape[0], _jc(backend)))
     experts = {k: p_moe[k] for k in ("w_gate", "w_up", "w_down")}
     valid = jnp.ones(x.shape[0], bool) if valid is None else valid
     out, st = jax.jit(lambda x, r, e, router: paged._moe_ffn(
-        cfg, progs, x, r, e, router, layer, valid))(x, resid, experts,
+        cfg, blocks, x, r, e, router, layer, valid))(x, resid, experts,
                                                    p_moe["router"][layer])
     return np.asarray(out), np.asarray(st)
 

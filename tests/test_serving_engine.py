@@ -72,32 +72,82 @@ def test_paged_matches_dense_reference_mixed_lengths(model, params):
             f"uid {r.uid}: paged decode diverged from dense reference"
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_paged_bf16_matches_cast_outside(backend):
-    """At a bf16 configuration the served programs read each layer's
-    stored weights in place from the stacked parameter and promote them
-    in the kernel.  Greedy tokens match the paged plain-jnp path, which
-    slices each layer and casts it to f32 outside.  (The dense reference
-    rounds its bf16 matmul outputs, so it is not token-exact at bf16.)"""
+def _tiny_moe_cfg():
+    import dataclasses
+
+    from repro.configs.base import MoECfg
+
+    cfg = configs.get("qwen3-moe-30b-a3b-ep8").scaled(n_layers=2)
+    return dataclasses.replace(cfg, moe=MoECfg(n_experts=8, top_k=4, d_ff_expert=32))
+
+
+_FAMILIES = {"dense": _tiny_cfg, "moe": _tiny_moe_cfg}
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_paged_bf16_matches_cast_outside(family):
+    """At a bf16 configuration the served Pallas programs read each
+    layer's stored weights in place from the stacked parameter and promote
+    them in the kernel.  Greedy tokens match the same programs lowered
+    through the ``jnp`` backend, which slices each weight and casts it to
+    f32 outside the dot (``lower_jnp.select_stacked``).  (The dense
+    reference rounds its bf16 matmul outputs, so it is not token-exact at
+    bf16.)"""
     import dataclasses
 
     import jax
 
-    model = build_model(dataclasses.replace(_tiny_cfg(), dtype="bfloat16"))
+    model = build_model(dataclasses.replace(_FAMILIES[family](), dtype="bfloat16"))
     params = model.init(jax.random.PRNGKey(0))
     plens = [3, 8, 13, 21, 32, 5]
     runs = {}
-    for name, kw in (("cast_outside", dict(use_stripe_decode=False)),
-                     ("stored", dict(backend=backend))):
-        eng = _engine(model, slots=3, **kw)
+    for backend in ("jnp", "pallas"):
+        eng = _engine(model, slots=3, backend=backend)
         for r in _mk_requests(model.cfg, plens, new=7):
             eng.submit(r)
-        runs[name] = {r.uid: r.out_tokens for r in eng.run(params, max_steps=4096)}
-    assert sorted(runs["stored"]) == list(range(len(plens)))
-    assert runs["stored"] == runs["cast_outside"]
-    for key in ("decode/qkv", "decode/attn_out", "decode/mlp"):
+        runs[backend] = {r.uid: r.out_tokens for r in eng.run(params, max_steps=4096)}
+    assert sorted(runs["pallas"]) == list(range(len(plens)))
+    assert runs["pallas"] == runs["jnp"]
+    ffn = "decode/moe" if family == "moe" else "decode/mlp"
+    for key in ("decode/qkv", "decode/attn_out", ffn):
         reads = eng.compile_records()[key].stored_reads
         assert reads and all(e["narrow"] and e["in_place"] for e in reads.values()), reads
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_plain_prefill_matches_programs(family):
+    """The prefill a quarantined bucket falls back to (the layer over
+    ``PlainBlocks``) gives the programs' first token and writes the same
+    KV pages, dense and with routed experts (whose (pairs, experts hit)
+    follow the token)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.hwconfig import get_config
+    from repro.serving import stripe_decode as sd
+    from repro.serving.paged import PlainBlocks, init_pages, make_prefill_step
+
+    cfg = _FAMILIES[family]()
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    ps, pps, bucket, length = 8, 6, 16, 11
+    jc = sd.EngineLikeConfig(hw=get_config("tpu_v5e"), backend="jnp", use_disk=False,
+                             cache=stripe_cache.CompilationCache(capacity=8, use_disk=False))
+    tokens = jnp.asarray(np.random.RandomState(1).randint(1, cfg.vocab, (1, bucket)),
+                         jnp.int32)
+    row = jnp.asarray([5, 2, 0, 7, 7, 7], jnp.int32)  # page 7: the garbage page
+    outs = {}
+    for name, blocks in (("plain", PlainBlocks(cfg.act)),
+                         ("programs", sd.build_programs(cfg, bucket, jc))):
+        pk, pv = init_pages(cfg, 8, ps)
+        step = jax.jit(make_prefill_step(cfg, blocks, ps, bucket))
+        outs[name] = jax.tree_util.tree_map(
+            np.asarray, step(params, tokens, jnp.int32(length), row, pk, pv))
+    (tok_a, pk_a, pv_a), (tok_b, pk_b, pv_b) = outs["plain"], outs["programs"]
+    assert tok_a.shape == ((3,) if family == "moe" else ())
+    np.testing.assert_array_equal(tok_a, tok_b)
+    assert np.any(pk_a[:, 5]) and not np.any(pk_a[:, 1])
+    np.testing.assert_allclose(pk_a, pk_b, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(pv_a, pv_b, rtol=1e-5, atol=1e-5)
 
 
 def test_determinism_across_runs(model, params):
