@@ -20,7 +20,8 @@ stored dtype, with the index of the slice to read, and the keys and
 values of the two paged attention programs as a page pool with a page
 table and slot lengths, every row past a slot's length NaN.  The share
 of the KV window the decode steps read (``serve.kv.pages_read /
-pages_window``) is printed once.
+pages_window``) is printed once, and no donated KV pool may have been
+lost to a failed step (``serve.kv.pool_rebuilds`` reads 0).
 
 Four chips: the multi-device compile path alone, i.e. an output-split
 SiLU-GLU FFN at Qwen3-4B MLP widths (all_gather) and a reduction-split
@@ -275,6 +276,10 @@ def one_chip(args, api, jax, on_tpu: bool, problems) -> None:
           f"({read / max(window, 1):.3f})")
     if not 0 < read < window:
         problems.append(f"KV pages read {read} of {window}")
+    rebuilds = obs_metrics.counter("serve.kv.pool_rebuilds").value
+    print(f"KV pool rebuilds: {rebuilds}")
+    if rebuilds:
+        problems.append(f"KV pools rebuilt {rebuilds} times after failed steps")
 
     for name, prog, inputs, want, rows in _decode_references(
             engine.decode_programs(), cfg, np.random.default_rng(args.seed + 1)):

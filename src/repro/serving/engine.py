@@ -11,7 +11,9 @@ Architecture (one PR-sized tour; DESIGN.md §9 has the long form):
 * **Paged KV** (:mod:`repro.serving.paged`): fixed-size pages in one
   static physical store, a per-slot page table, pages recycled on
   eviction.  Admission blocks only when the *pool* (not a dense
-  per-slot allocation) is exhausted.
+  per-slot allocation) is exhausted.  The decode step is handed the
+  store (donated) and writes its new rows into it in place; the engine
+  holds only what each step returns.
 * **Stripe-compiled decode** (:mod:`repro.serving.stripe_decode`): the
   dense blocks of both prefill and decode are Tile programs compiled
   via ``stripe_jit`` — fusion grouping, memory planning, per-block
@@ -58,7 +60,9 @@ Architecture (one PR-sized tour; DESIGN.md §9 has the long form):
     slots and requeues their requests (bounded by ``max_retries``); the
     retried incarnation regenerates the already-emitted prefix and
     *verifies* it token-for-token without re-emitting (exactly-once
-    output), while healthy slots keep decoding;
+    output), while healthy slots keep decoding; a failure after the step
+    consumed its donated KV pools rebuilds them and requeues every live
+    slot (``serve.kv.pool_rebuilds``);
   - *page-allocation failures* — a failed allocation defers the
     admission (``alloc_failed`` event) instead of crashing the engine.
 * **Phase spans.**  Each phase of the loop is a ``serve.*`` span
@@ -265,9 +269,10 @@ class ServingEngine:
             if getattr(self.cfg, "moe", None) else None)
         # paged attention: run totals of the KV pages the decode steps read
         # (live ones, K and V of every layer) and of those a full window
-        # would read, in the process-wide registry
+        # would read, and of the donated pools lost to a failed step and
+        # rebuilt, in the process-wide registry
         self._kv_ctr = {n: obs_metrics.counter(f"serve.kv.{n}")
-                        for n in ("pages_read", "pages_window")}
+                        for n in ("pages_read", "pages_window", "pool_rebuilds")}
         self._h_decode = self._obs.histogram("serve.decode_step_s")
         self._h_prefill = self._obs.histogram("serve.prefill_s")
         self._h_queue = self._obs.histogram("serve.queue_wait_s")
@@ -320,7 +325,10 @@ class ServingEngine:
             if built:
                 progs = build_programs(self.cfg, self.slots, self._jc,
                                        kv_window=self._kv_window, page_size=self._ps)
-                fn = jax.jit(make_decode_step(self.cfg, progs, self._ps))
+                # the step owns the pools it is handed (pages_k, pages_v):
+                # its new rows are written into them in place
+                fn = jax.jit(make_decode_step(self.cfg, progs, self._ps),
+                             donate_argnums=(1, 2))
                 hit = (fn, progs)
                 self._compile_cache.put_memory(key, hit)
         if built:
@@ -477,13 +485,14 @@ class ServingEngine:
         """Compile the decode step before serving, outside the device-step
         failure handler: a compiler refusal raises here instead of
         becoming slot requeues.  The warm-up call points every slot at its
-        garbage page and discards the result."""
+        garbage page; the step consumes the pools it is handed, so the
+        engine keeps the pools it returns (only garbage rows were written)."""
         if self._decode_warm:
             return
         with obs_trace.timed("serve.setup.warm_decode") as sp:
             table = np.tile(self._garbage[:, None], (1, self._pps))
             zeros = jnp.zeros(self.slots, jnp.int32)
-            jax.block_until_ready(self._decode_fn(
+            _, self._pk, self._pv = jax.block_until_ready(self._decode_fn(
                 params, self._pk, self._pv, jnp.asarray(table), zeros, zeros))
         self._decode_warm = True
         self._compile_log.append({
@@ -840,17 +849,32 @@ class ServingEngine:
                     queue_depth=len(self._ready),
                     free_pages=self._pool.free_pages)
 
-    def _on_step_failure(self, live: List[int], exc: BaseException) -> None:
+    def _on_step_failure(self, live: List[int], exc: BaseException, *,
+                         pools_lost: bool = False) -> None:
         """Crash-safe decode recovery: release only the affected slots and
         requeue their requests (front of queue, bounded by ``max_retries``);
-        healthy slots are untouched and simply redo the step.  Nothing was
-        committed for the failed step — KV pages, positions and output all
-        update only after a successful step — so the retried incarnation
-        replays deterministically from its prefill."""
+        healthy slots are untouched and simply redo the step.  Positions
+        and output update only after a successful step, and a step that
+        failed before it took the pools (a fault before the dispatch, a
+        refusal before the call consumed its inputs) left them as they
+        were, so the retried incarnation replays deterministically from
+        its prefill.
+
+        The step owns the pools it is handed (donated): once the call has
+        consumed them (``pools_lost``: the inputs are deleted, or the call
+        returned and its outputs cannot be trusted), every live slot's
+        history is gone.  Both pools are rebuilt empty, counted in
+        ``serve.kv.pool_rebuilds``, and every live slot is requeued
+        through the same replay path, whatever the payload names."""
         payload = getattr(exc, "payload", None) or {}
-        affected = payload.get("slots")
+        affected = None if pools_lost else payload.get("slots")
         affected = [s for s in (live if affected is None else affected)
                     if 0 <= s < self.slots and self._slot_req[s] is not None]
+        if pools_lost:
+            self._pk = self._pv = None  # drop the outputs before reallocating
+            self._pk, self._pv = init_pages(self.cfg, self._pool.total_pages, self._ps)
+            self._kv_ctr["pool_rebuilds"].inc()
+            self._event("kv_pools_rebuilt", slots=list(affected))
         self._event("device_step_failed", slots=list(affected),
                     error=repr(exc)[:200])
         for s in affected:
@@ -910,24 +934,29 @@ class ServingEngine:
                 continue
             stall = 0
             t0 = time.perf_counter()
+            pools = (self._pk, self._pv)
+            returned = False
             try:
                 faults.check("serve.decode_step",
                              step=self._steps, n_live=len(live))
                 with obs_trace.span("serve.decode_step", step=self._steps,
                                     n_live=len(live)):
                     with obs_trace.span("serve.decode.dispatch", step=self._steps):
-                        nxt, pk, pv = self._decode_fn(
-                            params, self._pk, self._pv,
+                        # the step consumes the pools it is handed (donated):
+                        # the engine holds the ones it returns
+                        nxt, self._pk, self._pv = self._decode_fn(
+                            params, *pools,
                             jnp.asarray(self._page_table), jnp.asarray(self._pos),
                             jnp.asarray(self._last))
+                        returned = True
                     with obs_trace.span("serve.decode.sync", step=self._steps):
                         nxt = np.asarray(nxt)
             except Exception as e:  # noqa: BLE001 — device-step crash:
-                # nothing was committed (pages/pos/output update below, only
-                # on success); recover the affected slots and carry on
-                self._on_step_failure(live, e)
+                # positions and output update below, only on success; the
+                # pools survive unless the step took them (_on_step_failure)
+                self._on_step_failure(
+                    live, e, pools_lost=returned or any(p.is_deleted() for p in pools))
                 continue
-            self._pk, self._pv = pk, pv
             self._h_decode.observe(time.perf_counter() - t0)
             if self._moe_ctr is not None:
                 self._count_moe("decode", nxt[self.slots:])

@@ -32,8 +32,10 @@ through the paged path reproduces the dense-cache reference decode
 token-for-token (asserted in tests/test_serving_engine.py and
 tests/test_paged_attention.py).  The layer scan carries no pages: it
 returns each layer's new rows, and after it each slot's rows of every
-layer go into the output pool in one in-place update (the output is a
-copy of the input pool, which the step does not own).
+layer go into the pool in one in-place update.  The engine jits the step
+with the pools donated, so the output pools are the input buffers with
+the new rows written (no copy of either pool); the input arrays are
+consumed by the call.
 
 Both steps run one layer body (``_run_layers``) over the ``(batch,
 rows)`` layout, decode's ``(S, 1)`` and prefill's ``(1, Lb)``; they
@@ -340,6 +342,10 @@ def make_decode_step(cfg, progs: DecodePrograms, page_size: int):
     ``pos (S,) int32`` (per-slot lengths; 0 for a slot with no request),
     ``tok (S,) int32``.  With routed experts ``next_tok`` is ``(S + 2,)``:
     the tokens, then the step's pairs and experts hit (module docstring).
+    The returned pools are the input pools with one row a slot written;
+    jitted with ``donate_argnums=(1, 2)`` (as the engine does) the write
+    is in place and the inputs are consumed, without donation XLA first
+    copies both pools.
     """
     ps = int(page_size)
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd

@@ -168,6 +168,48 @@ def test_decode_step_crash_scoped_to_payload_slots(model, params):
         assert done[uid].status == "ok"
 
 
+def test_decode_step_crash_after_donation_rebuilds_pools(model, params):
+    """A step that consumed its donated KV pools and then failed leaves no
+    slot's history: the engine rebuilds both pools, requeues every live
+    slot (not only those a payload names), and each request still ends
+    with exactly the tokens of an unfaulted run."""
+    from repro.obs import metrics as obs_metrics
+
+    plens = [4, 9, 6, 11]
+    want = _baseline_tokens(model, params, plens, new=8)
+    rebuilds = obs_metrics.counter("serve.kv.pool_rebuilds")
+    before = rebuilds.value
+    eng = _engine(model)
+    step = eng._decode_fn
+    calls, consumed = [], []
+
+    def consumes_then_raises(params, pk, pv, *rest):
+        out = step(params, pk, pv, *rest)
+        calls.append(1)
+        if len(calls) == 3:  # the warm-up, then the second served step
+            consumed.append(pk.is_deleted() and pv.is_deleted())
+            err = RuntimeError("device step lost after taking its pools")
+            err.payload = {"slots": [0]}
+            raise err
+        return out
+
+    eng._decode_fn = consumes_then_raises
+    for r in _mk_requests(model.cfg, plens, new=8):
+        eng.submit(r)
+    done = {r.uid: r for r in eng.run(params, max_steps=4096)}
+    assert consumed == [True]
+    failed = _events_of(eng, "device_step_failed")
+    assert len(failed) == 1 and failed[0]["slots"] == [0, 1], \
+        "every live slot is affected, whatever the payload names"
+    assert [e["slots"] for e in _events_of(eng, "kv_pools_rebuilt")] == [[0, 1]]
+    assert len(_events_of(eng, "requeue")) == 2
+    assert rebuilds.value - before == 1
+    for uid, toks in want.items():
+        assert list(done[uid].out_tokens) == toks
+        assert done[uid].status == "ok"
+        assert done[uid].retries == (1 if uid in (0, 1) else 0)
+
+
 def test_retries_exhausted_fails_request_without_hanging(model, params):
     # every decode step fails: requests burn max_retries then fail; the
     # engine must converge (no infinite requeue loop)

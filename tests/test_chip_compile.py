@@ -155,7 +155,7 @@ def _step_hlo(phase, sharding, jc):
     i32 = jnp.int32
     if phase == "decode":
         progs = sd.build_programs(QWEN, SLOTS, jc, kv_window=KV_WINDOW, page_size=ps)
-        fn = jax.jit(make_decode_step(QWEN, progs, ps))
+        fn = jax.jit(make_decode_step(QWEN, progs, ps), donate_argnums=(1, 2))  # as served
         args = (params, pages, pages, sds((SLOTS, pps), i32), sds((SLOTS,), i32),
                 sds((SLOTS,), i32))
     else:
@@ -263,7 +263,7 @@ def _moe_step_hlo(phase, sharding, jc):
     i32 = jnp.int32
     if phase == "decode":
         progs = sd.build_programs(MOE, m, jc, kv_window=KV_WINDOW, page_size=ps)
-        fn = jax.jit(make_decode_step(MOE, progs, ps))
+        fn = jax.jit(make_decode_step(MOE, progs, ps), donate_argnums=(1, 2))  # as served
         args = (params, pages, pages, sds((m, pps), i32), sds((m,), i32), sds((m,), i32))
     else:
         progs = sd.build_programs(MOE, m, jc)
@@ -326,3 +326,27 @@ def test_decode_step_reads_kv_pages_in_place(model, one_chip, jc):
         assert operands.startswith(f"{{s32[1]{{0}}, s32[{slots},{pps}]{{1,0}}, "
                                    f"s32[{slots}]{{0}}"), line[:200]
         assert pool in operands.split("}}", 1)[0], line[:200]
+
+
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_decode_step_writes_pools_in_place(family, one_chip, jc):
+    """The served decode step, its pools donated, aliases both pool
+    parameters to its outputs and makes no copy of a pool: the new rows
+    are written into the pools it was handed."""
+    import re
+
+    cfg, text = ((QWEN, _step_hlo("decode", one_chip, jc)) if family == "dense"
+                 else (MOE, _moe_step_hlo("decode", one_chip, jc)))
+    slots = SLOTS if family == "dense" else MOE_ROWS["decode"]
+    pps = KV_WINDOW // PAGE
+    pool = f"bf16[{cfg.n_layers},{slots * pps + slots},{PAGE},{cfg.n_kv_heads},{cfg.hd}]"
+    lines = text.splitlines()
+    entry = text.split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    params = {int(n) for n in re.findall(
+        rf"= {re.escape(pool)}\S* parameter\((\d+)\)", entry)}
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}", lines[0].split("input_output_alias=", 1)[-1])}
+    assert len(params) == 2 and params <= aliased, (params, lines[0][:300])
+    copies = [line.strip()[:160] for line in lines
+              if f"= {pool}" in line and re.search(r" copy(-start)?\(", line)]
+    assert not copies, copies

@@ -150,6 +150,54 @@ def test_plain_prefill_matches_programs(family):
     np.testing.assert_allclose(pv_a, pv_b, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_decode_step_owns_the_pools(family):
+    """The engine donates both KV pools to its decode step: the compiled
+    step aliases them to its outputs (the new rows are written in place,
+    no copy of either pool), every pool a served step was handed is
+    consumed, and greedy tokens equal those of the same step jitted
+    without donation, which copies both pools each step."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.serving.paged import make_decode_step
+
+    model = build_model(dataclasses.replace(_FAMILIES[family](), dtype="bfloat16"))
+    params = model.init(jax.random.PRNGKey(0))
+    plens = [3, 8, 13, 21, 32, 5]
+    eng = _engine(model, slots=3)
+    zeros = jnp.zeros(eng.slots, jnp.int32)
+    table = jnp.asarray(eng._page_table)
+    compiled = eng._decode_fn.lower(params, eng._pk, eng._pv, table, zeros, zeros).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= eng._pk.nbytes + eng._pv.nbytes
+
+    handed = []
+    step = eng._decode_fn
+
+    def spy(params, pk, pv, *rest):
+        handed.append((pk, pv))
+        return step(params, pk, pv, *rest)
+
+    eng._decode_fn = spy
+    for r in _mk_requests(model.cfg, plens, new=7):
+        eng.submit(r)
+    got = {r.uid: r.out_tokens for r in eng.run(params, max_steps=4096)}
+    assert len(handed) > 1  # the warm-up and the served steps
+    assert all(pk.is_deleted() and pv.is_deleted() for pk, pv in handed)
+    assert not (eng._pk.is_deleted() or eng._pv.is_deleted())
+
+    copying = _engine(model, slots=3)
+    copying._decode_fn = jax.jit(make_decode_step(model.cfg, copying.decode_programs(),
+                                                  copying._ps))
+    for r in _mk_requests(model.cfg, plens, new=7):
+        copying.submit(r)
+    want = {r.uid: r.out_tokens for r in copying.run(params, max_steps=4096)}
+    assert sorted(got) == list(range(len(plens)))
+    assert got == want
+
+
 def test_determinism_across_runs(model, params):
     def run_once():
         eng = _engine(model)
